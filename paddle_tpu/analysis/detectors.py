@@ -54,8 +54,8 @@ from .jaxpr_utils import aval_bytes, source_of, walk_closed, walk_eqns
 _CALLBACK_PRIMS = {
     "pure_callback": Severity.ERROR,
     "io_callback": Severity.ERROR,
-    "outside_call": Severity.ERROR,     # legacy host_callback
-    "debug_callback": Severity.WARNING,  # jax.debug.print / breakpoint
+    "debug_callback": Severity.WARNING,  # jax.debug.callback / breakpoint
+    "debug_print": Severity.WARNING,     # jax.debug.print
 }
 
 # collective primitives whose payload we account per mesh axis

@@ -47,10 +47,7 @@ import numpy as np
 from .findings import Finding, Severity
 from .jaxpr_utils import _sub_jaxprs, aval_bytes, source_of, walk_closed
 
-try:  # jax is mid-migration of these to jax.extend.core
-    from jax.core import DropVar, Literal, Var  # noqa: F401
-except ImportError:  # pragma: no cover - newer jax
-    from jax.extend.core import DropVar, Literal, Var  # noqa: F401
+from jax.extend.core import Literal
 
 #: call-like primitives whose single body jaxpr executes exactly once
 #: with the equation's own operands/results as its boundary — safe to

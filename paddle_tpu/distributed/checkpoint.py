@@ -223,12 +223,11 @@ class CheckpointManager:
             md = self._mgr.item_metadata(step)
         except Exception:
             md = None
-        md = getattr(md, "item_metadata", md)
         if md is None:
             # metadata unavailable (fresh manager without a handler
             # registry): inconclusive, let the restore attempt decide
             return True
-        on_disk = _flatten_tree(md)
+        on_disk = _flatten_tree(md.tree)  # orbax TreeMetadata
         if not on_disk:
             return True  # metadata empty/unreconstructable: inconclusive
         for path, leaf in recorded.items():

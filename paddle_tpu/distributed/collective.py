@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import monitor
-from ..core.jaxshim import shard_map
+from jax import shard_map
 from ..core.tensor import Tensor
 from . import topology
 

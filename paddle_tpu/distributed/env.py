@@ -17,7 +17,7 @@ from typing import Optional
 
 import jax
 
-from ..core.jaxshim import shard_map
+from jax import shard_map
 
 _INITIALIZED = False
 

@@ -162,12 +162,7 @@ class DistributedTrainStep:
                 list(param_vals), grads, opt_state, lr=lr, step=step_no)
             return loss, new_params, new_state
 
-        from ...core.jaxshim import SHARDING_AWARE_DONATION
-        # old jax mispairs donated buffers across the mixed-sharding
-        # param/opt trees (aval-only matching): donate only where the
-        # matcher is sharding-aware; the fallback costs one transient
-        # copy of params+state, it never changes numerics
-        donate = (0, 1) if SHARDING_AWARE_DONATION else ()
+        donate = (0, 1)
         self._donate = donate
         self._step_fn = step_fn
         self._jitted = jax.jit(
@@ -324,10 +319,8 @@ class DistributedTrainStep:
     def audit(self, *batch, donate=(0, 1), **audit_kw):
         """Static audit of the sharded step on abstract operands (works
         for ``abstract=True`` plan-only steps too — nothing is placed
-        on the mesh). ``donate`` defaults to the DESIGN intent (params
-        + opt state donated) even where the running jax disables
-        donation via the SHARDING_AWARE_DONATION shim: the audit checks
-        the program we ship on TPU, not the fallback."""
+        on the mesh). ``donate`` defaults to what the step donates:
+        params + opt state."""
         from ...analysis import audit as _audit
         if self._jitted is None:
             self._build(None)

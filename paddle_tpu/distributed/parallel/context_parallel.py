@@ -29,7 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ...core.jaxshim import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import topology
@@ -208,9 +208,6 @@ def split_sequence(x, axis: int = 1, axis_name: str = "sp", mesh=None):
     mesh = mesh or topology.get_mesh()
     if mesh is None or _axis_degree(mesh, axis_name) == 1:
         return x
-    from ...core import jaxshim
-    if jaxshim.in_manual_fallback():
-        return x
     parts = [P.UNCONSTRAINED] * x.ndim
     parts[axis] = axis_name
     from jax.sharding import NamedSharding
@@ -223,9 +220,6 @@ def gather_sequence(x, axis: int = 1, axis_name: str = "sp", mesh=None):
     stay UNCONSTRAINED."""
     mesh = mesh or topology.get_mesh()
     if mesh is None or _axis_degree(mesh, axis_name) == 1:
-        return x
-    from ...core import jaxshim
-    if jaxshim.in_manual_fallback():
         return x
     parts = [P.UNCONSTRAINED] * x.ndim
     parts[axis] = None

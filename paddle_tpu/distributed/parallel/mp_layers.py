@@ -43,11 +43,6 @@ def _constraint(x_raw, spec):
             n *= mesh.shape[a]
         if dim >= x_raw.ndim or x_raw.shape[dim] % n != 0:
             return x_raw
-    from ...core import jaxshim
-    if jaxshim.in_manual_fallback():
-        # old-jax full-manual shard_map fallback: these axes are manual
-        # in the enclosing region, a constraint on them fails lowering
-        return x_raw
     try:
         return jax.lax.with_sharding_constraint(
             x_raw, NamedSharding(mesh, spec))

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...core.jaxshim import pcast, shard_map
+from jax import shard_map
 from ...core.tensor import Parameter, Tensor
 from ...nn.container import Sequential
 from ...nn.layer import Layer
@@ -424,7 +424,7 @@ def _spmd_pipeline(unit_call, names, stacked_vals, specs, seg_counts,
 
         carry0 = (jnp.zeros_like(mb_local[0]), jnp.zeros_like(mb_local),
                   jnp.zeros_like(mb_local)) if v > 1 else             (jnp.zeros_like(mb_local[0]), jnp.zeros_like(mb_local))
-        init = pcast(carry0, ("pp",), to="varying")
+        init = jax.lax.pcast(carry0, ("pp",), to="varying")
         final_carry, _ = jax.lax.scan(tick, init, jnp.arange(steps))
         outs = final_carry[-1]
         # [1, M, mb, ...] local -> global leading dim S over 'pp'; only

@@ -635,7 +635,10 @@ def decode_loop(network, session, state_vals, ids, plen, cfg, spec,
     done = jnp.ones((b,), jnp.int32)
     budget = jnp.full((b,), max_new_tokens, jnp.int32)
     finished = finished | (done >= budget)
-    proposed = accepted = jnp.zeros((), jnp.int32)
+    # two buffers, not one bound twice: both lanes are donated to the
+    # verify step, and one buffer cannot be donated twice
+    proposed = jnp.zeros((), jnp.int32)
+    accepted = jnp.zeros((), jnp.int32)
 
     for w in range(max_new_tokens - 1):
         if spec.mode == "draft":

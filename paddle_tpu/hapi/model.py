@@ -338,8 +338,8 @@ class Model:
         guard = self._resolve_anomaly_guard(anomaly_guard, resilience)
         if resume and self._train_step is not None:
             # relaunch warm path (opt-in by the resume request): with an
-            # executable store active (enable_compile_cache /
-            # PADDLE_COMPILE_CACHE_DIR) the first step loads the
+            # executable store active (enable_compile_cache) the
+            # first step loads the
             # serialized fused-step executable instead of recompiling —
             # after the guard resolution above, which may have rebuilt
             # the TrainStep

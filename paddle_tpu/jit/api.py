@@ -539,12 +539,10 @@ class TrainStep:
             jax.ShapeDtypeStruct((), np.int32), *abstractify(raw_batch),
             donate=self._donate_argnums, **audit_kw)
 
-    def cost_analysis(self, *batch):
-        """XLA's cost model for the compiled step on these inputs
-        (['flops'], bytes accessed, ...) — bench.py derives MFU from it
-        instead of hand-maintained per-model formulas (the reference's
-        op cost-model table, cost_model/static_op_benchmark.json, is a
-        measured equivalent)."""
+    def lower(self, *batch):
+        """jax Lowered for the step on these example inputs (the same
+        entry ``fleet.DistributedTrainStep.lower`` offers): compile it
+        for ``cost_analysis()`` or to read the program's text."""
         params = self._params_cache
         if self._opt_state_tree is None:
             self._opt_state_tree = [
@@ -558,11 +556,18 @@ class TrainStep:
             jax.tree_util.tree_map(
                 _unwrap, b, is_leaf=lambda t: isinstance(t, Tensor))
             for b in batch)
-        lowered = self._jitted.lower(
+        return self._jitted.lower(
             [p._data for p in params], self._opt_state_tree,
             np.float32(self.optimizer.get_lr()),
             np.int32(self.optimizer._step_count + 1), *raw_batch)
-        return lowered.compile().cost_analysis()
+
+    def cost_analysis(self, *batch):
+        """XLA's cost model for the compiled step on these inputs
+        (['flops'], bytes accessed, ...) — bench.py derives MFU from it
+        instead of hand-maintained per-model formulas (the reference's
+        op cost-model table, cost_model/static_op_benchmark.json, is a
+        measured equivalent)."""
+        return self.lower(*batch).compile().cost_analysis()
 
 
 def not_to_static(fn=None):

@@ -7,13 +7,17 @@ each relaunch used to re-pay tens of seconds of XLA work. This module
 makes a relaunched process warm-start in seconds, two layers deep:
 
 1. **The process-global jax persistent compilation cache.**
-   ``enable_compile_cache(dir)`` (or ``PADDLE_COMPILE_CACHE_DIR``)
-   points jax's own HLO->binary disk cache at ``dir``. The jax cache
-   dir is process-global state: it is set ONCE here and never silently
-   re-pointed — a second caller naming a different dir gets a warning
-   and the original dir (predictor B must not hijack predictor A's
-   cache). This module is the only place allowed to touch
-   ``jax_compilation_cache_dir`` (lint rule ``compile-cache-dir``).
+   ``enable_compile_cache()`` turns jax's own HLO->binary disk cache
+   on. Where it lives follows one rule (:func:`cache_root`): if
+   ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and this
+   module sets no dir at all; otherwise the caller's ``dir``, else the
+   fixed ``<checkout>/.jax_cache`` (the path is part of jax's cache
+   key, so it never moves). The jax cache dir is process-global state:
+   it is placed ONCE and never silently re-pointed — a second caller
+   naming a different dir gets a warning and the original dir
+   (predictor B must not hijack predictor A's cache). This module is
+   the only place allowed to touch ``jax_compilation_cache_dir`` (lint
+   rule ``compile-cache-dir``).
 
 2. **The executable store above it.** jax's cache keys on internals
    and still re-runs part of the compile pipeline on a hit; the
@@ -90,7 +94,7 @@ __all__ = [
 ]
 
 #: executable-entry file layout: MAGIC + 64 hex sha256(payload) + payload
-_MAGIC = b"PDTPU-EXE1\n"
+_MAGIC = b"PDTPU-EXE2\n"
 #: manifest-entry layout: REF_MAGIC + 64 hex chars (the executable key)
 _REF_MAGIC = b"PDTPU-REF1\n"
 ENTRY_SUFFIX = ".pdexe"
@@ -103,28 +107,47 @@ _DEFAULT_STORE: Optional["ExecutableStore"] = None
 
 # --------------------------------------------------- process-global cache
 
-def enable_compile_cache(path: str,
+def _placed_by_env() -> str:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+
+
+def cache_root(path: Optional[str] = None) -> str:
+    """Where the compile cache lives: ``JAX_COMPILATION_CACHE_DIR`` if
+    the environment places it, else ``path``, else the fixed
+    ``<checkout>/.jax_cache``."""
+    import paddle_tpu
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(paddle_tpu.__file__)))
+    return _placed_by_env() or path \
+        or os.path.join(checkout, ".jax_cache")
+
+
+def enable_compile_cache(path: Optional[str] = None,
                          min_compile_time_secs: float = 0.0
                          ) -> "ExecutableStore":
-    """Point jax's persistent compilation cache at ``path`` and anchor
-    the process-default :class:`ExecutableStore` at
-    ``path/executables``. Returns the store.
+    """Turn jax's persistent compilation cache on at
+    :func:`cache_root` and anchor the process-default
+    :class:`ExecutableStore` at ``<root>/executables``. Returns the
+    store. With ``JAX_COMPILATION_CACHE_DIR`` set jax has already read
+    its dir from the environment and none is set here.
 
-    The jax cache dir is process-global; it is set once and a later
+    The jax cache dir is process-global; it is placed once and a later
     call naming a DIFFERENT path warns and keeps the original (the
     same conflict semantics the inference predictor always had —
     ``Config.enable_compile_cache`` delegates here)."""
     global _CACHE_DIR, _DEFAULT_STORE
     with _lock:
         if _CACHE_DIR is None:
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
+            root = cache_root(path)
+            os.makedirs(root, exist_ok=True)
+            if not _placed_by_env():
+                jax.config.update("jax_compilation_cache_dir", root)
             jax.config.update("jax_persistent_cache_min_compile_time_secs",
                               float(min_compile_time_secs))
-            _CACHE_DIR = path
+            _CACHE_DIR = root
             _DEFAULT_STORE = ExecutableStore(
-                os.path.join(path, "executables"))
-        elif os.path.abspath(path) != os.path.abspath(_CACHE_DIR):
+                os.path.join(root, "executables"))
+        if path and os.path.abspath(path) != os.path.abspath(_CACHE_DIR):
             warnings.warn(
                 f"compile cache already at {_CACHE_DIR!r}; the jax "
                 f"cache dir is process-global, ignoring {path!r}")
@@ -138,14 +161,9 @@ def cache_dir() -> Optional[str]:
 
 def default_store() -> Optional["ExecutableStore"]:
     """The process-default executable store: the one
-    :func:`enable_compile_cache` anchored, else auto-enabled from
-    ``PADDLE_COMPILE_CACHE_DIR`` on first ask, else None (AOT paths
-    then compile directly, persisting nothing)."""
+    :func:`enable_compile_cache` anchored, else None (AOT paths then
+    compile directly, persisting nothing)."""
     with _lock:
-        if _DEFAULT_STORE is None:
-            env = os.environ.get("PADDLE_COMPILE_CACHE_DIR", "").strip()
-            if env:
-                return enable_compile_cache(env)
         return _DEFAULT_STORE
 
 
@@ -459,8 +477,16 @@ class ExecutableStore:
             if hashlib.sha256(payload).hexdigest().encode() != digest:
                 raise ValueError("checksum mismatch (torn/corrupt entry)")
             from jax.experimental import serialize_executable as _se
-            serialized, in_tree, out_tree = pickle.loads(payload)
-            exe = _se.deserialize_and_load(serialized, in_tree, out_tree)
+            serialized, in_tree, out_tree, device_ids = \
+                pickle.loads(payload)
+            # load onto the devices the program was compiled for: left
+            # to its default, jax loads it across every device of the
+            # backend, and a one-device program then refuses its
+            # operands on a host with several
+            by_id = {d.id: d for d in jax.devices()}
+            exe = _se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             # a bad entry must never crash a relaunch: recompile instead
             # (and drop the entry so the fresh compile rewrites it)
@@ -491,8 +517,11 @@ class ExecutableStore:
         try:
             from jax.experimental import serialize_executable as _se
             serialized, in_tree, out_tree = _se.serialize(compiled)
-            payload = pickle.dumps((serialized, in_tree, out_tree),
-                                   protocol=pickle.HIGHEST_PROTOCOL)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            payload = pickle.dumps(
+                (serialized, in_tree, out_tree, device_ids),
+                protocol=pickle.HIGHEST_PROTOCOL)
         except Exception as e:
             monitor.record_swallowed(f"jit.compile_cache.save[{label}]", e)
             return False

@@ -837,6 +837,22 @@ MAX_DECODE_QLEN = _DECODE_QPAD
 _DECODE_BLOCK_K = 512
 
 
+def _decode_scratch(rows, d):
+    """m / l / acc scratch of the decode-family online softmax."""
+    return [pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)]
+
+
+def _scale_row_specs(bk, index_map, lead=(1,)):
+    """BlockSpecs for the k/v per-column scale rows of an int8 cache.
+    The row rides a singleton second-to-last dim ([..., 1, T] blocked
+    (..., 1, bk)): a 1-row block of a taller 2-D array is off the
+    (8, 128) tiling, a block equal to the array's own dim is on it."""
+    spec = pl.BlockSpec(lead + (1, bk), index_map)
+    return [spec, spec]
+
+
 def _decode_init(m_scr, l_scr, acc_scr):
     m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
@@ -907,32 +923,34 @@ def _decode_write_out(o_ref, l_scr, acc_scr):
     o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, *rest, sq, block_k,
-                   num_kblocks, quant=False):
+def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq, block_k,
+                   num_kblocks, group, quant=False):
     # q_ref holds q * (scale * log2e); scores are base-2 logits. In
-    # quant mode two per-column bf16 scale rows ([1, bk], same index
-    # map as k/v) ride between the caches and kv_len, and the shared
-    # accumulate body fuses the dequant into the score tile.
+    # quant mode two per-column bf16 scale rows ([1, 1, bk], same index
+    # map as k/v) follow the caches, and the shared accumulate body
+    # fuses the dequant into the score tile. kvlen_ref is the
+    # scalar-prefetched [B*Hk] length vector, whole in SMEM.
     if quant:
-        ks_ref, vs_ref, kvlen_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
-        kvlen_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, m_scr, l_scr, acc_scr = rest
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
         _decode_init(m_scr, l_scr, acc_scr)
 
-    kv_len = kvlen_ref[0, 0]  # this row's valid cache length (incl. the
-    #                           sq new positions, already written)
+    # this row's valid cache length (incl. the sq new positions,
+    # already written)
+    kv_len = kvlen_ref[pl.program_id(0) // group]
 
     # skip k-blocks entirely past the valid prefix
     @pl.when(ik * block_k < kv_len)
     def _compute():
         _decode_accumulate(q_ref[0], k_ref[0], v_ref[0], ik * block_k,
                            kv_len, sq, m_scr, l_scr, acc_scr,
-                           ks=ks_ref[...] if quant else None,
-                           vs=vs_ref[...] if quant else None)
+                           ks=ks_ref[0] if quant else None,
+                           vs=vs_ref[0] if quant else None)
 
     @pl.when(ik == num_kblocks - 1)
     def _finalize():
@@ -949,7 +967,9 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
     repeated copy is ever materialized. ``k_scale``/``v_scale``
     ([B*Hk, T] bf16) switch on the int8-cache mode — the scale rows
     stream through the SAME b//group index maps as the caches and the
-    dequant fuses in-register (see ``_decode_accumulate``)."""
+    dequant fuses in-register (see ``_decode_accumulate``). kv_len is
+    scalar-prefetched: a per-row (1, 1) SMEM block is off the TPU
+    tiling and the front end refuses it."""
     bh, sq, d = q.shape
     t = k_cache.shape[1]
     quant = k_scale is not None
@@ -959,41 +979,37 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
         q = jnp.pad(q, ((0, 0), (0, qpad - sq), (0, 0)))
     bk = _pick_block(t, block_k)
     nk = t // bk
-    kvlen2 = kv_len.astype(jnp.int32).reshape(k_cache.shape[0], 1)
     kv_bytes = k_cache.dtype.itemsize * t * d \
         + (k_scale.dtype.itemsize * t if quant else 0)
     in_specs = [
-        pl.BlockSpec((1, qpad, d), lambda b, j: (b, 0, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b // group, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, j: (b // group, j, 0)),
+        pl.BlockSpec((1, qpad, d), lambda b, j, kl: (b, 0, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, kl: (b // group, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, j, kl: (b // group, j, 0)),
     ]
     operands = [q, k_cache, v_cache]
     if quant:
-        in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b // group, j)),
-                     pl.BlockSpec((1, bk), lambda b, j: (b // group, j))]
-        operands += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, 1), lambda b, j: (b // group, 0),
-                                 memory_space=pltpu.SMEM))
-    operands.append(kvlen2)
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, sq=sq, block_k=bk,
-                          num_kblocks=nk, quant=quant),
+        in_specs += _scale_row_specs(
+            bk, lambda b, j, kl: (b // group, 0, j))
+        operands += [k_scale[:, None], v_scale[:, None]]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qpad, d), lambda b, j: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, qpad, d), lambda b, j, kl: (b, 0, 0)),
+        scratch_shapes=_decode_scratch(qpad, d),
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, sq=sq, block_k=bk,
+                          num_kblocks=nk, group=group, quant=quant),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, qpad, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((qpad, _LANES), jnp.float32),
-            pltpu.VMEM((qpad, _LANES), jnp.float32),
-            pltpu.VMEM((qpad, d), jnp.float32),
-        ],
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * qpad * t * d,
             bytes_accessed=bh * (qpad * d * q.dtype.itemsize
                                  + 2 * kv_bytes),
             transcendentals=bh * qpad * t),
         interpret=_interpret(),
-    )(*operands)
+    )(kv_len.astype(jnp.int32), *operands)
     return out[:, :sq]
 
 
@@ -1123,13 +1139,13 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
 _CHUNK_BLOCK_Q = 128
 
 
-def _chunk_kernel(q_ref, k_ref, v_ref, *rest, sq_total, block_q,
-                  block_k, num_kblocks, quant=False):
+def _chunk_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq_total,
+                  block_q, block_k, num_kblocks, group, quant=False):
     # q_ref holds q * (scale * log2e); scores are base-2 logits.
     if quant:
-        ks_ref, vs_ref, kvlen_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
-        kvlen_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, m_scr, l_scr, acc_scr = rest
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -1137,8 +1153,9 @@ def _chunk_kernel(q_ref, k_ref, v_ref, *rest, sq_total, block_q,
     def _init():
         _decode_init(m_scr, l_scr, acc_scr)
 
-    kv_len = kvlen_ref[0, 0]  # valid cache length incl. the sq_total
-    #                           new positions (already written)
+    # valid cache length incl. the sq_total new positions (already
+    # written), from the scalar-prefetched [B*Hk] vector
+    kv_len = kvlen_ref[pl.program_id(0) // group]
     # local row r of q-tile iq is global query iq*block_q + r, so the
     # shared mask with sq := sq_total - iq*block_q is exactly this
     # tile's causal window
@@ -1152,8 +1169,8 @@ def _chunk_kernel(q_ref, k_ref, v_ref, *rest, sq_total, block_q,
     def _compute():
         _decode_accumulate(q_ref[0], k_ref[0], v_ref[0], ik * block_k,
                            kv_len, sq_tile, m_scr, l_scr, acc_scr,
-                           ks=ks_ref[...] if quant else None,
-                           vs=vs_ref[...] if quant else None)
+                           ks=ks_ref[0] if quant else None,
+                           vs=vs_ref[0] if quant else None)
 
     @pl.when(ik == num_kblocks - 1)
     def _finalize():
@@ -1177,42 +1194,39 @@ def _chunk_pallas(q, k_cache, v_cache, kv_len, scale,
     nq = sq_pad // bq
     bk = _pick_block(t, block_k)
     nk = t // bk
-    kvlen2 = kv_len.astype(jnp.int32).reshape(k_cache.shape[0], 1)
     kv_bytes = k_cache.dtype.itemsize * t * d \
         + (k_scale.dtype.itemsize * t if quant else 0)
     in_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b // group, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b // group, j, 0)),
+        pl.BlockSpec((1, bq, d), lambda b, i, j, kl: (b, i, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, i, j, kl: (b // group, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, i, j, kl: (b // group, j, 0)),
     ]
     operands = [q, k_cache, v_cache]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, bk), lambda b, i, j: (b // group, j)),
-            pl.BlockSpec((1, bk), lambda b, i, j: (b // group, j))]
-        operands += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, 1), lambda b, i, j: (b // group, 0),
-                                 memory_space=pltpu.SMEM))
-    operands.append(kvlen2)
-    out = pl.pallas_call(
-        functools.partial(_chunk_kernel, sq_total=sq, block_q=bq,
-                          block_k=bk, num_kblocks=nk, quant=quant),
+        in_specs += _scale_row_specs(
+            bk, lambda b, i, j, kl: (b // group, 0, j))
+        operands += [k_scale[:, None], v_scale[:, None]]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, bq, d),
+                               lambda b, i, j, kl: (b, i, 0)),
+        scratch_shapes=_decode_scratch(bq, d),
+    )
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, sq_total=sq, block_q=bq,
+                          block_k=bk, num_kblocks=nk, group=group,
+                          quant=quant),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * sq_pad * t * d,
             bytes_accessed=bh * (sq_pad * d * q.dtype.itemsize
                                  + 2 * kv_bytes),
             transcendentals=bh * sq_pad * t),
         interpret=_interpret(),
-    )(*operands)
+    )(kv_len.astype(jnp.int32), *operands)
     return out[:, :sq]
 
 
@@ -1289,8 +1303,8 @@ def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
     # accumulate body is the SAME _decode_accumulate as the dense
     # kernel — only the k-block addressing differs (pages through the
     # scalar-prefetched table vs contiguous blocks). Quant mode adds
-    # the per-page scale rows ([1, 1, page], same table-resolved index
-    # map as the pools) and fuses the dequant in the shared body.
+    # the per-page scale rows ([1, 1, 1, page], same table-resolved
+    # index map as the pools) and fuses the dequant in the shared body.
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -1312,8 +1326,8 @@ def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
         _decode_accumulate(q_ref[0], k_ref[0, 0], v_ref[0, 0],
                            j * page_size, kv_len, sq,
                            m_scr, l_scr, acc_scr,
-                           ks=ks_ref[0] if quant else None,
-                           vs=vs_ref[0] if quant else None)
+                           ks=ks_ref[0, 0] if quant else None,
+                           vs=vs_ref[0, 0] if quant else None)
 
     @pl.when(j == num_page_slots - 1)
     def _finalize():
@@ -1346,9 +1360,6 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
     def k_index(r, j, tbl, kl):
         return ((r % hq) // group, tbl[r // hq, j], 0, 0)
 
-    def s_index(r, j, tbl, kl):
-        return ((r % hq) // group, tbl[r // hq, j], 0)
-
     in_specs = [
         pl.BlockSpec((1, qpad, d), lambda r, j, tbl, kl: (r, 0, 0)),
         pl.BlockSpec((1, 1, page, d), k_index),
@@ -1356,20 +1367,15 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, page), s_index),
-                     pl.BlockSpec((1, 1, page), s_index)]
-        operands += [k_scale, v_scale]
+        in_specs += _scale_row_specs(page, k_index, lead=(1, 1))
+        operands += [k_scale[:, :, None], v_scale[:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bh, num_slots),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, qpad, d),
                                lambda r, j, tbl, kl: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((qpad, _LANES), jnp.float32),
-            pltpu.VMEM((qpad, _LANES), jnp.float32),
-            pltpu.VMEM((qpad, d), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(qpad, d),
     )
     kv_bytes = k_pool.dtype.itemsize * num_slots * page * d \
         + (k_scale.dtype.itemsize * num_slots * page if quant else 0)

@@ -2,19 +2,23 @@
 ctypes (no pybind11 dependency — SURVEY §2.6: native where the reference is
 native: host tracer ≈ host_event_recorder.h, token feeder ≈ data_feed.cc).
 
-`lib()` compiles paddle_tpu/native/*.cc into _native.so on first use
-(cached by source mtime) and returns the ctypes handle, or None when no
-toolchain is available — callers must degrade to their pure-Python path.
+`lib()` compiles paddle_tpu/native/*.cc into _native.<hash>.so on first
+use and returns the ctypes handle, or None when no toolchain is
+available — callers must degrade to their pure-Python path. The name
+carries a hash of the sources' content, so a library built from other
+sources (a stale file copied along with the tree, whatever its mtime)
+is never loaded.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "_native.so")
 _SOURCES = ["host_tracer.cc", "token_feeder.cc", "tensor_store.cc"]
 
 _lock = threading.Lock()
@@ -22,19 +26,19 @@ _lib = None
 _tried = False
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_SO):
-        return True
-    so_mtime = os.path.getmtime(_SO)
-    return any(os.path.getmtime(os.path.join(_DIR, s)) > so_mtime
-               for s in _SOURCES)
+def _so_path() -> str:
+    h = hashlib.sha256()
+    for s in _SOURCES:
+        with open(os.path.join(_DIR, s), "rb") as f:
+            h.update(s.encode() + b"\0" + f.read())
+    return os.path.join(_DIR, f"_native.{h.hexdigest()[:16]}.so")
 
 
-def _build() -> bool:
+def _build(so: str) -> bool:
     # compile to a per-pid temp then os.rename: atomic on POSIX, so
     # concurrent dp-rank processes never load a half-written .so
     srcs = [os.path.join(_DIR, s) for s in _SOURCES]
-    tmp = f"{_SO}.tmp.{os.getpid()}"
+    tmp = f"{so}.tmp.{os.getpid()}"
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
            *srcs, "-o", tmp]
     try:
@@ -52,7 +56,13 @@ def _build() -> bool:
         except OSError:
             pass
         return False
-    os.replace(tmp, _SO)
+    os.replace(tmp, so)
+    for old in glob.glob(os.path.join(_DIR, "_native*.so")):
+        if old != so:  # built from sources that are gone
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
     return True
 
 
@@ -124,8 +134,8 @@ class CollectedEvent(ctypes.Structure):
 
 
 def lib():
-    """The ctypes handle to _native.so, building if needed; None if the
-    toolchain or build is unavailable."""
+    """The ctypes handle to the native library, building if needed;
+    None if the toolchain or build is unavailable."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -133,10 +143,11 @@ def lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if _needs_build() and not _build():
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            _lib = _bind(ctypes.CDLL(_SO))
+            _lib = _bind(ctypes.CDLL(so))
         except OSError:
             _lib = None
     return _lib

@@ -6,6 +6,9 @@ here fusion is the compiler's job with a Pallas override for the hot case.
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -44,6 +47,33 @@ def _sdpa_xla(q, k, v, mask=None, dropout_p=0.0, is_causal=False, scale=None,
     return jnp.swapaxes(out, 1, 2)  # back to [B,S,H,D]
 
 
+def _flash(query, key, value, causal, scale):
+    """The flash kernel, per shard under the active hybrid mesh. XLA's
+    partitioner cannot split a Mosaic kernel (lowering refuses one in a
+    program over several devices), so on a mesh the call is a shard_map
+    over the axes attention is parallel in: batch over the data axes,
+    heads over ``mp``. An axis that does not divide its dim stays out of
+    the specs, which replicates that dim."""
+    from ...distributed import topology
+    from ...kernels.flash_attention import flash_attention
+    mesh = topology.get_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(query, key, value, causal=causal,
+                               scale=scale)
+    from jax.sharding import PartitionSpec as P
+    from ...distributed.fleet.train_step import DATA_AXES
+    b, heads_kv = query.shape[0], key.shape[2]
+    data = tuple(a for a in DATA_AXES if mesh.shape[a] > 1)
+    n_data = math.prod(mesh.shape[a] for a in data)
+    mp = mesh.shape["mp"]
+    spec = P(data if data and b % n_data == 0 else None, None,
+             "mp" if mp > 1 and heads_kv % mp == 0 else None, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(query, key, value)
+
+
 @op("scaled_dot_product_attention")
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
@@ -52,8 +82,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     draws from `dropout_rng` if given, else the global eager key (tracing
     without an explicit rng disables dropout rather than baking a key)."""
     if dropout_p > 0.0 and training and dropout_rng is None:
-        import jax.core as _jcore
-        if not isinstance(query, _jcore.Tracer):
+        if not isinstance(query, jax.core.Tracer):
             from ...core import random as random_mod
             dropout_rng = random_mod.next_key()
     if not training:
@@ -74,20 +103,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                  and jax.default_backend() == "tpu")
     if use_flash:
         try:
-            from ...kernels.flash_attention import flash_attention
-            return flash_attention(query, key, value, causal=is_causal,
-                                   scale=scale)
+            return _flash(query, key, value, is_causal, scale)
         except NotImplementedError:
             pass  # declared unsupported shape (e.g. ragged causal):
-            #      the XLA path is the intended fallback
-        except Exception as e:  # pragma: no cover - kernel regression
-            # a genuine kernel/compile failure must NOT silently degrade
-            # to the (much slower) XLA path — that would hide a
-            # performance bug; warn loudly and fall back once per site
-            import warnings
-            warnings.warn(
-                f"flash_attention kernel failed unexpectedly and the XLA "
-                f"attention path was used instead ({type(e).__name__}: "
-                f"{e}); performance will be degraded", RuntimeWarning)
+            #      the XLA path is the intended fallback; any other
+            #      kernel failure propagates
     return _sdpa_xla(query, key, value, attn_mask, dropout_p, is_causal,
                      scale, dropout_rng)

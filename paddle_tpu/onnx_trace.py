@@ -309,7 +309,7 @@ def _walk(g: _Graph, jaxpr, in_names: List[str],
         if foldable:
             try:
                 if prim in _SUBJAXPR_PRIMS:
-                    from jax.core import jaxpr_as_fun
+                    from jax.extend.core import jaxpr_as_fun
                     sub = _sub_jaxpr(eqn)
                     vals = jaxpr_as_fun(sub)(*cvals)
                 else:

@@ -115,8 +115,6 @@ class CppExtensionModule:
         def impl(*xs):
             if not any(isinstance(x, jax.core.Tracer) for x in xs):
                 # eager: call the C function directly on host buffers
-                # (also sidesteps PJRT backends without host-callback
-                # support, e.g. tunneled devices)
                 return jnp.asarray(host_call(*[np.asarray(x)
                                                for x in xs]))
             oshape = shape_fn(*[tuple(x.shape) for x in xs])

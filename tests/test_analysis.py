@@ -244,7 +244,7 @@ class TestCollectiveAccounting:
         topology.set_hybrid_communicate_group(prev)
 
     def _world_psum(self):
-        from paddle_tpu.core.jaxshim import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()).reshape(-1), ("world",))
         return shard_map(lambda a: jax.lax.psum(a, "world"), mesh=mesh,

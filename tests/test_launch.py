@@ -333,7 +333,7 @@ def test_launch_multihost_global_mesh(tmp_path):
         from jax.experimental import multihost_utils
         global_x = multihost_utils.host_local_array_to_global_array(
             local, mesh, P("dp"))
-        from paddle_tpu.core.jaxshim import shard_map
+        from jax import shard_map
         out = jax.jit(shard_map(summed, mesh=mesh, in_specs=P("dp"),
                                 out_specs=P()))(global_x)
         # fully replicated result: every host reads its local replica
