@@ -27,8 +27,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...optimizer.optimizer import opt_key as _opt_key
+from ...core import flight_recorder
 from ...core.tensor import Tensor
-from ...jit.api import functional_call, _unwrap, _wrap
+from ...jit import compile_cache
+from ...jit.api import (_RetraceTracker, _note_built, functional_call,
+                        _unwrap, _wrap)
 from ...nn.layer import Layer
 from .. import topology
 from ..parallel.sharding import ShardingStrategy
@@ -372,6 +375,11 @@ class DistributedTrainStep:
         return sig
 
     def __call__(self, *batch):
+        # host side only, as TrainStep: prepare, dispatch
+        with flight_recorder.span("train.step") as sp:
+            return self._call(sp, batch)
+
+    def _call(self, sp, batch):
         params = self._params
         raw_batch = self._prepare(batch)
         lr = self.optimizer.get_lr()
@@ -381,7 +389,6 @@ class DistributedTrainStep:
                 *raw_batch)
         if self._warm_store is not None and self._warm_exe is None:
             from ...core import monitor
-            from ...jit import compile_cache
             try:
                 self._warm_exe = compile_cache.build_or_load(
                     self._warm_signature(args),
@@ -396,6 +403,7 @@ class DistributedTrainStep:
                 monitor.record_swallowed(
                     "jit.compile_cache.fleet_warm", e)
             self._warm_store = None  # warmed once; drift falls back
+            sp.set(compiled=1)
         if self._warm_exe is not None:
             try:
                 loss, new_vals, self._opt_state_tree = \
@@ -406,7 +414,14 @@ class DistributedTrainStep:
                     "jit.compile_cache.fleet_warm_step", e)
                 self._warm_exe = None
         if self._warm_exe is None:
+            cache_of = _RetraceTracker._cache_of
+            pre = cache_of(self._jitted) if flight_recorder.enabled \
+                else None
+            hits = compile_cache.persistent_cache_hits() \
+                if pre is not None else 0
             loss, new_vals, self._opt_state_tree = self._jitted(*args)
+            if pre is not None and (cache_of(self._jitted) or 0) > pre:
+                _note_built(sp, "fleet.train_step", hits)
         for p, v in zip(params, new_vals):
             p._data = v
         for p, st in zip(params, self._opt_state_tree):

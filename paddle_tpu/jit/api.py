@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import numpy as np
 
+from . import compile_cache
 from ..core import flight_recorder as _flight_recorder
 from ..core import monitor
 from ..core.tensor import Parameter, Tensor, no_grad
@@ -102,9 +103,10 @@ class _RetraceTracker:
         signature novelty is the (over-approximate) fallback. Runs for
         the flight recorder too — a post-mortem must show what
         compiled even when the metrics registry was never enabled
-        (monitor.record_retrace feeds both streams)."""
+        (monitor.record_retrace feeds both streams). Returns whether
+        the jit cache grew during this call (a program was built)."""
         if not (monitor.enabled or _flight_recorder.enabled):
-            return
+            return False
         cache = self._cache_of(jitted)
         known = cache is not None and pre_cache is not None
         compiled = known and cache > pre_cache
@@ -112,22 +114,34 @@ class _RetraceTracker:
             # flight-recorder-only mode: nothing compiled this call, so
             # skip the per-leaf signature walk — the black box only
             # needs the (rare) compile events, not a hot-path tax
-            return
+            return False
         sig = self._signature(trees)
         if sig in self._seen_set:
             if compiled:
                 monitor.record_retrace("donation_miss")
-            return
+            return compiled
         if compiled or not known:
             monitor.record_retrace(self._classify(sig))
         if len(self._seen) == self.MAX_SEEN:
             self._seen_set.discard(self._seen[0])  # deque evicts it
         self._seen_set.add(sig)
         self._seen.append(sig)
+        return compiled
 
 
 def _wrap(x):
     return Tensor(x) if isinstance(x, jax.Array) else x
+
+
+def _note_built(sp, label: str, hits_before: int):
+    """The jit cache grew during the call ``sp`` (a ``train.step``
+    span) covers: it traced, lowered and compiled the step (or fetched
+    it from jax's persistent cache) before it dispatched it."""
+    sp.set(compiled=1)
+    _flight_recorder.record_span(
+        "jit.program", sp.start_ns, _flight_recorder.now_ns(),
+        parent=sp.id, label=label,
+        source=compile_cache.compile_source(hits_before))
 
 
 def functional_call(layer: Layer, params_and_buffers: Dict[str, Any],
@@ -454,6 +468,12 @@ class TrainStep:
             self._offload = False
 
     def __call__(self, *batch):
+        # the host side of one step (argument flattening, tracker,
+        # dispatch) is a train.step span; the device runs on after it
+        with _flight_recorder.span("train.step") as sp:
+            return self._call(sp, batch)
+
+    def _call(self, sp, batch):
         params = self._params_cache
         if self._opt_state_tree is None:
             # seed from the optimizer's own state when present (e.g. a
@@ -473,7 +493,6 @@ class TrainStep:
                 np.float32(lr), np.int32(self.optimizer._step_count),
                 *raw_batch)
         if self._warm_store is not None and self._warm_exe is None:
-            from . import compile_cache
             self._warm_exe = compile_cache.build_or_load(
                 self._warm_signature(args),
                 lambda: self._aot_jitted.lower(*args),
@@ -482,6 +501,7 @@ class TrainStep:
                            donation=self._aot_donate),
                 label="train_step")
             self._warm_store = None  # warmed once; drift falls back
+            sp.set(compiled=1)
         if self._warm_exe is not None:
             try:
                 loss, new_vals, self._opt_state_tree = \
@@ -495,11 +515,13 @@ class TrainStep:
                 self._warm_exe = None
         if self._warm_exe is None:
             pre_cache = self._tracker.pre(self._jitted)
+            hits = compile_cache.persistent_cache_hits() \
+                if pre_cache is not None else 0
             loss, new_vals, self._opt_state_tree = self._jitted(*args)
-            if monitor.enabled or _flight_recorder.enabled:
-                # donated args keep their aval metadata
-                self._tracker.observe(
-                    self._jitted, (args[0], raw_batch), pre_cache)
+            # donated args keep their aval metadata
+            if self._tracker.observe(
+                    self._jitted, (args[0], raw_batch), pre_cache):
+                _note_built(sp, "train_step", hits)
         for p, v in zip(params, new_vals):
             p._data = v
         # mirror the functional state back so optimizer.state_dict()

@@ -76,7 +76,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 
-from ..core import monitor
+from ..core import flight_recorder, monitor
 
 __all__ = [
     "ExecutableStore",
@@ -85,9 +85,11 @@ __all__ = [
     "cache_key",
     "callable_signature",
     "compile_or_load",
+    "compile_source",
     "default_store",
     "enable_compile_cache",
     "network_signature",
+    "persistent_cache_hits",
     "scalar_signature",
     "set_default_store",
     "source_hash",
@@ -106,6 +108,50 @@ _DEFAULT_STORE: Optional["ExecutableStore"] = None
 
 
 # --------------------------------------------------- process-global cache
+
+_persistent_hits = 0
+_listening = False
+
+
+def _on_jax_event(event: str, **_):
+    global _persistent_hits
+    if event == "/jax/compilation_cache/cache_hits":
+        _persistent_hits += 1
+
+
+def persistent_cache_hits() -> int:
+    """How many XLA compiles jax's persistent compilation cache has
+    answered in this process since the first call (which registers the
+    listener): a count that moved across a ``compile()`` means the
+    binary came from disk."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_listener(_on_jax_event)
+        _listening = True
+    return _persistent_hits
+
+
+def compile_source(hits_before: int) -> str:
+    """``persistent_cache`` or ``compile``: where the XLA compile that
+    ran since ``hits_before = persistent_cache_hits()`` got its binary
+    (the ``source`` field of a ``jit.program`` span)."""
+    return "persistent_cache" if persistent_cache_hits() > hits_before \
+        else "compile"
+
+
+def _compile(lowered, info: dict):
+    hits = persistent_cache_hits()
+    exe = lowered.compile()
+    info["source"] = compile_source(hits)
+    return exe
+
+
+def _lower(lower_fn, info: dict):
+    t0 = flight_recorder.now_ns()
+    lowered = lower_fn()
+    info["lower_s"] = round((flight_recorder.now_ns() - t0) * 1e-9, 6)
+    return lowered
+
 
 def _placed_by_env() -> str:
     return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
@@ -585,21 +631,37 @@ class ExecutableStore:
                 pass
 
     # ---------------------------------------------------------- combined
-    def get_or_compile(self, lowered, *, extra: Optional[dict] = None,
-                       label: str = ""):
-        """The traced AOT entry point: key the lowered program, load
-        the stored executable on a hit (zero XLA compiles), else
-        compile and persist. Always returns a callable ``Compiled``."""
-        key = self.key_for(lowered, extra=extra)
-        exe = self.load(key, label=label)
+    def _moved_bytes(self) -> int:
+        return self.stats["bytes_loaded"] + self.stats["bytes_saved"]
+
+    def _load_or_compile(self, key: str, lowered, label: str,
+                         info: dict, load: bool = True):
+        """The entry under ``key``, else a fresh compile that is then
+        persisted; ``info`` learns which (``source``) and the bytes
+        read or written."""
+        b0 = self._moved_bytes()
+        exe = self.load(key, label=label) if load else None
         if exe is not None:
-            return exe
-        exe = lowered.compile()
-        self.save(key, exe, label=label)
+            info["source"] = "store"
+        else:
+            exe = _compile(lowered, info)
+            self.save(key, exe, label=label)
+        info["bytes"] = self._moved_bytes() - b0
         return exe
 
+    def get_or_compile(self, lowered, *, extra: Optional[dict] = None,
+                       label: str = "", info: Optional[dict] = None):
+        """The traced AOT entry point: key the lowered program, load
+        the stored executable on a hit (zero XLA compiles), else
+        compile and persist. Always returns a callable ``Compiled``.
+        ``info`` (the ``jit.program`` span's fields) is filled in."""
+        return self._load_or_compile(
+            self.key_for(lowered, extra=extra), lowered, label,
+            {} if info is None else info)
+
     def get_or_build(self, signature: Optional[dict], lower_fn, *,
-                     extra: Optional[dict] = None, label: str = ""):
+                     extra: Optional[dict] = None, label: str = "",
+                     info: Optional[dict] = None):
         """The TRACELESS AOT entry point. ``signature`` structurally
         identifies the program (see :func:`network_signature` /
         :func:`aval_signature`); on a manifest hit the executable
@@ -610,20 +672,25 @@ class ExecutableStore:
         rewrites the ref for the next relaunch. Under
         ``PADDLE_COMPILE_CACHE_VERIFY=1`` the trace runs regardless and
         a lying ref is recorded as ``misses{cause=stale_ref}`` and
-        replaced."""
+        replaced. ``info`` (the ``jit.program`` span's fields) is
+        filled in."""
+        info = {} if info is None else info
         ref_key = None
         failed_key = None
         if signature is not None:
             ref_key = _signature_key(signature, extra)
             exe_key = self._read_ref(ref_key)
             if exe_key is not None and not _verify_mode():
+                b0 = self._moved_bytes()
                 exe = self.load(exe_key, label=label)
                 if exe is not None:
+                    info.update(source="store",
+                                bytes=self._moved_bytes() - b0)
                     return exe
                 # entry vanished/corrupt under the ref (miss recorded
                 # by load): re-derive everything through the traced path
                 failed_key = exe_key
-        lowered = lower_fn()
+        lowered = _lower(lower_fn, info)
         true_key = self.key_for(lowered, extra=extra)
         if ref_key is not None and _verify_mode():
             stored = self._read_ref(ref_key)
@@ -637,11 +704,8 @@ class ExecutableStore:
         # when the ref's target just failed and IS this program's key,
         # skip the second lookup — one corruption must count one miss,
         # not corrupt+absent
-        exe = None if true_key == failed_key \
-            else self.load(true_key, label=label)
-        if exe is None:
-            exe = lowered.compile()
-            self.save(true_key, exe, label=label)
+        exe = self._load_or_compile(true_key, lowered, label, info,
+                                    load=true_key != failed_key)
         if ref_key is not None:
             self._write_ref(ref_key, true_key)
         return exe
@@ -673,9 +737,15 @@ def compile_or_load(lowered, *, store: Optional[ExecutableStore] = None,
     process-default store; with no store active this is exactly
     ``lowered.compile()``)."""
     store = store if store is not None else default_store()
-    if store is None:
-        return lowered.compile()
-    return store.get_or_compile(lowered, extra=extra, label=label)
+    with flight_recorder.span("jit.program", label=label) as sp:
+        info = {}
+        if store is None:
+            exe = _compile(lowered, info)
+        else:
+            exe = store.get_or_compile(lowered, extra=extra, label=label,
+                                       info=info)
+        sp.set(**info)
+    return exe
 
 
 def build_or_load(signature: Optional[dict], lower_fn, *,
@@ -685,7 +755,12 @@ def build_or_load(signature: Optional[dict], lower_fn, *,
     ``lower_fn`` is never called (zero traces, zero compiles). With no
     store active this is ``lower_fn().compile()``."""
     store = store if store is not None else default_store()
-    if store is None:
-        return lower_fn().compile()
-    return store.get_or_build(signature, lower_fn, extra=extra,
-                              label=label)
+    with flight_recorder.span("jit.program", label=label) as sp:
+        info = {}
+        if store is None:
+            exe = _compile(_lower(lower_fn, info), info)
+        else:
+            exe = store.get_or_build(signature, lower_fn, extra=extra,
+                                     label=label, info=info)
+        sp.set(**info)
+    return exe

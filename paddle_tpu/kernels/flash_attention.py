@@ -258,6 +258,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None):
             ],
             cost_estimate=cost,
             interpret=_interpret(),
+            name="flash_fwd_whole_k",
         )(q, k, v)
         return out, lse
     kernel = functools.partial(
@@ -287,6 +288,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None):
         ],
         cost_estimate=cost,
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -666,6 +668,7 @@ def _flash_bwd_fused_tiled(q, k, v, lse_b, delta_b, do, scale, causal,
             pltpu.VMEM((bk, d), jnp.float32),   # dv tile accumulator
         ],
         interpret=_interpret(),
+        name="flash_bwd_tiled",
     )(q, k, v, do, lse_b, delta_b)
     return dq, dk, dv
 
@@ -707,6 +710,7 @@ def _flash_bwd_fused(q, k, v, lse_b, delta_b, do, scale, causal,
             pltpu.VMEM((sk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd",
     )(q, k, v, do, lse_b, delta_b)
     return dq, dk, dv
 
@@ -758,6 +762,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse_b, delta_b)
 
     col_specs = [
@@ -787,6 +792,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse_b, delta_b)
     return dq, dk, dv
 
@@ -1009,6 +1015,7 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
                                  + 2 * kv_bytes),
             transcendentals=bh * qpad * t),
         interpret=_interpret(),
+        name="flash_decode",
     )(kv_len.astype(jnp.int32), *operands)
     return out[:, :sq]
 
@@ -1226,6 +1233,7 @@ def _chunk_pallas(q, k_cache, v_cache, kv_len, scale,
                                  + 2 * kv_bytes),
             transcendentals=bh * sq_pad * t),
         interpret=_interpret(),
+        name="flash_chunk",
     )(kv_len.astype(jnp.int32), *operands)
     return out[:, :sq]
 
@@ -1391,6 +1399,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
                                  + 2 * kv_bytes),
             transcendentals=bh * qpad * num_slots * page),
         interpret=_interpret() if interpret is None else interpret,
+        name="flash_decode_paged",
     )(table, kvl, *operands)
     return out[:, :sq]
 

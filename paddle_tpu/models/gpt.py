@@ -13,6 +13,7 @@ import math
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -191,14 +192,20 @@ class GPTBlock(Layer):
 
     def forward(self, x, attn_mask=None, cache=None, layer_idx=0,
                 decode=False):
+        # the regions a device trace (and a per-region roofline) tells
+        # apart: embed / attn / mlp / lm_head
         if cache is not None:
-            a, cache = self.attn(self.ln1(x), attn_mask, cache=cache,
-                                 layer_idx=layer_idx, decode=decode)
-            x = x + a
-            x = x + self.mlp(self.ln2(x))
+            with jax.named_scope("attn"):
+                a, cache = self.attn(self.ln1(x), attn_mask, cache=cache,
+                                     layer_idx=layer_idx, decode=decode)
+                x = x + a
+            with jax.named_scope("mlp"):
+                x = x + self.mlp(self.ln2(x))
             return x, cache
-        x = x + self.attn(self.ln1(x), attn_mask)
-        x = x + self.mlp(self.ln2(x))
+        with jax.named_scope("attn"):
+            x = x + self.attn(self.ln1(x), attn_mask)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.ln2(x))
         return x
 
 
@@ -220,6 +227,7 @@ class GPTEmbeddings(Layer):
         self.drop = Dropout(cfg.dropout)
         self.sequence_parallel = cfg.sequence_parallel
 
+    @jax.named_scope("embed")
     def forward(self, input_ids, pos=None):
         b, s = input_ids.shape
         from .. import ops
@@ -233,6 +241,7 @@ class GPTEmbeddings(Layer):
         return self.drop(x)
 
 
+@jax.named_scope("lm_head")
 def _lm_logits(x, head, wte_weight):
     """Final head dispatch (tied vs separate), with the output constraint.
     Shared by GPTForCausalLM and GPTHeadPipe."""
@@ -436,6 +445,7 @@ class GPTForCausalLM(Layer):
         from ..generation.api import generate as _generate
         return _generate(self, input_ids, max_new_tokens, **kwargs)
 
+    @jax.named_scope("lm_head")
     def _fused_loss(self, hidden, labels, w):
         """Chunked LM-head + cross-entropy: scan sequence chunks, each
         chunk's logits live only inside its (rematerialized) scan step.

@@ -386,14 +386,17 @@ class Profiler:
             _host_disable()
             events = self._pending_events + _host_collect()
             self._pending_events = []
-            # sampled serving-request spans (flight recorder) that
-            # completed inside the record window join the same trace:
-            # queue-wait/prefill/decode segments render as "ph": "X"
-            # slices next to RecordEvent spans and counter tracks
+            # flight-recorder spans that completed inside the record
+            # window join the same trace: scheduler iterations, syncs,
+            # each request's queue-wait/prefill and the sampled decode
+            # segments render as "ph": "X" slices next to RecordEvent
+            # spans and counter tracks (the recorder's monotonic clock
+            # is perf_counter's: tests/test_flight_recorder.py)
             from ..core import flight_recorder
             t0_ns = int(getattr(self, "_record_t0", 0) * 1e9)
-            events += flight_recorder.spans_between(
-                t0_ns, time.perf_counter_ns())
+            events += [(s.name, s.start_ns, s.end_ns, s.tid, 0)
+                       for s in flight_recorder.spans_between(
+                           t0_ns, flight_recorder.now_ns())]
         else:
             events = []
         if not self.timer_only:

@@ -60,6 +60,15 @@ Host syncs are confined to the scheduler's poll cadence (every
 ``poll_every`` decode steps: two [batch]-lane reads), one small sync
 per admission (the TTFT measurement point), and one row read per
 completion — the decode hot loop itself dispatches without waiting.
+
+Every scheduler iteration, admission, blocking read, decode dispatch
+and poll is a flight-recorder span (``serve.step`` > ``serve.admit`` /
+``serve.dispatch`` / ``serve.poll`` > ``serve.sync{site=...}``;
+``core/flight_recorder.DECLARED_SPANS``), and every request records
+``serve.queue_wait`` + ``serve.prefill``. Each boundary is stamped once,
+on the recorder's clock; the request's ``admitted_at`` /
+``first_token_at``, the TTFT and per-token latency metrics, the goodput
+charges and the per-request cost are all derived from those stamps.
 """
 from __future__ import annotations
 
@@ -121,737 +130,755 @@ class ServingEngine:
                  kv_pages: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  hbm_budget=None):
-        from ..inference.precision import serving_params
-        from ..jit.api import _unwrap, functional_call
+        with flight_recorder.span("setup.engine_init"):
+            from ..inference.precision import serving_params
+            from ..jit.api import _unwrap, functional_call
 
-        layer = getattr(config, "_layer", None)
-        if layer is None:
-            raise ValueError("ServingEngine needs a live layer: use "
-                             "Config.from_layer(...) (artifact-backed "
-                             "configs have no cache protocol to drive)")
-        opts = getattr(config, "_generation", None)
-        if opts is None:
-            raise ValueError("ServingEngine reuses the generation "
-                             "serving setup: call "
-                             "Config.enable_generation() first")
-        sopts = getattr(config, "_serving", None) or {}
+            layer = getattr(config, "_layer", None)
+            if layer is None:
+                raise ValueError("ServingEngine needs a live layer: use "
+                                 "Config.from_layer(...) (artifact-backed "
+                                 "configs have no cache protocol to drive)")
+            opts = getattr(config, "_generation", None)
+            if opts is None:
+                raise ValueError("ServingEngine reuses the generation "
+                                 "serving setup: call "
+                                 "Config.enable_generation() first")
+            sopts = getattr(config, "_serving", None) or {}
 
-        def _opt(kw, key, default):
-            if kw is not None:
-                return kw
-            v = sopts.get(key)
-            return default if v is None else v
+            def _opt(kw, key, default):
+                if kw is not None:
+                    return kw
+                v = sopts.get(key)
+                return default if v is None else v
 
-        self.max_queue = int(_opt(max_queue, "max_queue", 64))
-        self.poll_every = max(1, int(_opt(poll_every, "poll_every", 4)))
-        self.drain_timeout_s = float(  # lint: host-sync-ok (config coercion)
-            _opt(drain_timeout_s, "drain_timeout_s", 30.0))
-        self.default_deadline_s = _opt(default_deadline_s,
-                                       "default_deadline_s", None)
-        cache_max_len = _opt(cache_max_len, "cache_max_len", None)
-        # per-request tracing: 1-in-N requests carry full queue-wait /
-        # prefill / decode-segment spans into the flight recorder (and
-        # through it the Perfetto export). Default 8 keeps the span
-        # cost off the steady-state p95; 0 turns tracing off.
-        env_sample = os.environ.get("PADDLE_TRACE_SAMPLE", "").strip()
-        if env_sample.lower() in ("off", "false", "no"):
-            env_default = 0
-        elif env_sample.isdigit():
-            env_default = int(env_sample)
-        else:
-            if env_sample:  # garbage must not silently re-enable
-                monitor.record_swallowed(
-                    "serving.trace_sample",
-                    ValueError(f"PADDLE_TRACE_SAMPLE={env_sample!r}"))
-            env_default = 8
-        self.trace_sample = int(_opt(trace_sample, "trace_sample",
-                                     env_default))
+            self.max_queue = int(_opt(max_queue, "max_queue", 64))
+            self.poll_every = max(1, int(_opt(poll_every, "poll_every", 4)))
+            self.drain_timeout_s = float(  # lint: host-sync-ok (config coercion)
+                _opt(drain_timeout_s, "drain_timeout_s", 30.0))
+            self.default_deadline_s = _opt(default_deadline_s,
+                                           "default_deadline_s", None)
+            cache_max_len = _opt(cache_max_len, "cache_max_len", None)
+            # per-request tracing: every request records serve.queue_wait +
+            # serve.prefill; 1-in-N requests additionally carry a decode
+            # segment per poll (and their prefill chunks) into the flight
+            # recorder (and through it the Perfetto export). Default 8 keeps
+            # the per-poll span cost off the steady-state p95; 0 turns the
+            # sampled segments off.
+            env_sample = os.environ.get("PADDLE_TRACE_SAMPLE", "").strip()
+            if env_sample.lower() in ("off", "false", "no"):
+                env_default = 0
+            elif env_sample.isdigit():
+                env_default = int(env_sample)
+            else:
+                if env_sample:  # garbage must not silently re-enable
+                    monitor.record_swallowed(
+                        "serving.trace_sample",
+                        ValueError(f"PADDLE_TRACE_SAMPLE={env_sample!r}"))
+                env_default = 8
+            self.trace_sample = int(_opt(trace_sample, "trace_sample",
+                                         env_default))
 
-        # precision: the same serving cast/quant pass the Predictor's
-        # run() path audits (int8-compute may swap modules; int4
-        # weight-only packs Linear weights two-nibbles-per-byte)
-        self._sp = serving_params(layer, config)
-        layer = self._sp.layer
-        layer.eval()
-        self.network = layer
-        self.config = config
+            # precision: the same serving cast/quant pass the Predictor's
+            # run() path audits (int8-compute may swap modules; int4
+            # weight-only packs Linear weights two-nibbles-per-byte)
+            with flight_recorder.span("setup.state"):
+                self._sp = serving_params(layer, config)
+            layer = self._sp.layer
+            layer.eval()
+            self.network = layer
+            self.config = config
 
-        # low-bit KV cache (ROADMAP item 4): the serving knob wins over
-        # the generation one, PADDLE_KV_CACHE_DTYPE fills the gap. The
-        # dtype is baked into every program below (prefill creates the
-        # quantized cache in-trace; decode dequantizes in-kernel).
-        from ..generation.kv_cache import resolve_cache_dtype
-        explicit_cd = sopts.get("kv_cache_dtype")
-        if explicit_cd is None:
-            explicit_cd = opts.get("kv_cache_dtype")
-        self.cache_dtype = resolve_cache_dtype(explicit_cd)
-        cache_kw = {} if self.cache_dtype is None \
-            else {"cache_dtype": self.cache_dtype}
+            # low-bit KV cache (ROADMAP item 4): the serving knob wins over
+            # the generation one, PADDLE_KV_CACHE_DTYPE fills the gap. The
+            # dtype is baked into every program below (prefill creates the
+            # quantized cache in-trace; decode dequantizes in-kernel).
+            from ..generation.kv_cache import resolve_cache_dtype
+            explicit_cd = sopts.get("kv_cache_dtype")
+            if explicit_cd is None:
+                explicit_cd = opts.get("kv_cache_dtype")
+            self.cache_dtype = resolve_cache_dtype(explicit_cd)
+            cache_kw = {} if self.cache_dtype is None \
+                else {"cache_dtype": self.cache_dtype}
 
-        self._cfg = GenerationConfig(
-            do_sample=opts["do_sample"], temperature=opts["temperature"],
-            top_k=opts["top_k"], top_p=opts["top_p"],
-            eos_token_id=opts["eos_token_id"],
-            pad_token_id=opts["pad_token_id"])
-        self.max_new_tokens = int(opts["max_new_tokens"])
-        self.max_batch = int(opts["max_batch"])
-        if self.max_batch < 1:
-            raise ValueError("max_batch must be >= 1")
+            self._cfg = GenerationConfig(
+                do_sample=opts["do_sample"], temperature=opts["temperature"],
+                top_k=opts["top_k"], top_p=opts["top_p"],
+                eos_token_id=opts["eos_token_id"],
+                pad_token_id=opts["pad_token_id"])
+            self.max_new_tokens = int(opts["max_new_tokens"])
+            self.max_batch = int(opts["max_batch"])
+            if self.max_batch < 1:
+                raise ValueError("max_batch must be >= 1")
 
-        # speculative decoding on the slots: the per-poll decode step
-        # becomes a fused ngram-draft + single-dispatch verify over the
-        # live lanes — each dispatch advances every live row by 1..k+1
-        # tokens. Only the model-free self-speculative drafter runs on
-        # the engine (a draft model would need its own per-slot cache
-        # admission path); generate()/the Predictor serve draft mode.
-        from ..generation.speculative import as_spec_config
-        self._spec = as_spec_config(opts.get("speculative"),
-                                    opts.get("draft_model"))
-        if self._spec is not None and self._spec.mode != "ngram":
-            raise ValueError(
-                "ServingEngine supports speculative='ngram' (the "
-                "model-free prompt-lookup drafter); draft-model "
-                "speculation is a generate()/Predictor path for now")
-        overhang = self._spec.k if self._spec is not None else 0
-
-        max_pos = getattr(getattr(layer, "cfg", None),
-                          "max_position_embeddings", None)
-        buckets = sorted(
-            int(b) for b in opts["prefill_buckets"]
-            if max_pos is None
-            or b + self.max_new_tokens + overhang <= int(max_pos))
-        if not buckets:
-            raise ValueError(
-                f"no prefill bucket in {opts['prefill_buckets']} fits "
-                f"max_position_embeddings={max_pos} with "
-                f"max_new_tokens={self.max_new_tokens}"
-                + (f" + speculative overhang {overhang}" if overhang
-                   else ""))
-        self.buckets = buckets
-        self.max_len = int(cache_max_len) if cache_max_len else \
-            _round_up(buckets[-1] + self.max_new_tokens + overhang)
-        if self.max_len < buckets[-1] + self.max_new_tokens + overhang:
-            raise ValueError(
-                f"cache_max_len {self.max_len} < largest bucket "
-                f"{buckets[-1]} + max_new_tokens {self.max_new_tokens}"
-                + (f" + speculative verify-window overhang {overhang} "
-                   "(the last window's unaccepted draft tokens still "
-                   "write their KV before rollback)" if overhang
-                   else "")
-                + "; the shared ring cache would wrap under a "
-                "full-length request")
-
-        # ------------------------------------------------- paged KV cache
-        # block-table paged cache + shared-prefix reuse (ROADMAP item 3):
-        # K/V live in a pool of fixed-size pages, each slot holds an
-        # int32 page table, admission is gated on FREE PAGES (memory)
-        # as well as free slots (batch lanes), and identical prompt
-        # prefixes reference the same pages copy-on-write.
-        self._alloc = None
-        self._overhang = overhang
-        if bool(_opt(paged, "paged", False)):  # lint: host-sync-ok (config coercion)
-            from ..generation.paged_cache import PageAllocator
-            env_ps = os.environ.get("PADDLE_KV_PAGE_SIZE", "").strip()
-            if env_ps and not env_ps.isdigit():
-                # garbage must not silently re-shape the cache (same
-                # contract as PADDLE_TRACE_SAMPLE above)
-                monitor.record_swallowed(
-                    "serving.kv_page_size",
-                    ValueError(f"PADDLE_KV_PAGE_SIZE={env_ps!r}"))
-            ps = int(_opt(kv_page_size, "kv_page_size",
-                          int(env_ps) if env_ps.isdigit() else 128))
-            if ps < 1 or self.max_len % ps:
+            # speculative decoding on the slots: the per-poll decode step
+            # becomes a fused ngram-draft + single-dispatch verify over the
+            # live lanes — each dispatch advances every live row by 1..k+1
+            # tokens. Only the model-free self-speculative drafter runs on
+            # the engine (a draft model would need its own per-slot cache
+            # admission path); generate()/the Predictor serve draft mode.
+            from ..generation.speculative import as_spec_config
+            self._spec = as_spec_config(opts.get("speculative"),
+                                        opts.get("draft_model"))
+            if self._spec is not None and self._spec.mode != "ngram":
                 raise ValueError(
-                    f"kv_page_size {ps} must divide the cache length "
-                    f"{self.max_len} (PADDLE_KV_PAGE_SIZE / "
-                    "enable_serving(kv_page_size=...))")
-            self.page_size = ps
-            self.pages_per_row = self.max_len // ps
-            # default pool: the dense cache's exact HBM footprint
-            # (max_batch rows of max_len) plus the reserved null page —
-            # the capacity win comes from requests that don't USE
-            # max_len and from shared prefixes, not from a bigger pool
-            n_pages = int(_opt(kv_pages, "kv_pages",
-                               self.max_batch * self.pages_per_row + 1))
-            # a pool that cannot cover ONE max-size request would stall
-            # the queue head forever with no error — same fail-fast
-            # contract as the dense "ring would wrap" check above
-            worst = -(-(buckets[-1] + self.max_new_tokens + overhang)
-                      // ps)
-            if n_pages - 1 < worst:
+                    "ServingEngine supports speculative='ngram' (the "
+                    "model-free prompt-lookup drafter); draft-model "
+                    "speculation is a generate()/Predictor path for now")
+            overhang = self._spec.k if self._spec is not None else 0
+
+            max_pos = getattr(getattr(layer, "cfg", None),
+                              "max_position_embeddings", None)
+            buckets = sorted(
+                int(b) for b in opts["prefill_buckets"]
+                if max_pos is None
+                or b + self.max_new_tokens + overhang <= int(max_pos))
+            if not buckets:
                 raise ValueError(
-                    f"kv_pages {n_pages} (1 reserved) cannot hold one "
-                    f"full-size request: bucket {buckets[-1]} + "
-                    f"max_new_tokens {self.max_new_tokens}"
+                    f"no prefill bucket in {opts['prefill_buckets']} fits "
+                    f"max_position_embeddings={max_pos} with "
+                    f"max_new_tokens={self.max_new_tokens}"
                     + (f" + speculative overhang {overhang}" if overhang
+                       else ""))
+            self.buckets = buckets
+            self.max_len = int(cache_max_len) if cache_max_len else \
+                _round_up(buckets[-1] + self.max_new_tokens + overhang)
+            if self.max_len < buckets[-1] + self.max_new_tokens + overhang:
+                raise ValueError(
+                    f"cache_max_len {self.max_len} < largest bucket "
+                    f"{buckets[-1]} + max_new_tokens {self.max_new_tokens}"
+                    + (f" + speculative verify-window overhang {overhang} "
+                       "(the last window's unaccepted draft tokens still "
+                       "write their KV before rollback)" if overhang
                        else "")
-                    + f" needs {worst} pages of {ps}; raise kv_pages "
-                    "or kv_page_size")
-            self._alloc = PageAllocator(n_pages, ps)
-            self._page_seen: Dict[str, int] = {}
-            self._pending_pages: Dict[int, tuple] = {}
-            self._row_pages: List[Optional[list]] = [None] * self.max_batch
-            self._page_blocked = False
-            # (req.id, allocator version) of the last head whose plan
-            # failed to commit: while nothing changed in the pool, the
-            # pump loop skips re-hashing the prompt and re-walking the
-            # registry on every iteration
-            self._blocked_key = None
+                    + "; the shared ring cache would wrap under a "
+                    "full-length request")
 
-        # ---------------------------------------------- chunked prefill
-        # head-of-line fix (ROADMAP item 2a): prompts longer than
-        # prefill_chunk_tokens are admitted C tokens at a time, ONE
-        # chunk per scheduler iteration, interleaved with the decode
-        # dispatch — in-flight streams keep producing tokens while the
-        # long prompt fills a persistent batch-1 SIDE cache that the
-        # ordinary admit program installs at the final chunk. Opt-in
-        # (kwarg > enable_serving > PADDLE_PREFILL_CHUNK_TOKENS); paged
-        # engines require page alignment so every completed chunk ends
-        # on a page boundary the span-install can commit.
-        env_ct = os.environ.get("PADDLE_PREFILL_CHUNK_TOKENS",
-                                "").strip()
-        if env_ct and not env_ct.isdigit():
-            # garbage must not silently enable/resize chunking (same
-            # contract as PADDLE_TRACE_SAMPLE / PADDLE_KV_PAGE_SIZE)
-            monitor.record_swallowed(
-                "serving.prefill_chunk_tokens",
-                ValueError(f"PADDLE_PREFILL_CHUNK_TOKENS={env_ct!r}"))
-        ct = _opt(prefill_chunk_tokens, "prefill_chunk_tokens",
-                  int(env_ct) if env_ct.isdigit() else None)
-        self.prefill_chunk_tokens = None
-        if ct is not None:
-            ct = int(ct)
-            if ct < 1:
-                raise ValueError(
-                    f"prefill_chunk_tokens {ct} must be >= 1 "
-                    "(PADDLE_PREFILL_CHUNK_TOKENS / "
-                    "enable_serving(prefill_chunk_tokens=...))")
-            if self._alloc is not None and ct % self.page_size:
-                raise ValueError(
-                    f"prefill_chunk_tokens {ct} must be a multiple of "
-                    f"kv_page_size {self.page_size}: every completed "
-                    "chunk must end on a page boundary so its span "
-                    "installs into whole committed pages")
-            # the final chunk pads to the chunk width, so the side
-            # cache writes up to ceil(bucket/C)*C positions — past
-            # max_len the ring modulo would WRAP the write onto the
-            # prompt's own prefix (silent corruption, not an error)
-            padded_top = -(-buckets[-1] // ct) * ct
-            if ct < buckets[-1] and padded_top > self.max_len:
-                raise ValueError(
-                    f"prefill_chunk_tokens {ct}: the largest bucket "
-                    f"{buckets[-1]} pads to {padded_top} chunked "
-                    f"tokens, past the cache length {self.max_len} — "
-                    "the final padded chunk would wrap the ring onto "
-                    "the prompt prefix; raise prefill_chunk_tokens or "
-                    "cache_max_len")
-            self.prefill_chunk_tokens = ct
-        # chunking can only ever trigger for prompts LONGER than one
-        # chunk; with every bucket at or under C the programs would be
-        # dead weight in warmup
-        self._chunk_enabled = (self.prefill_chunk_tokens is not None
-                               and self.prefill_chunk_tokens
-                               < buckets[-1])
-        self._chunking = None   # the (single) in-flight chunked
-        #                         admission's scheduler state
+            # ------------------------------------------------- paged KV cache
+            # block-table paged cache + shared-prefix reuse (ROADMAP item 3):
+            # K/V live in a pool of fixed-size pages, each slot holds an
+            # int32 page table, admission is gated on FREE PAGES (memory)
+            # as well as free slots (batch lanes), and identical prompt
+            # prefixes reference the same pages copy-on-write.
+            self._alloc = None
+            self._overhang = overhang
+            if bool(_opt(paged, "paged", False)):  # lint: host-sync-ok (config coercion)
+                from ..generation.paged_cache import PageAllocator
+                env_ps = os.environ.get("PADDLE_KV_PAGE_SIZE", "").strip()
+                if env_ps and not env_ps.isdigit():
+                    # garbage must not silently re-shape the cache (same
+                    # contract as PADDLE_TRACE_SAMPLE above)
+                    monitor.record_swallowed(
+                        "serving.kv_page_size",
+                        ValueError(f"PADDLE_KV_PAGE_SIZE={env_ps!r}"))
+                ps = int(_opt(kv_page_size, "kv_page_size",
+                              int(env_ps) if env_ps.isdigit() else 128))
+                if ps < 1 or self.max_len % ps:
+                    raise ValueError(
+                        f"kv_page_size {ps} must divide the cache length "
+                        f"{self.max_len} (PADDLE_KV_PAGE_SIZE / "
+                        "enable_serving(kv_page_size=...))")
+                self.page_size = ps
+                self.pages_per_row = self.max_len // ps
+                # default pool: the dense cache's exact HBM footprint
+                # (max_batch rows of max_len) plus the reserved null page —
+                # the capacity win comes from requests that don't USE
+                # max_len and from shared prefixes, not from a bigger pool
+                n_pages = int(_opt(kv_pages, "kv_pages",
+                                   self.max_batch * self.pages_per_row + 1))
+                # a pool that cannot cover ONE max-size request would stall
+                # the queue head forever with no error — same fail-fast
+                # contract as the dense "ring would wrap" check above
+                worst = -(-(buckets[-1] + self.max_new_tokens + overhang)
+                          // ps)
+                if n_pages - 1 < worst:
+                    raise ValueError(
+                        f"kv_pages {n_pages} (1 reserved) cannot hold one "
+                        f"full-size request: bucket {buckets[-1]} + "
+                        f"max_new_tokens {self.max_new_tokens}"
+                        + (f" + speculative overhang {overhang}" if overhang
+                           else "")
+                        + f" needs {worst} pages of {ps}; raise kv_pages "
+                        "or kv_page_size")
+                self._alloc = PageAllocator(n_pages, ps)
+                self._page_seen: Dict[str, int] = {}
+                self._pending_pages: Dict[int, tuple] = {}
+                self._row_pages: List[Optional[list]] = [None] * self.max_batch
+                self._page_blocked = False
+                # (req.id, allocator version) of the last head whose plan
+                # failed to commit: while nothing changed in the pool, the
+                # pump loop skips re-hashing the prompt and re-walking the
+                # registry on every iteration
+                self._blocked_key = None
 
-        names = self._sp.names
-        sp = self._sp
-        cfg = self._cfg
+            # ---------------------------------------------- chunked prefill
+            # head-of-line fix (ROADMAP item 2a): prompts longer than
+            # prefill_chunk_tokens are admitted C tokens at a time, ONE
+            # chunk per scheduler iteration, interleaved with the decode
+            # dispatch — in-flight streams keep producing tokens while the
+            # long prompt fills a persistent batch-1 SIDE cache that the
+            # ordinary admit program installs at the final chunk. Opt-in
+            # (kwarg > enable_serving > PADDLE_PREFILL_CHUNK_TOKENS); paged
+            # engines require page alignment so every completed chunk ends
+            # on a page boundary the span-install can commit.
+            env_ct = os.environ.get("PADDLE_PREFILL_CHUNK_TOKENS",
+                                    "").strip()
+            if env_ct and not env_ct.isdigit():
+                # garbage must not silently enable/resize chunking (same
+                # contract as PADDLE_TRACE_SAMPLE / PADDLE_KV_PAGE_SIZE)
+                monitor.record_swallowed(
+                    "serving.prefill_chunk_tokens",
+                    ValueError(f"PADDLE_PREFILL_CHUNK_TOKENS={env_ct!r}"))
+            ct = _opt(prefill_chunk_tokens, "prefill_chunk_tokens",
+                      int(env_ct) if env_ct.isdigit() else None)
+            self.prefill_chunk_tokens = None
+            if ct is not None:
+                ct = int(ct)
+                if ct < 1:
+                    raise ValueError(
+                        f"prefill_chunk_tokens {ct} must be >= 1 "
+                        "(PADDLE_PREFILL_CHUNK_TOKENS / "
+                        "enable_serving(prefill_chunk_tokens=...))")
+                if self._alloc is not None and ct % self.page_size:
+                    raise ValueError(
+                        f"prefill_chunk_tokens {ct} must be a multiple of "
+                        f"kv_page_size {self.page_size}: every completed "
+                        "chunk must end on a page boundary so its span "
+                        "installs into whole committed pages")
+                # the final chunk pads to the chunk width, so the side
+                # cache writes up to ceil(bucket/C)*C positions — past
+                # max_len the ring modulo would WRAP the write onto the
+                # prompt's own prefix (silent corruption, not an error)
+                padded_top = -(-buckets[-1] // ct) * ct
+                if ct < buckets[-1] and padded_top > self.max_len:
+                    raise ValueError(
+                        f"prefill_chunk_tokens {ct}: the largest bucket "
+                        f"{buckets[-1]} pads to {padded_top} chunked "
+                        f"tokens, past the cache length {self.max_len} — "
+                        "the final padded chunk would wrap the ring onto "
+                        "the prompt prefix; raise prefill_chunk_tokens or "
+                        "cache_max_len")
+                self.prefill_chunk_tokens = ct
+            # chunking can only ever trigger for prompts LONGER than one
+            # chunk; with every bucket at or under C the programs would be
+            # dead weight in warmup
+            self._chunk_enabled = (self.prefill_chunk_tokens is not None
+                                   and self.prefill_chunk_tokens
+                                   < buckets[-1])
+            self._chunking = None   # the (single) in-flight chunked
+            #                         admission's scheduler state
 
-        def prefill_fn(state_vals, ids, plen, key, cfg, cache_len):
-            params = sp.materialize(state_vals)
-            out = functional_call(
-                layer, dict(zip(names, params)), Tensor(ids),
-                use_cache=True, prompt_len=plen, cache_max_len=cache_len,
-                **cache_kw)
-            logits, cache = _expect_logits_cache(out)
-            logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-            k0, k1 = jax.random.split(key)
-            tok = sample(logits, k0, **_sample_cfg(cfg))
-            if cfg.eos_token_id is not None:
-                finished = tok == cfg.eos_token_id
+            names = self._sp.names
+            sp = self._sp
+            cfg = self._cfg
+
+            def prefill_fn(state_vals, ids, plen, key, cfg, cache_len):
+                params = sp.materialize(state_vals)
+                out = functional_call(
+                    layer, dict(zip(names, params)), Tensor(ids),
+                    use_cache=True, prompt_len=plen, cache_max_len=cache_len,
+                    **cache_kw)
+                logits, cache = _expect_logits_cache(out)
+                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
+                k0, k1 = jax.random.split(key)
+                tok = sample(logits, k0, **_sample_cfg(cfg))
+                if cfg.eos_token_id is not None:
+                    finished = tok == cfg.eos_token_id
+                else:
+                    finished = jnp.zeros(tok.shape, bool)
+                return tok, cache, k1, finished
+
+            def step_fn(state_vals, tok, cache, key, finished, steps,
+                        budget, out_buf, cfg):
+                params = sp.materialize(state_vals)
+                out = functional_call(layer, dict(zip(names, params)),
+                                      Tensor(tok[:, None]), cache=cache)
+                logits, cache = _expect_logits_cache(out)
+                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
+                k0, k1 = jax.random.split(key)
+                nxt = sample(logits, k0, **_sample_cfg(cfg))
+                rows = jnp.arange(nxt.shape[0], dtype=jnp.int32)
+                idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
+                # finished lanes are masked: their buffer entry and step
+                # count stay frozen while the fixed-batch step runs on
+                out_buf = out_buf.at[rows, idx].set(
+                    jnp.where(finished, out_buf[rows, idx], nxt))
+                steps = steps + jnp.where(finished, 0, 1)
+                if cfg.eos_token_id is not None:
+                    finished = finished | (nxt == cfg.eos_token_id)
+                finished = finished | (steps >= budget)
+                # dead slots: pin kv_len at 0 so an idle lane neither wraps
+                # the ring nor walks the position table out of range while
+                # it waits for its next admission
+                cache = cache.with_kv_len(
+                    jnp.where(finished, 0, cache.kv_len))
+                return nxt, cache, k1, finished, steps, budget, out_buf
+
+            spec = self._spec
+
+            def spec_step_fn(state_vals, tok, cache, key, finished, steps,
+                             budget, out_buf, tok_buf, tok_len, proposed,
+                             accepted, cfg, spec):
+                from ..generation.speculative import (apply_verify_window,
+                                                      ngram_propose)
+                params = sp.materialize(state_vals)
+                draft = ngram_propose(tok_buf, tok_len, k=spec.k,
+                                      n=spec.ngram)
+                window = jnp.concatenate([tok[:, None], draft], axis=1)
+                out = functional_call(layer, dict(zip(names, params)),
+                                      Tensor(window), cache=cache)
+                logits, cache = _expect_logits_cache(out)
+                logits = _unwrap(logits).astype(jnp.float32)
+                k0, k1 = jax.random.split(key)
+                # the shared acceptance/clamp/scatter/rollback core —
+                # pin_finished_kv is the engine's idle-lane contract (a
+                # parked slot must never wrap the ring)
+                (tok, cache, finished, steps, out_buf, tok_buf, tok_len,
+                 proposed, accepted) = apply_verify_window(
+                    logits, draft, k0, cfg, spec, tok, cache, finished,
+                    steps, budget, out_buf, tok_buf, tok_len, proposed,
+                    accepted, pin_finished_kv=True)
+                return (tok, cache, k1, finished, steps, budget, out_buf,
+                        tok_buf, tok_len, proposed, accepted)
+
+            def admit_lanes(tok, finished, steps, budget, out_buf, slot,
+                            first_tok, first_fin, row_budget):
+                # the slot's scheduler lanes after admission (shared by the
+                # dense and paged admit programs — only the cache install
+                # differs); the slot index is a traced scalar, so one
+                # program serves every slot
+                tok = tok.at[slot].set(first_tok[0])
+                steps = steps.at[slot].set(1)
+                budget = budget.at[slot].set(row_budget)
+                row = jnp.zeros((out_buf.shape[1],), jnp.int32) \
+                    .at[0].set(first_tok[0])
+                out_buf = out_buf.at[slot].set(row)
+                finished = finished.at[slot].set(
+                    first_fin[0] | (row_budget <= 1))
+                return tok, finished, steps, budget, out_buf
+
+            def drafter_lanes(tok_buf, tok_len, slot, ids_row, row_plen,
+                              first_tok):
+                # the drafter's token history: the padded prompt row with
+                # the prefill token appended — the n-gram drafter reads
+                # prompt AND emitted tokens from one buffer
+                row = ids_row.at[row_plen].set(first_tok[0])
+                return (tok_buf.at[slot].set(row),
+                        tok_len.at[slot].set(row_plen + 1))
+
+            def admit_fn(cache, tok, finished, steps, budget, out_buf,
+                         slot, row_cache, first_tok, first_fin, row_budget):
+                # install the batch-1 prefill row into the freed slot
+                cache = cache.copy_row_from(row_cache, 0, slot)
+                (tok, finished, steps, budget, out_buf) = admit_lanes(
+                    tok, finished, steps, budget, out_buf, slot, first_tok,
+                    first_fin, row_budget)
+                return cache, tok, finished, steps, budget, out_buf
+
+            def spec_admit_fn(cache, tok, finished, steps, budget, out_buf,
+                              slot, row_cache, first_tok, first_fin,
+                              row_budget, tok_buf, tok_len, ids_row,
+                              row_plen):
+                (cache, tok, finished, steps, budget, out_buf) = admit_fn(
+                    cache, tok, finished, steps, budget, out_buf, slot,
+                    row_cache, first_tok, first_fin, row_budget)
+                tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
+                                                 ids_row, row_plen,
+                                                 first_tok)
+                return (cache, tok, finished, steps, budget, out_buf,
+                        tok_buf, tok_len)
+
+            def free_fn(cache, finished, slot):
+                return cache.reset_rows(slot), finished.at[slot].set(True)
+
+            def paged_admit_fn(cache, tok, finished, steps, budget, out_buf,
+                               slot, row_cache, first_tok, first_fin,
+                               row_budget, table_row, start):
+                # paged admission: scatter the batch-1 prefill row into the
+                # pool pages named by table_row, SKIPPING the shared-prefix
+                # positions below start (they already hold this content —
+                # prefill once, reference-count many). slot/table/start are
+                # traced data — one program, every slot, every layout.
+                cache = cache.install_row(row_cache, slot, table_row, start)
+                (tok, finished, steps, budget, out_buf) = admit_lanes(
+                    tok, finished, steps, budget, out_buf, slot, first_tok,
+                    first_fin, row_budget)
+                return cache, tok, finished, steps, budget, out_buf
+
+            def paged_spec_admit_fn(cache, tok, finished, steps, budget,
+                                    out_buf, slot, row_cache, first_tok,
+                                    first_fin, row_budget, table_row, start,
+                                    tok_buf, tok_len, ids_row, row_plen):
+                (cache, tok, finished, steps, budget, out_buf) = \
+                    paged_admit_fn(cache, tok, finished, steps, budget,
+                                   out_buf, slot, row_cache, first_tok,
+                                   first_fin, row_budget, table_row, start)
+                tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
+                                                 ids_row, row_plen,
+                                                 first_tok)
+                return (cache, tok, finished, steps, budget, out_buf,
+                        tok_buf, tok_len)
+
+            def chunk_fn(state_vals, ids, row_cache):
+                # one NON-final prefill chunk: decode-mode forward over the
+                # persistent batch-1 side cache — attention masks at
+                # kv_len + C with queries at offset kv_len (the chunk
+                # kernel), the C new KV rows land in the ring, kv_len
+                # advances. The logits are never read, so the LM head DCEs
+                # out of the compiled program.
+                params = sp.materialize(state_vals)
+                out = functional_call(layer, dict(zip(names, params)),
+                                      Tensor(ids), cache=row_cache)
+                _, row_cache = _expect_logits_cache(out)
+                return row_cache
+
+            def chunk_final_fn(state_vals, ids, plen, key, row_cache, cfg):
+                # the FINAL (pad-to-C) chunk: kv_len clamps to the true
+                # prompt length, the hidden state is gathered at the last
+                # REAL position, and the first token is sampled — the same
+                # (tok, row_cache, key, finished) contract as prefill_fn,
+                # so the EXISTING admit program installs the result
+                # unchanged.
+                params = sp.materialize(state_vals)
+                out = functional_call(layer, dict(zip(names, params)),
+                                      Tensor(ids), cache=row_cache,
+                                      prompt_len=plen)
+                logits, row_cache = _expect_logits_cache(out)
+                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
+                k0, k1 = jax.random.split(key)
+                tok = sample(logits, k0, **_sample_cfg(cfg))
+                if cfg.eos_token_id is not None:
+                    finished = tok == cfg.eos_token_id
+                else:
+                    finished = jnp.zeros(tok.shape, bool)
+                return tok, row_cache, k1, finished
+
+            def install_span_fn(cache, row_cache, table_row, start):
+                # commit one completed chunk's positions into the pool
+                # pages the admission planner already committed — table row
+                # and kv_len stay untouched, so the slot's lane stays
+                # parked (null-page routed) until the final admit installs
+                # the pointers atomically
+                return cache.install_span(row_cache, table_row, start)
+
+            self._prefill_fn, self._free_fn = prefill_fn, free_fn
+            self._chunk_fn = chunk_fn
+            self._chunk_final_fn = chunk_final_fn
+            self._span_fn = install_span_fn
+            self._step_fn = step_fn if spec is None else spec_step_fn
+            if self._alloc is None:
+                self._admit_fn = admit_fn if spec is None else spec_admit_fn
             else:
-                finished = jnp.zeros(tok.shape, bool)
-            return tok, cache, k1, finished
-
-        def step_fn(state_vals, tok, cache, key, finished, steps,
-                    budget, out_buf, cfg):
-            params = sp.materialize(state_vals)
-            out = functional_call(layer, dict(zip(names, params)),
-                                  Tensor(tok[:, None]), cache=cache)
-            logits, cache = _expect_logits_cache(out)
-            logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-            k0, k1 = jax.random.split(key)
-            nxt = sample(logits, k0, **_sample_cfg(cfg))
-            rows = jnp.arange(nxt.shape[0], dtype=jnp.int32)
-            idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
-            # finished lanes are masked: their buffer entry and step
-            # count stay frozen while the fixed-batch step runs on
-            out_buf = out_buf.at[rows, idx].set(
-                jnp.where(finished, out_buf[rows, idx], nxt))
-            steps = steps + jnp.where(finished, 0, 1)
-            if cfg.eos_token_id is not None:
-                finished = finished | (nxt == cfg.eos_token_id)
-            finished = finished | (steps >= budget)
-            # dead slots: pin kv_len at 0 so an idle lane neither wraps
-            # the ring nor walks the position table out of range while
-            # it waits for its next admission
-            cache = cache.with_kv_len(
-                jnp.where(finished, 0, cache.kv_len))
-            return nxt, cache, k1, finished, steps, budget, out_buf
-
-        spec = self._spec
-
-        def spec_step_fn(state_vals, tok, cache, key, finished, steps,
-                         budget, out_buf, tok_buf, tok_len, proposed,
-                         accepted, cfg, spec):
-            from ..generation.speculative import (apply_verify_window,
-                                                  ngram_propose)
-            params = sp.materialize(state_vals)
-            draft = ngram_propose(tok_buf, tok_len, k=spec.k,
-                                  n=spec.ngram)
-            window = jnp.concatenate([tok[:, None], draft], axis=1)
-            out = functional_call(layer, dict(zip(names, params)),
-                                  Tensor(window), cache=cache)
-            logits, cache = _expect_logits_cache(out)
-            logits = _unwrap(logits).astype(jnp.float32)
-            k0, k1 = jax.random.split(key)
-            # the shared acceptance/clamp/scatter/rollback core —
-            # pin_finished_kv is the engine's idle-lane contract (a
-            # parked slot must never wrap the ring)
-            (tok, cache, finished, steps, out_buf, tok_buf, tok_len,
-             proposed, accepted) = apply_verify_window(
-                logits, draft, k0, cfg, spec, tok, cache, finished,
-                steps, budget, out_buf, tok_buf, tok_len, proposed,
-                accepted, pin_finished_kv=True)
-            return (tok, cache, k1, finished, steps, budget, out_buf,
-                    tok_buf, tok_len, proposed, accepted)
-
-        def admit_lanes(tok, finished, steps, budget, out_buf, slot,
-                        first_tok, first_fin, row_budget):
-            # the slot's scheduler lanes after admission (shared by the
-            # dense and paged admit programs — only the cache install
-            # differs); the slot index is a traced scalar, so one
-            # program serves every slot
-            tok = tok.at[slot].set(first_tok[0])
-            steps = steps.at[slot].set(1)
-            budget = budget.at[slot].set(row_budget)
-            row = jnp.zeros((out_buf.shape[1],), jnp.int32) \
-                .at[0].set(first_tok[0])
-            out_buf = out_buf.at[slot].set(row)
-            finished = finished.at[slot].set(
-                first_fin[0] | (row_budget <= 1))
-            return tok, finished, steps, budget, out_buf
-
-        def drafter_lanes(tok_buf, tok_len, slot, ids_row, row_plen,
-                          first_tok):
-            # the drafter's token history: the padded prompt row with
-            # the prefill token appended — the n-gram drafter reads
-            # prompt AND emitted tokens from one buffer
-            row = ids_row.at[row_plen].set(first_tok[0])
-            return (tok_buf.at[slot].set(row),
-                    tok_len.at[slot].set(row_plen + 1))
-
-        def admit_fn(cache, tok, finished, steps, budget, out_buf,
-                     slot, row_cache, first_tok, first_fin, row_budget):
-            # install the batch-1 prefill row into the freed slot
-            cache = cache.copy_row_from(row_cache, 0, slot)
-            (tok, finished, steps, budget, out_buf) = admit_lanes(
-                tok, finished, steps, budget, out_buf, slot, first_tok,
-                first_fin, row_budget)
-            return cache, tok, finished, steps, budget, out_buf
-
-        def spec_admit_fn(cache, tok, finished, steps, budget, out_buf,
-                          slot, row_cache, first_tok, first_fin,
-                          row_budget, tok_buf, tok_len, ids_row,
-                          row_plen):
-            (cache, tok, finished, steps, budget, out_buf) = admit_fn(
-                cache, tok, finished, steps, budget, out_buf, slot,
-                row_cache, first_tok, first_fin, row_budget)
-            tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
-                                             ids_row, row_plen,
-                                             first_tok)
-            return (cache, tok, finished, steps, budget, out_buf,
-                    tok_buf, tok_len)
-
-        def free_fn(cache, finished, slot):
-            return cache.reset_rows(slot), finished.at[slot].set(True)
-
-        def paged_admit_fn(cache, tok, finished, steps, budget, out_buf,
-                           slot, row_cache, first_tok, first_fin,
-                           row_budget, table_row, start):
-            # paged admission: scatter the batch-1 prefill row into the
-            # pool pages named by table_row, SKIPPING the shared-prefix
-            # positions below start (they already hold this content —
-            # prefill once, reference-count many). slot/table/start are
-            # traced data — one program, every slot, every layout.
-            cache = cache.install_row(row_cache, slot, table_row, start)
-            (tok, finished, steps, budget, out_buf) = admit_lanes(
-                tok, finished, steps, budget, out_buf, slot, first_tok,
-                first_fin, row_budget)
-            return cache, tok, finished, steps, budget, out_buf
-
-        def paged_spec_admit_fn(cache, tok, finished, steps, budget,
-                                out_buf, slot, row_cache, first_tok,
-                                first_fin, row_budget, table_row, start,
-                                tok_buf, tok_len, ids_row, row_plen):
-            (cache, tok, finished, steps, budget, out_buf) = \
-                paged_admit_fn(cache, tok, finished, steps, budget,
-                               out_buf, slot, row_cache, first_tok,
-                               first_fin, row_budget, table_row, start)
-            tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
-                                             ids_row, row_plen,
-                                             first_tok)
-            return (cache, tok, finished, steps, budget, out_buf,
-                    tok_buf, tok_len)
-
-        def chunk_fn(state_vals, ids, row_cache):
-            # one NON-final prefill chunk: decode-mode forward over the
-            # persistent batch-1 side cache — attention masks at
-            # kv_len + C with queries at offset kv_len (the chunk
-            # kernel), the C new KV rows land in the ring, kv_len
-            # advances. The logits are never read, so the LM head DCEs
-            # out of the compiled program.
-            params = sp.materialize(state_vals)
-            out = functional_call(layer, dict(zip(names, params)),
-                                  Tensor(ids), cache=row_cache)
-            _, row_cache = _expect_logits_cache(out)
-            return row_cache
-
-        def chunk_final_fn(state_vals, ids, plen, key, row_cache, cfg):
-            # the FINAL (pad-to-C) chunk: kv_len clamps to the true
-            # prompt length, the hidden state is gathered at the last
-            # REAL position, and the first token is sampled — the same
-            # (tok, row_cache, key, finished) contract as prefill_fn,
-            # so the EXISTING admit program installs the result
-            # unchanged.
-            params = sp.materialize(state_vals)
-            out = functional_call(layer, dict(zip(names, params)),
-                                  Tensor(ids), cache=row_cache,
-                                  prompt_len=plen)
-            logits, row_cache = _expect_logits_cache(out)
-            logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-            k0, k1 = jax.random.split(key)
-            tok = sample(logits, k0, **_sample_cfg(cfg))
-            if cfg.eos_token_id is not None:
-                finished = tok == cfg.eos_token_id
+                self._admit_fn = paged_admit_fn if spec is None \
+                    else paged_spec_admit_fn
+            # executable persistence: every program warmup() compiles goes
+            # through jit.compile_cache (this store, or the process default
+            # when None) so a relaunched engine loads instead of recompiling
+            self._exe_store = executable_store
+            # donate on TPU only (CPU/GPU donation is a no-op that warns
+            # once per program); audit() gates the TPU donation INTENT
+            tpu = jax.default_backend() == "tpu"
+            # the spec admit's drafter tok_buf/tok_len positions — shifted
+            # by the paged table_row/start args. ONE definition shared by
+            # the jit donation wiring below and audit(): the audited
+            # donation set must be the set the production program uses.
+            self._spec_admit_buf = (11, 12) if self._alloc is None \
+                else (13, 14)
+            # the _intent tuples are the TPU donation design regardless of
+            # the running backend — audit() and memory_plan() gate against
+            # THEM, the jit wiring applies them only where donation works
+            if spec is None:
+                self._step_donate_intent = (1, 2, 3, 4, 5, 6, 7)
+                self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7)
+                step_static = (8,)
             else:
-                finished = jnp.zeros(tok.shape, bool)
-            return tok, row_cache, k1, finished
+                # the spec step additionally carries the drafter's token
+                # buffer/length lanes and the proposed/accepted counters —
+                # all donated (in-place across polls, audited as intent).
+                # The paged spec admit's tok_buf/tok_len sit two positions
+                # later (after table_row/start).
+                self._step_donate_intent = tuple(range(1, 12))
+                self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7) \
+                    + self._spec_admit_buf
+                step_static = (12, 13)
+            self._free_donate_intent = (0, 1)
+            # chunk programs: the side cache is the ONLY donated operand —
+            # it round-trips in place every chunk (chunk_fn arg 2,
+            # chunk_final_fn arg 4); the span install donates the pool
+            # pytree (arg 0) but NOT the source side cache, which the next
+            # chunk still reads
+            self._chunk_donate_intent = (2,)
+            self._chunk_final_donate_intent = (4,)
+            self._span_donate_intent = (0,)
+            self._step_donate = self._step_donate_intent if tpu else ()
+            self._admit_donate = self._admit_donate_intent if tpu else ()
+            self._free_donate = self._free_donate_intent if tpu else ()
+            self._chunk_donate = self._chunk_donate_intent if tpu else ()
+            self._chunk_final_donate = \
+                self._chunk_final_donate_intent if tpu else ()
+            self._span_donate = self._span_donate_intent if tpu else ()
+            self._prefill_jit = jax.jit(prefill_fn, static_argnums=(4, 5))
+            self._step_jit = jax.jit(
+                self._step_fn, static_argnums=step_static,
+                donate_argnums=self._step_donate)
+            self._admit_jit = jax.jit(
+                self._admit_fn, donate_argnums=self._admit_donate)
+            self._free_jit = jax.jit(
+                free_fn, donate_argnums=self._free_donate)
+            self._chunk_jit = jax.jit(
+                chunk_fn, donate_argnums=self._chunk_donate)
+            self._chunk_final_jit = jax.jit(
+                chunk_final_fn, static_argnums=(5,),
+                donate_argnums=self._chunk_final_donate)
+            self._span_jit = jax.jit(
+                install_span_fn, donate_argnums=self._span_donate)
 
-        def install_span_fn(cache, row_cache, table_row, start):
-            # commit one completed chunk's positions into the pool
-            # pages the admission planner already committed — table row
-            # and kv_len stay untouched, so the slot's lane stays
-            # parked (null-page routed) until the final admit installs
-            # the pointers atomically
-            return cache.install_span(row_cache, table_row, start)
+            # ------------------------------------------------------- state
+            self._state = tuple(self._sp.vals)
+            if seed is not None:
+                self._key = jax.random.PRNGKey(int(seed))
+            elif cfg.do_sample:
+                from ..core import random as _random
+                self._key = _random.next_key()
+            else:
+                self._key = jax.random.PRNGKey(0)  # greedy: never consumed
 
-        self._prefill_fn, self._free_fn = prefill_fn, free_fn
-        self._chunk_fn = chunk_fn
-        self._chunk_final_fn = chunk_final_fn
-        self._span_fn = install_span_fn
-        self._step_fn = step_fn if spec is None else spec_step_fn
-        if self._alloc is None:
-            self._admit_fn = admit_fn if spec is None else spec_admit_fn
-        else:
-            self._admit_fn = paged_admit_fn if spec is None \
-                else paged_spec_admit_fn
-        # executable persistence: every program warmup() compiles goes
-        # through jit.compile_cache (this store, or the process default
-        # when None) so a relaunched engine loads instead of recompiling
-        self._exe_store = executable_store
-        # donate on TPU only (CPU/GPU donation is a no-op that warns
-        # once per program); audit() gates the TPU donation INTENT
-        tpu = jax.default_backend() == "tpu"
-        # the spec admit's drafter tok_buf/tok_len positions — shifted
-        # by the paged table_row/start args. ONE definition shared by
-        # the jit donation wiring below and audit(): the audited
-        # donation set must be the set the production program uses.
-        self._spec_admit_buf = (11, 12) if self._alloc is None \
-            else (13, 14)
-        # the _intent tuples are the TPU donation design regardless of
-        # the running backend — audit() and memory_plan() gate against
-        # THEM, the jit wiring applies them only where donation works
-        if spec is None:
-            self._step_donate_intent = (1, 2, 3, 4, 5, 6, 7)
-            self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7)
-            step_static = (8,)
-        else:
-            # the spec step additionally carries the drafter's token
-            # buffer/length lanes and the proposed/accepted counters —
-            # all donated (in-place across polls, audited as intent).
-            # The paged spec admit's tok_buf/tok_len sit two positions
-            # later (after table_row/start).
-            self._step_donate_intent = tuple(range(1, 12))
-            self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7) \
-                + self._spec_admit_buf
-            step_static = (12, 13)
-        self._free_donate_intent = (0, 1)
-        # chunk programs: the side cache is the ONLY donated operand —
-        # it round-trips in place every chunk (chunk_fn arg 2,
-        # chunk_final_fn arg 4); the span install donates the pool
-        # pytree (arg 0) but NOT the source side cache, which the next
-        # chunk still reads
-        self._chunk_donate_intent = (2,)
-        self._chunk_final_donate_intent = (4,)
-        self._span_donate_intent = (0,)
-        self._step_donate = self._step_donate_intent if tpu else ()
-        self._admit_donate = self._admit_donate_intent if tpu else ()
-        self._free_donate = self._free_donate_intent if tpu else ()
-        self._chunk_donate = self._chunk_donate_intent if tpu else ()
-        self._chunk_final_donate = \
-            self._chunk_final_donate_intent if tpu else ()
-        self._span_donate = self._span_donate_intent if tpu else ()
-        self._prefill_jit = jax.jit(prefill_fn, static_argnums=(4, 5))
-        self._step_jit = jax.jit(
-            self._step_fn, static_argnums=step_static,
-            donate_argnums=self._step_donate)
-        self._admit_jit = jax.jit(
-            self._admit_fn, donate_argnums=self._admit_donate)
-        self._free_jit = jax.jit(
-            free_fn, donate_argnums=self._free_donate)
-        self._chunk_jit = jax.jit(
-            chunk_fn, donate_argnums=self._chunk_donate)
-        self._chunk_final_jit = jax.jit(
-            chunk_final_fn, static_argnums=(5,),
-            donate_argnums=self._chunk_final_donate)
-        self._span_jit = jax.jit(
-            install_span_fn, donate_argnums=self._span_donate)
+            B, cap = self.max_batch, self.max_new_tokens
+            sds = jax.ShapeDtypeStruct
+            cache_aval = jax.eval_shape(
+                lambda s, i, p, k: prefill_fn(s, i, p, k, cfg, self.max_len),
+                self._state, sds((B, buckets[0]), jnp.int32),
+                sds((B,), jnp.int32), self._key)[1]
+            with flight_recorder.span("setup.cache_alloc") as alloc_sp:
+                # lane/cache buffers built on HOST and device_put: jnp.zeros
+                # would compile one tiny broadcast program per shape — dead
+                # weight on the warm-relaunch path the executable store keeps
+                # otherwise XLA-free
+                quant = getattr(cache_aval, "k_scale", None) is not None
+                if self._alloc is None:
+                    self._cache = jax.tree_util.tree_map(
+                        lambda a: jax.device_put(np.zeros(a.shape, a.dtype)),
+                        cache_aval)
+                elif quant:
+                    # paged int8 pool: value pages + their bf16 scale pages
+                    # (the scales live IN the page, so prefix sharing / COW /
+                    # reclaim carry them for free) + the saturation counter
+                    from ..generation.paged_cache import QuantPagedKVCache
+                    L, _, _, H, D = cache_aval.k.shape
+                    pool = (L, self._alloc.n_pages, self.page_size, H, D)
+                    spool = (L, self._alloc.n_pages, self.page_size, H)
+                    self._cache = QuantPagedKVCache(
+                        jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
+                        jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
+                        jax.device_put(np.zeros((B, self.pages_per_row),
+                                                np.int32)),
+                        jax.device_put(np.zeros((B,), np.int32)),
+                        jax.device_put(np.zeros(spool, jnp.bfloat16)),
+                        jax.device_put(np.zeros(spool, jnp.bfloat16)),
+                        jax.device_put(np.zeros((), np.int32)))
+                else:
+                    # paged pool: layers/heads/head_dim/dtype from the dense
+                    # prefill aval, rows replaced by the page pool + tables
+                    from ..generation.paged_cache import PagedKVCache
+                    L, _, _, H, D = cache_aval.k.shape
+                    pool = (L, self._alloc.n_pages, self.page_size, H, D)
+                    self._cache = PagedKVCache(
+                        jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
+                        jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
+                        jax.device_put(np.zeros((B, self.pages_per_row),
+                                                np.int32)),
+                        jax.device_put(np.zeros((B,), np.int32)))
+                # the low-bit accounting satellites: the kv_dtype info gauge
+                # (what this engine serves — the router reads it beside the
+                # capacity numbers) and, when quantized, the HBM bytes the int8
+                # storage saved vs the wide dtype (host arithmetic over shapes)
+                self._clips_seen = 0
+                if quant:
+                    # the wide dtype the cache WOULD have carried: the serving
+                    # compute dtype when a precision mode set one, else the
+                    # model's own float param dtype (a model.bfloat16() under
+                    # default precision serves a bf16 cache — name check
+                    # because np.issubdtype(bfloat16, floating) is False)
+                    wide_dt = self._sp.compute_dtype
+                    if wide_dt is None:
+                        wide_dt = next(
+                            (v.dtype for v in self._sp.vals
+                             if np.issubdtype(np.dtype(v.dtype), np.floating)
+                             or np.dtype(v.dtype).name == "bfloat16"),
+                            np.float32)
+                    wide_dt = np.dtype(wide_dt)
+                    self._kv_dtype_label = "int8"
+                    saved = 2 * int(np.prod(self._cache.k.shape)) \
+                        * (wide_dt.itemsize - 1) \
+                        - 2 * int(np.prod(self._cache.k_scale.shape)) * 2
+                    monitor.record_kv_quant(bytes_saved=max(0, saved))
+                else:
+                    # the dtype the cache ACTUALLY carries, from its own aval
+                    self._kv_dtype_label = np.dtype(cache_aval.k.dtype).name
+                monitor.record_kv_dtype(self._kv_dtype_label)
+                self._tok = jax.device_put(np.zeros((B,), np.int32))
+                self._finished = jax.device_put(np.ones((B,), bool))  # empty
+                #                                       slots are masked
+                self._steps = jax.device_put(np.zeros((B,), np.int32))
+                self._budget = jax.device_put(np.zeros((B,), np.int32))
+                self._out_buf = jax.device_put(np.zeros((B, cap), np.int32))
+                if spec is not None:
+                    # drafter lanes: per-slot token history (prompt +
+                    # emitted, the n-gram lookup corpus) and the on-device
+                    # proposed/accepted counters the poll drains into
+                    # gen.spec.*
+                    self._tok_buf = jax.device_put(
+                        np.zeros((B, self.max_len), np.int32))
+                    self._tok_len = jax.device_put(np.zeros((B,), np.int32))
+                    self._proposed = jax.device_put(np.zeros((), np.int32))
+                    self._accepted = jax.device_put(np.zeros((), np.int32))
+                    self._spec_seen = (0, 0)   # host mirror for poll deltas
+                # bytes handed to device_put; the transfer is not awaited
+                # here (the first program that reads them waits for it)
+                alloc_sp.set(bytes=sum(
+                    int(a.nbytes) for a in jax.tree_util.tree_leaves((
+                        self._cache, self._tok, self._finished, self._steps,
+                        self._budget, self._out_buf))))
 
-        # ------------------------------------------------------- state
-        self._state = tuple(self._sp.vals)
-        if seed is not None:
-            self._key = jax.random.PRNGKey(int(seed))
-        elif cfg.do_sample:
-            from ..core import random as _random
-            self._key = _random.next_key()
-        else:
-            self._key = jax.random.PRNGKey(0)  # greedy: never consumed
+            # chunked prefill's persistent batch-1 SIDE cache: the same
+            # dense row cache a bucket prefill would produce (max_len long,
+            # quant sidecars included), host-built zeros like the lanes
+            # above. Rebuilt from host zeros after every chunked admission
+            # or abort — the admit program DONATES it (arg 7), so the
+            # buffer is gone either way, and the rebuild is also what
+            # resets kv_len to 0 and zeroes the quant clip counter between
+            # requests.
+            self._row_cache = None
+            self._row_cache_aval = None
+            if self._chunk_enabled:
+                self._row_cache_aval = self._row_avals()[1]
+                self._row_cache = self._fresh_row_cache()
 
-        B, cap = self.max_batch, self.max_new_tokens
-        sds = jax.ShapeDtypeStruct
-        cache_aval = jax.eval_shape(
-            lambda s, i, p, k: prefill_fn(s, i, p, k, cfg, self.max_len),
-            self._state, sds((B, buckets[0]), jnp.int32),
-            sds((B,), jnp.int32), self._key)[1]
-        # lane/cache buffers built on HOST and device_put: jnp.zeros
-        # would compile one tiny broadcast program per shape — dead
-        # weight on the warm-relaunch path the executable store keeps
-        # otherwise XLA-free
-        quant = getattr(cache_aval, "k_scale", None) is not None
-        if self._alloc is None:
-            self._cache = jax.tree_util.tree_map(
-                lambda a: jax.device_put(np.zeros(a.shape, a.dtype)),
-                cache_aval)
-        elif quant:
-            # paged int8 pool: value pages + their bf16 scale pages
-            # (the scales live IN the page, so prefix sharing / COW /
-            # reclaim carry them for free) + the saturation counter
-            from ..generation.paged_cache import QuantPagedKVCache
-            L, _, _, H, D = cache_aval.k.shape
-            pool = (L, self._alloc.n_pages, self.page_size, H, D)
-            spool = (L, self._alloc.n_pages, self.page_size, H)
-            self._cache = QuantPagedKVCache(
-                jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
-                jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
-                jax.device_put(np.zeros((B, self.pages_per_row),
-                                        np.int32)),
-                jax.device_put(np.zeros((B,), np.int32)),
-                jax.device_put(np.zeros(spool, jnp.bfloat16)),
-                jax.device_put(np.zeros(spool, jnp.bfloat16)),
-                jax.device_put(np.zeros((), np.int32)))
-        else:
-            # paged pool: layers/heads/head_dim/dtype from the dense
-            # prefill aval, rows replaced by the page pool + tables
-            from ..generation.paged_cache import PagedKVCache
-            L, _, _, H, D = cache_aval.k.shape
-            pool = (L, self._alloc.n_pages, self.page_size, H, D)
-            self._cache = PagedKVCache(
-                jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
-                jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
-                jax.device_put(np.zeros((B, self.pages_per_row),
-                                        np.int32)),
-                jax.device_put(np.zeros((B,), np.int32)))
-        # the low-bit accounting satellites: the kv_dtype info gauge
-        # (what this engine serves — the router reads it beside the
-        # capacity numbers) and, when quantized, the HBM bytes the int8
-        # storage saved vs the wide dtype (host arithmetic over shapes)
-        self._clips_seen = 0
-        if quant:
-            # the wide dtype the cache WOULD have carried: the serving
-            # compute dtype when a precision mode set one, else the
-            # model's own float param dtype (a model.bfloat16() under
-            # default precision serves a bf16 cache — name check
-            # because np.issubdtype(bfloat16, floating) is False)
-            wide_dt = self._sp.compute_dtype
-            if wide_dt is None:
-                wide_dt = next(
-                    (v.dtype for v in self._sp.vals
-                     if np.issubdtype(np.dtype(v.dtype), np.floating)
-                     or np.dtype(v.dtype).name == "bfloat16"),
-                    np.float32)
-            wide_dt = np.dtype(wide_dt)
-            self._kv_dtype_label = "int8"
-            saved = 2 * int(np.prod(self._cache.k.shape)) \
-                * (wide_dt.itemsize - 1) \
-                - 2 * int(np.prod(self._cache.k_scale.shape)) * 2
-            monitor.record_kv_quant(bytes_saved=max(0, saved))
-        else:
-            # the dtype the cache ACTUALLY carries, from its own aval
-            self._kv_dtype_label = np.dtype(cache_aval.k.dtype).name
-        monitor.record_kv_dtype(self._kv_dtype_label)
-        self._tok = jax.device_put(np.zeros((B,), np.int32))
-        self._finished = jax.device_put(np.ones((B,), bool))  # empty
-        #                                       slots are masked
-        self._steps = jax.device_put(np.zeros((B,), np.int32))
-        self._budget = jax.device_put(np.zeros((B,), np.int32))
-        self._out_buf = jax.device_put(np.zeros((B, cap), np.int32))
-        if spec is not None:
-            # drafter lanes: per-slot token history (prompt + emitted,
-            # the n-gram lookup corpus) and the on-device
-            # proposed/accepted counters the poll drains into gen.spec.*
-            self._tok_buf = jax.device_put(
-                np.zeros((B, self.max_len), np.int32))
-            self._tok_len = jax.device_put(np.zeros((B,), np.int32))
-            self._proposed = jax.device_put(np.zeros((), np.int32))
-            self._accepted = jax.device_put(np.zeros((), np.int32))
-            self._spec_seen = (0, 0)   # host mirror for poll deltas
-
-        # chunked prefill's persistent batch-1 SIDE cache: the same
-        # dense row cache a bucket prefill would produce (max_len long,
-        # quant sidecars included), host-built zeros like the lanes
-        # above. Rebuilt from host zeros after every chunked admission
-        # or abort — the admit program DONATES it (arg 7), so the
-        # buffer is gone either way, and the rebuild is also what
-        # resets kv_len to 0 and zeroes the quant clip counter between
-        # requests.
-        self._row_cache = None
-        self._row_cache_aval = None
-        if self._chunk_enabled:
-            self._row_cache_aval = self._row_avals()[1]
-            self._row_cache = self._fresh_row_cache()
-
-        self._slots: List[Optional[Request]] = [None] * B
-        self._slot_used = [False] * B          # reuse detection
-        self._queue = collections.deque()
-        self._qlock = threading.Lock()
-        self._pump_lock = threading.RLock()
-        self._thread: Optional[threading.Thread] = None
-        self._exes: Dict = {}
-        self._warm = False
-        self._shutdown = False
-        self._steps_since_poll = 0
-        self._window_t0: Optional[float] = None
-        self._window_steps = 0
-        self.stats = dict(submitted=0, admitted=0, completed=0,
-                          cancelled=0, rejected=0, slots_reused=0,
-                          decode_steps=0, prefills=0, prefill_chunks=0,
-                          spec_proposed=0, spec_accepted=0)
-        # top-K most expensive terminal requests (heap of
-        # (total_s, req id, cost dict)) — the /slo cost table
-        self._cost_top: List[tuple] = []
-        self._cost_topk = 10
-        # goodput ledger (serve.goodput.* family): dispatch windows and
-        # admissions charge compute (or compile when a retrace happened
-        # inside the window), serve_forever's empty-queue sleeps charge
-        # idle, preemption drains charge preemption_recovery; the
-        # unattributed residual folds into idle — an un-pumped engine
-        # is waiting, not computing. Started after warmup so the
-        # one-time compile storm doesn't poison steady-state goodput.
-        from ..core import goodput as goodput_mod
-        self._goodput = goodput_mod.GoodputLedger(
-            "serve", default_bucket="idle")
-        # ------------------------------------------------ HBM planning
-        # admission control for MEMORY, before a single buffer compiles:
-        # with a budget declared (kwarg > enable_serving > env), the
-        # static planner (analysis.memory) predicts the engine's peak —
-        # weights + kv pool + lanes resident, plus the decode/admission
-        # transients — and a config that cannot fit fails HERE, not as
-        # an on-device OOM under traffic (the kv_pages-too-small
-        # fail-fast contract). health() reports the headroom.
-        self._mem_summary = None
-        self.hbm_budget = None
-        from ..analysis.memory import resolve_hbm_budget
-        explicit_budget = _opt(hbm_budget, "hbm_budget", None)
-        if explicit_budget is not None:
-            # an explicit (kwarg / enable_serving) garbage budget
-            # RAISES: the operator asked for a gate and must get one
-            self.hbm_budget = resolve_hbm_budget(explicit_budget)
-        else:
+            self._slots: List[Optional[Request]] = [None] * B
+            self._slot_used = [False] * B          # reuse detection
+            self._queue = collections.deque()
+            self._qlock = threading.Lock()
+            self._pump_lock = threading.RLock()
+            self._thread: Optional[threading.Thread] = None
+            self._exes: Dict = {}
+            self._warm = False
+            self._shutdown = False
+            self._steps_since_poll = 0
+            # decode steps dispatched since the last blocking read returned:
+            # what the next read waits behind (serve.sync's steps_queued)
+            self._steps_unsynced = 0
+            self._window_t0_ns: Optional[int] = None
+            self._window_steps = 0
+            # emitted_tokens / polls: every lane's progress as the polls saw
+            # it (Request.n_emitted is brought up to date at the same poll)
+            self.stats = dict(submitted=0, admitted=0, completed=0,
+                              cancelled=0, rejected=0, slots_reused=0,
+                              decode_steps=0, prefills=0, prefill_chunks=0,
+                              spec_proposed=0, spec_accepted=0,
+                              emitted_tokens=0, polls=0)
+            # top-K most expensive terminal requests (heap of
+            # (total_s, req id, cost dict)) — the /slo cost table
+            self._cost_top: List[tuple] = []
+            self._cost_topk = 10
+            # goodput ledger (serve.goodput.* family): dispatch windows and
+            # admissions charge compute (or compile when a retrace happened
+            # inside the window), serve_forever's empty-queue sleeps charge
+            # idle, preemption drains charge preemption_recovery; the
+            # unattributed residual folds into idle — an un-pumped engine
+            # is waiting, not computing. Started after warmup so the
+            # one-time compile storm doesn't poison steady-state goodput.
+            from ..core import goodput as goodput_mod
+            self._goodput = goodput_mod.GoodputLedger(
+                "serve", default_bucket="idle")
+            # ------------------------------------------------ HBM planning
+            # admission control for MEMORY, before a single buffer compiles:
+            # with a budget declared (kwarg > enable_serving > env), the
+            # static planner (analysis.memory) predicts the engine's peak —
+            # weights + kv pool + lanes resident, plus the decode/admission
+            # transients — and a config that cannot fit fails HERE, not as
+            # an on-device OOM under traffic (the kv_pages-too-small
+            # fail-fast contract). health() reports the headroom.
+            self._mem_summary = None
+            self.hbm_budget = None
+            from ..analysis.memory import resolve_hbm_budget
+            explicit_budget = _opt(hbm_budget, "hbm_budget", None)
+            if explicit_budget is not None:
+                # an explicit (kwarg / enable_serving) garbage budget
+                # RAISES: the operator asked for a gate and must get one
+                self.hbm_budget = resolve_hbm_budget(explicit_budget)
+            else:
+                try:
+                    self.hbm_budget = resolve_hbm_budget()
+                except ValueError as e:
+                    # a garbage ENV budget must not crash (or silently
+                    # gate) the engine: swallow observably, serve ungated
+                    monitor.record_swallowed("serving.hbm_budget", e)
+            if self.hbm_budget is not None:
+                mp = self.memory_plan()
+                if mp["predicted_peak_bytes"] > self.hbm_budget:
+                    raise ValueError(
+                        f"predicted peak HBM {mp['predicted_peak_bytes']} "
+                        f"bytes exceeds hbm_budget {self.hbm_budget} "
+                        f"(weights {mp['weights_bytes']}, kv cache "
+                        f"{mp['kv_cache_bytes']}, lanes "
+                        f"{mp['lanes_bytes']}, decode peak "
+                        f"{mp['decode_peak_bytes']}, admission prefill "
+                        f"peak {mp['prefill_peak_bytes']}); shrink "
+                        "max_batch/cache_max_len/kv_pages or quantize the "
+                        "cache (kv_cache_dtype='int8'), or raise the "
+                        "budget (PADDLE_HBM_BUDGET / "
+                        "enable_serving(hbm_budget=...))")
+            # live export surface: opt-in via telemetry_port= (here or in
+            # Config.enable_serving) or PADDLE_TELEMETRY_PORT. Started
+            # BEFORE warmup so /healthz answers while the replica warms
+            # (/readyz stays 503 until warm — a router must not route yet).
+            # A bind failure (port still held by a drained-but-not-stopped
+            # predecessor) must never crash the engine it would measure:
+            # the engine serves un-scraped, the swallow is logged.
+            self.telemetry = None
+            tp = _opt(telemetry_port, "telemetry_port", None)
+            from ..core import telemetry_server
             try:
-                self.hbm_budget = resolve_hbm_budget()
-            except ValueError as e:
-                # a garbage ENV budget must not crash (or silently
-                # gate) the engine: swallow observably, serve ungated
-                monitor.record_swallowed("serving.hbm_budget", e)
-        if self.hbm_budget is not None:
-            mp = self.memory_plan()
-            if mp["predicted_peak_bytes"] > self.hbm_budget:
-                raise ValueError(
-                    f"predicted peak HBM {mp['predicted_peak_bytes']} "
-                    f"bytes exceeds hbm_budget {self.hbm_budget} "
-                    f"(weights {mp['weights_bytes']}, kv cache "
-                    f"{mp['kv_cache_bytes']}, lanes "
-                    f"{mp['lanes_bytes']}, decode peak "
-                    f"{mp['decode_peak_bytes']}, admission prefill "
-                    f"peak {mp['prefill_peak_bytes']}); shrink "
-                    "max_batch/cache_max_len/kv_pages or quantize the "
-                    "cache (kv_cache_dtype='int8'), or raise the "
-                    "budget (PADDLE_HBM_BUDGET / "
-                    "enable_serving(hbm_budget=...))")
-        # live export surface: opt-in via telemetry_port= (here or in
-        # Config.enable_serving) or PADDLE_TELEMETRY_PORT. Started
-        # BEFORE warmup so /healthz answers while the replica warms
-        # (/readyz stays 503 until warm — a router must not route yet).
-        # A bind failure (port still held by a drained-but-not-stopped
-        # predecessor) must never crash the engine it would measure:
-        # the engine serves un-scraped, the swallow is logged.
-        self.telemetry = None
-        tp = _opt(telemetry_port, "telemetry_port", None)
-        from ..core import telemetry_server
-        try:
-            if tp is not None:
-                self.telemetry = telemetry_server.TelemetryServer(
-                    port=int(tp)).start().attach_engine(self)
-            else:
-                self.telemetry = telemetry_server.start_from_env(self)
-        except OSError as e:
-            monitor.record_swallowed("serving.telemetry_bind", e)
-        # fleet plane opt-in (PADDLE_FLEET_STORE=host:port, exported by
-        # the launcher's --fleet_store): publish this replica's metrics
-        # + health to the shared TCPStore; on the elected rank the
-        # member also aggregates, and the aggregator rides this
-        # process's telemetry server at /fleet/*. A bad address or an
-        # unreachable store must never take the replica down.
-        self.fleet = None
-        try:
-            from ..distributed import fleet_telemetry
-            self.fleet = fleet_telemetry.start_from_env(
-                health_fn=self.health)
-            if self.fleet is not None and \
-                    self.fleet.aggregator is not None and \
-                    self.telemetry is not None:
-                self.telemetry.attach_aggregator(self.fleet.aggregator)
-        except Exception as e:
-            monitor.record_swallowed("serving.fleet_start", e)
-        if warmup:
+                if tp is not None:
+                    self.telemetry = telemetry_server.TelemetryServer(
+                        port=int(tp)).start().attach_engine(self)
+                else:
+                    self.telemetry = telemetry_server.start_from_env(self)
+            except OSError as e:
+                monitor.record_swallowed("serving.telemetry_bind", e)
+            # fleet plane opt-in (PADDLE_FLEET_STORE=host:port, exported by
+            # the launcher's --fleet_store): publish this replica's metrics
+            # + health to the shared TCPStore; on the elected rank the
+            # member also aggregates, and the aggregator rides this
+            # process's telemetry server at /fleet/*. A bad address or an
+            # unreachable store must never take the replica down.
+            self.fleet = None
             try:
-                self.warmup()
-            except BaseException:
-                # constructor abort: the caller never gets a handle, so
-                # shutdown() can never release the port — stop the
-                # server here or it leaks (bound, answering "engine
-                # gone" forever, blocking the retried engine's bind)
-                if self.telemetry is not None:
-                    self.telemetry.stop()
-                    self.telemetry = None
-                if self.fleet is not None:
-                    self.fleet.stop()
-                    self.fleet = None
-                raise
-        self._goodput.start()
+                from ..distributed import fleet_telemetry
+                self.fleet = fleet_telemetry.start_from_env(
+                    health_fn=self.health)
+                if self.fleet is not None and \
+                        self.fleet.aggregator is not None and \
+                        self.telemetry is not None:
+                    self.telemetry.attach_aggregator(self.fleet.aggregator)
+            except Exception as e:
+                monitor.record_swallowed("serving.fleet_start", e)
+            if warmup:
+                try:
+                    self.warmup()
+                except BaseException:
+                    # constructor abort: the caller never gets a handle, so
+                    # shutdown() can never release the port — stop the
+                    # server here or it leaks (bound, answering "engine
+                    # gone" forever, blocking the retried engine's bind)
+                    if self.telemetry is not None:
+                        self.telemetry.stop()
+                        self.telemetry = None
+                    if self.fleet is not None:
+                        self.fleet.stop()
+                        self.fleet = None
+                    raise
+            self._goodput.start()
 
     # ------------------------------------------------------ compilation
     def _ensure_eval(self):
@@ -1020,16 +1047,17 @@ class ServingEngine:
         prefill is enabled). After this, live traffic only ever hits
         warm executables; any later compile is recorded as
         ``jit.compile{cause=new_shape}``."""
-        for b in self.buckets:
-            self._exe_prefill(b)
-        self._exe_step()
-        self._exe_admit()
-        self._exe_free()
-        if self._chunk_enabled:
-            self._exe_chunk()
-            self._exe_chunk_final()
-            if self._alloc is not None:
-                self._exe_span()
+        with flight_recorder.span("setup.warmup"):
+            for b in self.buckets:
+                self._exe_prefill(b)
+            self._exe_step()
+            self._exe_admit()
+            self._exe_free()
+            if self._chunk_enabled:
+                self._exe_chunk()
+                self._exe_chunk_final()
+                if self._alloc is not None:
+                    self._exe_span()
         self._warm = True
         return self
 
@@ -1079,7 +1107,6 @@ class ServingEngine:
                     f"{reason}", reason=reason, request=req)
             if self.trace_sample and req.id % self.trace_sample == 0:
                 req.traced = True
-                req._t_submit_ns = flight_recorder.now_ns()
             self._queue.append(req)
             self.stats["submitted"] += 1
             qdepth = len(self._queue)
@@ -1127,15 +1154,18 @@ class ServingEngine:
         dispatches BEFORE the chunk's blocking sync, so in-flight
         streams overlap the chunk's device time instead of stalling
         behind a whole long prefill — the head-of-line fix."""
-        with self._pump_lock:
+        with self._pump_lock, flight_recorder.span("serve.step") as sp:
             self._admit_ready()
-            if any(s is not None
-                   and s.status is RequestStatus.RUNNING
-                   for s in self._slots):
+            live = sum(s is not None
+                       and s.status is RequestStatus.RUNNING
+                       for s in self._slots)
+            if live:
                 self._dispatch_decode()
             self._advance_chunked()
             if self._steps_since_poll >= self.poll_every:
                 self._poll()
+            sp.set(decode=int(live > 0), live=live,
+                   queued=len(self._queue))
 
     def _unblock_if(self, req: Request):
         """Clear the page-pressure flag when the request it was
@@ -1231,50 +1261,78 @@ class ServingEngine:
                              label="error")
                 monitor.record_swallowed("serving.admit", e)
 
-    def _admit(self, req: Request, slot: int):
-        # admission wall time is compute in the goodput ledger — or
-        # compile, when the dispatch retraced (a cold bucket slipping
-        # past warmup spends the window tracing, not prefilling)
-        retraces0 = monitor.retrace_count()
-        t_admit = time.perf_counter()
-        try:
-            self._admit_inner(req, slot)
-        finally:
-            dt = time.perf_counter() - t_admit
-            # cost attribution mirrors the ledger charge: the request
-            # owns exactly the admission wall the ledger books, so
-            # per-request costs reconcile against the compute bucket
-            req._cost_prefill_s += dt
-            self._goodput.charge(
-                "compile" if monitor.retrace_count() > retraces0
-                else "compute", dt)
+    def _sync(self, site: str, read):
+        """One blocking device read under a ``serve.sync`` span:
+        ``(what read() returned, the stamp at which it returned)``."""
+        with flight_recorder.span("serve.sync", site=site,
+                                  steps_queued=self._steps_unsynced) as sp:
+            out = read()
+        self._steps_unsynced = 0
+        return out, sp.end_ns or flight_recorder.now_ns()
 
-    def _admit_inner(self, req: Request, slot: int):
+    def _dequeued(self, req: Request, sp, bucket: int) -> int:
+        """``req`` has left the queue and ``sp`` (its ``serve.admit``)
+        is open: the one stamp that ends its queue wait, starts its
+        prefill and is its ``admitted_at``."""
+        t = sp.start_ns or flight_recorder.now_ns()
+        req.admitted_at = t * 1e-9
+        if flight_recorder.enabled:
+            req.stage_span("serve.queue_wait",
+                           int(req.submitted_at * 1e9), t, bucket=bucket)
+        return t
+
+    def _first_token(self, req: Request, t_admit_ns: int, t_ns: int,
+                     bucket: int):
+        """The prefill's sync returned at ``t_ns``: the TTFT
+        measurement point."""
+        req.first_token_at = t_ns * 1e-9
+        monitor.record_serve_ttft(req.first_token_at - req.submitted_at)
+        if flight_recorder.enabled:
+            req.stage_span("serve.prefill", t_admit_ns, t_ns,
+                           bucket=bucket)
+        if req.traced:
+            req._t_seg_ns = t_ns
+
+    def _charge_admission(self, req: Request, dt: float, retraced: bool):
+        """Admission wall time is compute in the goodput ledger — or
+        compile, when the dispatch retraced (a cold bucket slipping past
+        warmup spends the window tracing, not prefilling). Cost
+        attribution mirrors the ledger charge: the request owns exactly
+        the admission wall the ledger books, so per-request costs
+        reconcile against the compute bucket."""
+        req._cost_prefill_s += dt
+        self._goodput.charge("compile" if retraced else "compute", dt)
+
+    def _admit(self, req: Request, slot: int):
+        retraces0 = monitor.retrace_count()
         bucket = next(b for b in self.buckets if b >= req.prompt.size)
+        sp = flight_recorder.span("serve.admit", req=req.id, slot=slot,
+                                  bucket=bucket,
+                                  prompt=int(req.prompt.size))
+        t0 = 0
+        try:
+            with sp:
+                t0 = self._dequeued(req, sp, bucket)
+                self._admit_inner(req, slot, bucket, t0)
+        finally:
+            t1 = sp.end_ns or flight_recorder.now_ns()
+            self._charge_admission(
+                req, (t1 - (t0 or t1)) * 1e-9,
+                monitor.retrace_count() > retraces0)
+
+    def _admit_inner(self, req: Request, slot: int, bucket: int,
+                     t_admit_ns: int):
         ids = np.full((1, bucket), self._cfg.pad_value, np.int32)
         ids[0, :req.prompt.size] = req.prompt
         plen = np.array([req.prompt.size], np.int32)
-        t_admit_ns = flight_recorder.now_ns() if req.traced else 0
         exe = self._exe_prefill(bucket)
         tok, row_cache, self._key, fin = exe(
             self._state, jnp.asarray(ids), jnp.asarray(plen), self._key)
         # TTFT measurement point: the request's first token exists once
         # the prefill lands — one small sync per ADMISSION (not per
         # decode step)
-        tok.block_until_ready()
-        now = time.monotonic()
-        req.admitted_at = req.first_token_at = now
-        monitor.record_serve_ttft(now - req.submitted_at)
-        if flight_recorder.enabled:
-            flight_recorder.record("serve.admit", req=req.id, slot=slot,
-                                   bucket=bucket)
-        if req.traced:
-            # the sampled request's first two trace segments: time spent
-            # queued, then the (synchronous) prefill-into-slot
-            t1 = flight_recorder.now_ns()
-            req.span("queue_wait", req._t_submit_ns, t_admit_ns)
-            req.span("prefill", t_admit_ns, t1, bucket=bucket, slot=slot)
-            req._t_seg_ns = t1
+        _, t1 = self._sync("prefill", tok.block_until_ready)
+        self._first_token(req, t_admit_ns, t1, bucket)
         monitor.record_generation(prefill_steps=1)
         self.stats["prefills"] += 1
         admit = self._exe_admit()
@@ -1343,21 +1401,22 @@ class ServingEngine:
         C = self.prefill_chunk_tokens
         plen = int(req.prompt.size)
         n = -(-plen // C)
-        ids = np.full((1, n * C), self._cfg.pad_value, np.int32)
-        ids[0, :plen] = req.prompt
-        shared = 0
-        if self._alloc is not None:
-            shared = int(self._pending_pages[req.id][1].shared_len)
-        t_ns = flight_recorder.now_ns() if req.traced else 0
-        if req.traced:
-            req.span("queue_wait", req._t_submit_ns, t_ns)
-        self._chunking = dict(req=req, slot=slot, plen=plen, n=n,
-                              next=0, ids=ids, shared=shared,
-                              decode_steps=0, t_ns=t_ns)
-        self._slots[slot] = req  # lint: lock-discipline-ok (admission runs under the caller's pump lock)
-        req.status = RequestStatus.PENDING_PREFILL
-        monitor.record_serve_slot_occupancy(
-            sum(s is not None for s in self._slots) / self.max_batch)
+        with flight_recorder.span("serve.admit", req=req.id, slot=slot,
+                                  bucket=n * C, prompt=plen,
+                                  chunks=n) as sp:
+            t_ns = self._dequeued(req, sp, n * C)
+            ids = np.full((1, n * C), self._cfg.pad_value, np.int32)
+            ids[0, :plen] = req.prompt
+            shared = 0
+            if self._alloc is not None:
+                shared = int(self._pending_pages[req.id][1].shared_len)
+            self._chunking = dict(req=req, slot=slot, plen=plen, n=n,
+                                  next=0, ids=ids, shared=shared,
+                                  decode_steps=0, t_ns=t_ns)
+            self._slots[slot] = req  # lint: lock-discipline-ok (admission runs under the caller's pump lock)
+            req.status = RequestStatus.PENDING_PREFILL
+            monitor.record_serve_slot_occupancy(
+                sum(s is not None for s in self._slots) / self.max_batch)
 
     def _advance_chunked(self):
         """Run AT MOST ONE chunk of the in-flight chunked prefill: the
@@ -1379,7 +1438,7 @@ class ServingEngine:
         # the request's prefill cost — chunked admissions sum their
         # per-chunk walls instead of under-charging one instant
         retraces0 = monitor.retrace_count()
-        t0 = time.perf_counter()
+        t0 = flight_recorder.now_ns()
         try:
             if st["next"] < st["n"] - 1:
                 self._chunk_step(st)
@@ -1391,11 +1450,9 @@ class ServingEngine:
                 label="error")
             monitor.record_swallowed("serving.admit", e)
         finally:
-            dt = time.perf_counter() - t0
-            req._cost_prefill_s += dt
-            self._goodput.charge(
-                "compile" if monitor.retrace_count() > retraces0
-                else "compute", dt)
+            self._charge_admission(
+                req, (flight_recorder.now_ns() - t0) * 1e-9,
+                monitor.retrace_count() > retraces0)
             # the blocking chunk sync must not be attributed to
             # per-token decode latency: re-anchor the poll window
             # (the same artifact class as inline admission)
@@ -1427,7 +1484,7 @@ class ServingEngine:
         # the chunk must LAND before the host moves on: the sync point
         # is what bounds how long a chunk can monopolize the device
         # between decode dispatches
-        self._row_cache.kv_len.block_until_ready()  # lint: host-sync-ok (one sync per prefill chunk, the interleave cadence)
+        _, t1 = self._sync("chunk", self._row_cache.kv_len.block_until_ready)
         st["next"] = k + 1
         tokens = min(C, st["plen"] - k * C)
         self.stats["prefill_chunks"] += 1
@@ -1436,9 +1493,8 @@ class ServingEngine:
             flight_recorder.record(
                 "serve.prefill_chunk", req=req.id, slot=slot, chunk=k,
                 start=k * C, tokens=tokens, remaining=st["n"] - k - 1)
-        if req.traced:
-            req.span("prefill_chunk", t_ns, flight_recorder.now_ns(),
-                     chunk=k, slot=slot, tokens=tokens)
+        req.span("prefill_chunk", t_ns, t1, chunk=k, slot=slot,
+                 tokens=tokens)
 
     def _finish_chunked(self, st: dict):
         """The final (padded) chunk + admission: sample the first token
@@ -1454,10 +1510,8 @@ class ServingEngine:
             self._state, ids, plen, self._key, self._row_cache)
         self._row_cache = row_cache
         # TTFT measurement point — same contract as inline admission
-        tok.block_until_ready()  # lint: host-sync-ok (TTFT measurement point, one per admission)
-        now = time.monotonic()
-        req.admitted_at = req.first_token_at = now
-        monitor.record_serve_ttft(now - req.submitted_at)
+        _, t1 = self._sync("prefill", tok.block_until_ready)
+        self._first_token(req, st["t_ns"], t1, st["n"] * C)
         tokens = st["plen"] - k * C
         self.stats["prefill_chunks"] += 1
         monitor.record_prefill_chunk(tokens)
@@ -1467,13 +1521,8 @@ class ServingEngine:
             flight_recorder.record(
                 "serve.prefill_chunk", req=req.id, slot=slot, chunk=k,
                 start=k * C, tokens=tokens, remaining=0)
-            flight_recorder.record("serve.admit", req=req.id, slot=slot,
-                                   bucket=st["n"] * C, chunks=st["n"])
-        if req.traced:
-            t1 = flight_recorder.now_ns()
-            req.span("prefill_chunk", t_ns, t1, chunk=k, slot=slot,
-                     tokens=tokens)
-            req._t_seg_ns = t1
+        req.span("prefill_chunk", t_ns, t1, chunk=k, slot=slot,
+                 tokens=tokens)
         monitor.record_generation(prefill_steps=1)
         self.stats["prefills"] += 1
         admit = self._exe_admit()
@@ -1550,21 +1599,24 @@ class ServingEngine:
 
     def _dispatch_decode(self):
         exe = self._exe_step()
-        if self._spec is None:
-            (self._tok, self._cache, self._key, self._finished,
-             self._steps, self._budget, self._out_buf) = exe(
-                self._state, self._tok, self._cache, self._key,
-                self._finished, self._steps, self._budget,
-                self._out_buf)
-        else:
-            (self._tok, self._cache, self._key, self._finished,
-             self._steps, self._budget, self._out_buf, self._tok_buf,
-             self._tok_len, self._proposed, self._accepted) = exe(
-                self._state, self._tok, self._cache, self._key,
-                self._finished, self._steps, self._budget,
-                self._out_buf, self._tok_buf, self._tok_len,
-                self._proposed, self._accepted)
+        with flight_recorder.span("serve.dispatch") as sp:
+            if self._spec is None:
+                (self._tok, self._cache, self._key, self._finished,
+                 self._steps, self._budget, self._out_buf) = exe(
+                    self._state, self._tok, self._cache, self._key,
+                    self._finished, self._steps, self._budget,
+                    self._out_buf)
+            else:
+                (self._tok, self._cache, self._key, self._finished,
+                 self._steps, self._budget, self._out_buf,
+                 self._tok_buf, self._tok_len, self._proposed,
+                 self._accepted) = exe(
+                    self._state, self._tok, self._cache, self._key,
+                    self._finished, self._steps, self._budget,
+                    self._out_buf, self._tok_buf, self._tok_len,
+                    self._proposed, self._accepted)
         self._steps_since_poll += 1
+        self._steps_unsynced += 1
         if self._chunking is not None:
             # decode steps interleaved into THIS chunked admission —
             # the serve.prefill.interleave_ratio numerator
@@ -1573,26 +1625,38 @@ class ServingEngine:
             # anchor the latency window at the first dispatch after a
             # poll — idle gaps between traffic bursts must not be
             # attributed to per-token latency
-            self._window_t0 = time.monotonic()
+            self._window_t0_ns = sp.end_ns or flight_recorder.now_ns()
         self._window_steps += 1
         self.stats["decode_steps"] += 1
         monitor.record_generation(decode_steps=1)
+
+    def _read_lanes(self):
+        """The poll's blocking read: the [batch] finished/step lanes and,
+        in the same window, the on-device speculation counters (two
+        int32 scalars — no extra sync cadence)."""
+        fin = np.asarray(self._finished)  # lint: host-sync-ok (scheduler poll, every poll_every steps)
+        steps = np.asarray(self._steps)  # lint: host-sync-ok (same poll read)
+        if self._spec is None:
+            return fin, steps, 0, 0
+        return (fin, steps,
+                int(np.asarray(self._proposed)),  # lint: host-sync-ok (same poll read)
+                int(np.asarray(self._accepted)))  # lint: host-sync-ok (same poll read)
 
     def _poll(self):
         """Scheduler poll: read the [batch] finished/step lanes (the
         only per-window host sync on the decode path), complete
         finished rows, cancel over-deadline ones, time the window."""
-        self._steps_since_poll = 0
-        fin = np.asarray(self._finished)  # lint: host-sync-ok (scheduler poll, every poll_every steps)
-        steps = np.asarray(self._steps)  # lint: host-sync-ok (same poll read)
+        with flight_recorder.span("serve.poll") as sp:
+            self._poll_lanes(sp)
+
+    def _poll_lanes(self, sp):
+        covered, self._steps_since_poll = self._steps_since_poll, 0
+        (fin, steps, prop, acc), t_ns = self._sync("poll",
+                                                   self._read_lanes)
         if self._spec is not None:
-            # drain the on-device speculation counters in the same poll
-            # window (two int32 scalars — no extra sync cadence). The
-            # device counters are lifetime-monotonic int32 and WRAP on
-            # a long-lived engine; per-poll deltas are tiny, so modular
-            # subtraction recovers them exactly across the wrap
-            prop = int(np.asarray(self._proposed))  # lint: host-sync-ok (same poll read)
-            acc = int(np.asarray(self._accepted))  # lint: host-sync-ok (same poll read)
+            # the device counters are lifetime-monotonic int32 and WRAP
+            # on a long-lived engine; per-poll deltas are tiny, so
+            # modular subtraction recovers them exactly across the wrap
             dp = (prop - self._spec_seen[0]) % (1 << 32)
             da = (acc - self._spec_seen[1]) % (1 << 32)
             if dp or da:
@@ -1600,16 +1664,16 @@ class ServingEngine:
                 self.stats["spec_proposed"] += dp
                 self.stats["spec_accepted"] += da
                 monitor.record_speculative(dp, da)
-        now = time.monotonic()
+        now = t_ns * 1e-9
         window_dt = 0.0
-        if self._window_t0 is not None and self._window_steps:
-            window_dt = now - self._window_t0
+        if self._window_t0_ns is not None and self._window_steps:
+            window_dt = (t_ns - self._window_t0_ns) * 1e-9
             monitor.record_serve_token_latency(
                 window_dt / self._window_steps)
             # the dispatch window (host dispatches + the device wait
             # the lane reads above just paid) is goodput compute
             self._goodput.charge("compute", window_dt)
-        self._window_steps = 0   # next dispatch re-anchors _window_t0
+        self._window_steps = 0   # next dispatch re-anchors the window
         if window_dt > 0.0:
             # cost attribution: every live request owns an equal share
             # of the window the ledger just booked as compute (shares
@@ -1634,7 +1698,7 @@ class ServingEngine:
                         pages = self._row_pages[i]
                         if pages:
                             r._cost_page_s += len(pages) * window_dt
-        t_poll_ns = flight_recorder.now_ns()
+        emitted = admitted = completed = evicted = 0
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1643,20 +1707,33 @@ class ServingEngine:
                 # flag reads True) — completion/deadline/trace handling
                 # belongs to _advance_chunked, not the decode poll
                 continue
+            # the lane's progress since the last poll saw it (a lane
+            # polled for the first time brings its prefill's token)
+            n = int(steps[i])
+            admitted += req.n_emitted == 0
+            emitted += n - req.n_emitted
+            req.n_emitted = n
             if fin[i]:
-                toks = np.asarray(self._out_buf[i])[:int(steps[i])]  # lint: host-sync-ok (one row read per completion)
-                self._complete(req, toks)
+                row, _ = self._sync(
+                    "row", lambda: np.asarray(self._out_buf[i]))  # lint: host-sync-ok (one row read per completion)
+                self._complete(req, row[:n])
+                completed += 1
                 # freed in place; the next admission overwrites the row
                 self._slots[i] = None  # lint: lock-discipline-ok (poll runs under the caller's pump lock)
                 self._free_slot_pages(i)
             elif req.deadline is not None and now > req.deadline:
-                self._evict(i, req, "deadline", int(steps[i]))
+                self._evict(i, req, "deadline", n)
+                evicted += 1
             elif req.traced:
                 # rolling decode segment: one span per poll window, so
                 # a mid-flight dump shows how far the request got
-                req.span("decode", req._t_seg_ns, t_poll_ns,
-                         tokens=int(steps[i]))
-                req._t_seg_ns = t_poll_ns
+                req.span("decode", req._t_seg_ns, t_ns, tokens=n)
+                req._t_seg_ns = t_ns
+        self.stats["emitted_tokens"] += emitted
+        self.stats["polls"] += 1
+        sp.set(steps=covered, emitted=emitted, admitted=admitted,
+               completed=completed, evicted=evicted,
+               live=sum(s is not None for s in self._slots))
         # expire queued requests that can no longer meet their deadline
         with self._qlock:
             for req in list(self._queue):
@@ -1714,7 +1791,8 @@ class ServingEngine:
         self._cache, self._finished = exe(
             self._cache, self._finished, jnp.asarray(slot, jnp.int32))
         if n_done:
-            row = np.asarray(self._out_buf[slot])  # lint: host-sync-ok (partial row on eviction)
+            row, _ = self._sync(
+                "row", lambda: np.asarray(self._out_buf[slot]))  # lint: host-sync-ok (partial row on eviction)
             req.tokens = row[:n_done].astype(np.int32)
             req.n_emitted = n_done
         self._slots[slot] = None  # lint: lock-discipline-ok (eviction runs under the caller's pump lock)
@@ -1786,7 +1864,8 @@ class ServingEngine:
         as the speculation counters)."""
         if getattr(self._cache, "clips", None) is None:
             return
-        clips = int(np.asarray(self._cache.clips))  # lint: host-sync-ok (scheduler poll, tiny scalar)
+        clips, _ = self._sync(
+            "stats", lambda: int(np.asarray(self._cache.clips)))  # lint: host-sync-ok (scheduler poll, tiny scalar)
         d = (clips - self._clips_seen) % (1 << 32)
         if d:
             self._clips_seen = clips
@@ -1837,7 +1916,7 @@ class ServingEngine:
                                           for s in self._slots))
                         flight_recorder.auto_dump("preemption")
                     compute0 = self._goodput.bucket_total("compute")
-                    t_drain = time.perf_counter()
+                    t_drain = flight_recorder.now_ns()
                     self.drain()
                     if preempted_drain:
                         # the preemption-recovery bucket gets the drain
@@ -1847,8 +1926,8 @@ class ServingEngine:
                             - compute0
                         self._goodput.charge(
                             "preemption_recovery",
-                            max(time.perf_counter() - t_drain - dc,
-                                0.0))
+                            max((flight_recorder.now_ns() - t_drain)
+                                * 1e-9 - dc, 0.0))
                     break
                 while it is not None and not exhausted and \
                         self._queue_room():

@@ -110,20 +110,24 @@ class Request:
         self.tokens: Optional[np.ndarray] = None   # eos-trimmed on success
         self.n_emitted = 0                    # raw tokens incl. eos
         self.submitted_at = time.monotonic()
+        # when the request LEFT THE QUEUE for a slot (the end of its
+        # serve.queue_wait), not "its admission succeeded": a request
+        # whose prefill then raises is CANCELLED with this set and
+        # first_token_at None
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self._engine = engine
         self._event = threading.Event()
-        # ---- per-request tracing (tentpole 2): every request carries a
-        # trace id; SAMPLED requests (the engine sets traced=True for
-        # 1-in-N) additionally record queue-wait/prefill/decode spans
-        # into the flight recorder, so a dump or a Perfetto export shows
-        # what each in-flight request was doing. The off path is one
+        # ---- per-request tracing: every request carries a trace id and
+        # records serve.queue_wait + serve.prefill into the flight
+        # recorder; SAMPLED requests (the engine sets traced=True for
+        # 1-in-N) additionally record a decode segment per poll (and
+        # their prefill chunks), so a dump or a Perfetto export shows
+        # how far each in-flight request got. The off path is one
         # attribute check (gated by test_overhead_gate).
         self.trace_id = f"{os.getpid():x}.{self.id}"
         self.traced = False
-        self._t_submit_ns = 0   # set by the engine when traced
         self._t_seg_ns = 0      # rolling decode-segment anchor
         # ---- cost attribution (SLO watchtower): the engine charges
         # prefill wall at admission, this request's share of every poll
@@ -134,16 +138,24 @@ class Request:
         self._cost_page_s = 0.0
 
     def span(self, name: str, start_ns: int, end_ns: int, **fields):
-        """Record one trace span for this request (no-op unless the
-        engine sampled it). Spans land in the flight recorder ring and,
-        through it, in the Profiler's Perfetto export; the tid keys
-        each request onto its own trace row."""
+        """Record one sampled trace segment for this request (no-op
+        unless the engine sampled it). Spans land in the flight recorder
+        ring and, through it, in the Profiler's Perfetto export; the tid
+        keys each request onto its own trace row."""
         if not self.traced:
             return
         flight_recorder.record_span(
             f"req{self.id}.{name}", start_ns, end_ns,
             trace_id=self.trace_id, tid=1000 + self.id % 64,
             req=self.id, **fields)
+
+    def stage_span(self, name: str, start_ns: int, end_ns: int,
+                   **fields):
+        """``serve.queue_wait`` / ``serve.prefill``: recorded for EVERY
+        request, under its trace id."""
+        flight_recorder.record_span(
+            name, start_ns, end_ns, trace_id=self.trace_id,
+            tid=1000 + self.id % 64, req=self.id, **fields)
 
     # ------------------------------------------------------------ handle
     def done(self) -> bool:
@@ -180,21 +192,21 @@ class Request:
             return
         self.status = status
         self.detail = detail
-        self.finished_at = time.monotonic()
+        t = flight_recorder.now_ns()
+        self.finished_at = t * 1e-9
         if flight_recorder.enabled:
             flight_recorder.record(
-                "serve.finish", req=self.id, status=status.value,
+                "serve.finish", t_ns=t, req=self.id, status=status.value,
                 tokens=self.n_emitted,
                 **({"detail": detail} if detail else {}))
-            if self.traced:
-                t = flight_recorder.now_ns()
-                if self.admitted_at is None and self._t_submit_ns:
-                    # never admitted: its whole life was queue wait
-                    self.span("queue_wait", self._t_submit_ns, t,
-                              status=status.value)
-                elif self._t_seg_ns:
-                    self.span("decode", self._t_seg_ns, t,
-                              tokens=self.n_emitted, status=status.value)
+            if self.admitted_at is None:
+                # never left the queue: its whole life was queue wait
+                self.stage_span("serve.queue_wait",
+                                int(self.submitted_at * 1e9), t,
+                                status=status.value)
+            elif self.traced and self._t_seg_ns:
+                self.span("decode", self._t_seg_ns, t,
+                          tokens=self.n_emitted, status=status.value)
         self._event.set()
 
     # ----------------------------------------------------------- timings

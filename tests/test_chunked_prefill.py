@@ -320,7 +320,7 @@ def test_chunk_metrics_and_flight_events(tiny_gpt):
     # the traced request carries per-chunk spans (the preemption-dump
     # evidence the chaos test asserts end to end)
     spans = [s for s in flight_recorder.spans_between(0, 2 ** 62)
-             if s[0] == f"req{h.id}.prefill_chunk"]
+             if s.name == f"req{h.id}.prefill_chunk"]
     assert len(spans) == 3
     eng.shutdown()
 

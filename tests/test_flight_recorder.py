@@ -65,7 +65,106 @@ class TestRing:
         fr.record("test.point")  # point events never surface as spans
         fr.record_span("early", t0 - 5000, t0 - 4000)
         spans = fr.spans_between(t0 - 100, t0 + 2000)
-        assert spans == [("req1.decode", t0, t0 + 1000, 1001, 0)]
+        assert [tuple(s) for s in spans] == [
+            ("req1.decode", t0, t0 + 1000, 1001, spans[0].id, None,
+             "x.1", {"tokens": 3})]
+
+    def test_span_helper_links_parent_and_child(self):
+        with fr.span("serve.step", queued=2) as outer:
+            with fr.span("serve.poll") as inner:
+                inner.set(emitted=3)
+            explicit = fr.record_span("serve.sync", outer.start_ns,
+                                      outer.start_ns + 5,
+                                      parent=outer.id, site="poll")
+        poll, sync, step = fr.spans_between(0, 2 ** 62)  # end order
+        assert (step.name, step.parent, step.fields) == \
+            ("serve.step", None, {"queued": 2})
+        assert (poll.name, poll.parent, poll.fields) == \
+            ("serve.poll", step.id, {"emitted": 3})
+        assert (sync.id, sync.parent, sync.fields) == \
+            (explicit, step.id, {"site": "poll"})
+        assert len({poll.id, sync.id, step.id}) == 3
+        assert step.start_ns <= poll.start_ns <= poll.end_ns \
+            <= step.end_ns == outer.end_ns
+
+    def test_span_parent_is_per_thread(self):
+        import threading
+        seen = {}
+
+        def other():
+            with fr.span("serve.step") as sp:
+                seen["parent"] = sp.parent
+        with fr.span("setup.warmup"):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+        assert seen["parent"] is None
+
+    def test_span_closes_on_error_and_restores_parent(self):
+        with pytest.raises(ValueError):
+            with fr.span("serve.step"):
+                with fr.span("serve.admit"):
+                    raise ValueError("boom")
+        with fr.span("serve.step") as after:
+            pass
+        assert after.parent is None        # the thread's parent is back
+        assert [s.name for s in fr.spans_between(0, 2 ** 62)] == \
+            ["serve.admit", "serve.step", "serve.step"]
+
+    def test_span_records_through_record_span(self):
+        """``span()`` draws its id at the start (children name it) and
+        lands through ``record_span`` with that id."""
+        with fr.span("serve.step", queued=1) as outer:
+            with fr.span("serve.poll") as inner:
+                pass
+            outer.set(decode=1)
+        free = fr.record_span("serve.sync", 0, 1, site="stats")
+        by_id = {s.id: s for s in fr.spans_between(0, 2 ** 62)}
+        assert len({outer.id, inner.id, free}) == 3
+        assert by_id[inner.id].parent == outer.id
+        assert by_id[outer.id].fields == {"queued": 1, "decode": 1}
+        assert by_id[free].fields == {"site": "stats"}
+
+    def test_disabled_span_stamps_nothing(self):
+        fr.disable()
+        with fr.span("serve.step", a=1) as sp:
+            sp.set(b=2)
+        assert (sp.id, sp.start_ns, sp.end_ns) == (None, 0, 0)
+        assert fr.record_span("serve.sync", 0, 1) is None
+        fr.enable()
+        assert fr.events() == []
+
+    @pytest.mark.parametrize("cut", [False, True])
+    def test_dropped_since_tells_a_cut_window(self, cut):
+        """A reader can tell a whole window from one the ring bound cut
+        into."""
+        fr.configure(capacity=8)
+        t0 = fr.now_ns()
+        for i in range(6):
+            fr.record("test.before", i=i)
+        t_open = fr.now_ns()
+        for i in range(12 if cut else 6):
+            with fr.span("serve.step"):
+                pass
+        assert fr.dropped_since(t0) > 0        # the early ones went
+        assert (fr.dropped_since(t_open) > 0) == cut
+        fr.clear()
+        assert fr.dropped_since(0) == 0
+
+    def test_one_clock(self):
+        """The recorder's clock is the requests' and the harness's
+        (CLOCK_MONOTONIC), and agrees with perf_counter, which the
+        profiler's host spans use, to 1 ms."""
+        a, b, c = fr.now_ns(), time.perf_counter_ns(), fr.now_ns()
+        assert a <= c and abs((a + c) // 2 - b) < 1_000_000
+        assert abs(fr.now_ns() * 1e-9 - time.monotonic()) < 1e-3
+        fr.record("test.stamp")
+        assert abs(fr.events()[-1][0] - fr.now_ns()) < 1e9
+
+    def test_default_capacity_holds_a_benchmark_window(self):
+        # ISSUE 26: ~60 events/s x (set-up + 51 s + 60 s grace) x 4
+        assert fr.DEFAULT_CAPACITY == 65536 >= 4 * 60 * 180
+        assert fr.capacity() == fr.DEFAULT_CAPACITY
 
     def test_env_capacity_parse(self, monkeypatch):
         monkeypatch.setenv("PADDLE_FLIGHT_RECORDER", "off")
@@ -127,6 +226,25 @@ class TestDumps:
         finally:
             metrics.disable()
 
+    def test_auto_dump_writes_the_newest_events_only(self, tmp_path,
+                                                     monkeypatch):
+        """The crash path serialises AUTO_DUMP_EVENTS, not the whole
+        65,536-event ring; a dump on demand writes everything."""
+        monkeypatch.setenv("PADDLE_FLIGHT_RECORDER_DIR", str(tmp_path))
+        monkeypatch.setattr(fr, "AUTO_DUMP_EVENTS", 8)
+        for i in range(20):
+            fr.record("test.kind", i=i)
+        with open(fr.auto_dump("bounded")) as f:
+            d = json.load(f)
+        got = [e["args"]["i"] for e in d["traceEvents"]
+               if e["name"] == "test.kind"]
+        assert got == list(range(12, 20))
+        assert d["metadata"]["events"] == 20
+        with open(fr.dump(str(tmp_path / "all"))) as f:
+            assert len(json.load(f)["traceEvents"]) == 21
+        assert [e[2]["i"] for e in fr.events(3)] == [17, 18, 19]
+        assert len(fr.events(100)) == 20
+
     def test_auto_dump_cap(self, tmp_path, monkeypatch):
         monkeypatch.setenv("PADDLE_FLIGHT_RECORDER_DIR", str(tmp_path))
         r = fr.recorder()
@@ -177,6 +295,15 @@ class TestEventSchema:
         assert set(fr.EVENT_DOC) == set(fr.DECLARED_EVENTS)
         for name, desc in fr.EVENT_DOC.items():
             assert desc and "\n" not in desc, name
+
+    def test_declared_spans_one_line_each(self):
+        assert set(fr.DECLARED_SPANS) >= {
+            "serve.step", "serve.admit", "serve.sync", "serve.dispatch",
+            "serve.poll", "serve.queue_wait", "serve.prefill",
+            "setup.engine_init", "setup.state", "setup.cache_alloc",
+            "setup.warmup", "jit.program", "train.step"}
+        for name, desc in fr.DECLARED_SPANS.items():
+            assert desc and "\n" not in desc and "|" not in desc, name
 
     def test_generated_events_doc_is_fresh(self):
         """Tier-1 drift gate: docs/events.md must match what
@@ -236,6 +363,31 @@ class TestWiring:
         assert "req3.decode" in names
         assert "host_work" in names
 
+    def test_spans_lie_on_the_device_traces_host_plane(self, tmp_path):
+        """Each span the helper opens is a jax.profiler.TraceAnnotation:
+        while a device trace is being taken it lands on the trace's
+        host plane, in the trace's clock."""
+        import glob as _glob
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with fr.span("serve.step"):
+                with fr.span("serve.sync", site="poll"):
+                    jax.numpy.ones((8,)).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = _glob.glob(str(
+            tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        found = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+                 for plane in data.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name in ("serve.step", "serve.sync")}
+        assert set(found) == {"serve.step", "serve.sync"}
+        (s0, s1), (c0, c1) = found["serve.step"], found["serve.sync"]
+        assert s0 <= c0 <= c1 <= s1
+
     def test_fit_crash_dumps(self, tmp_path, monkeypatch):
         """An uncaught exception inside Model.fit leaves a fit_crash
         dump with the last dispatched steps in it."""
@@ -289,9 +441,11 @@ def _tiny_engine(**kw):
 
 
 def _req_spans(dump_path):
+    """{(span name, request id)} of the dump's request spans, and the
+    dump."""
     d = json.load(open(dump_path))
-    return [e for e in d["traceEvents"]
-            if e["ph"] == "X" and e["name"].startswith("req")], d
+    return {(e["name"], e["args"]["req"]) for e in d["traceEvents"]
+            if e["ph"] == "X" and "req" in e.get("args", {})}, d
 
 
 @pytest.mark.chaos
@@ -316,9 +470,8 @@ def test_watchdog_timeout_dumps_inflight_request_spans(tmp_path,
     dumps = glob.glob(str(tmp_path / "flightrecorder_watchdog_*.json"))
     assert len(dumps) == 1
     spans, d = _req_spans(dumps[0])
-    names = {e["name"] for e in spans}
-    assert f"req{h.id}.queue_wait" in names
-    assert f"req{h.id}.prefill" in names
+    assert ("serve.queue_wait", h.id) in spans
+    assert ("serve.prefill", h.id) in spans
     assert any(e["name"] == "watchdog.timeout"
                and e["args"]["label"] == "test.stall"
                for e in d["traceEvents"])
@@ -356,8 +509,7 @@ def test_sigterm_mid_serve_dumps_inflight_request_spans(tmp_path,
     # request's spans are already in the ring
     admitted = [h for h in handles if h.admitted_at is not None]
     assert admitted
-    span_names = {e["name"] for e in spans}
-    assert any(f"req{h.id}.prefill" in span_names for h in admitted)
+    assert any(("serve.prefill", h.id) in spans for h in admitted)
     # the ring (post-drain) holds the drain bracket too
     kinds = [k for _, k, _ in fr.events()]
     assert "serve.drain_begin" in kinds and "serve.drain_end" in kinds

@@ -156,12 +156,37 @@ class TestEventNameRule:
             from ..core import flight_recorder
             def f(kind):
                 flight_recorder.record("serve.typo_event", req=1)
-                flight_recorder.record("serve.admit", req=1)  # declared
+                flight_recorder.record("serve.submit", req=1)  # declared
                 flight_recorder.record(kind, req=1)   # dynamic: fine
-                flight_recorder.record_span("req3.decode", 0, 1)  # span
+                flight_recorder.record_span(f"req{kind}.decode", 0, 1)
             """, "paddle_tpu/serving/whatever.py")
         assert _rules_of(found) == ["event-name"]
         assert len(found) == 1 and "serve.typo_event" in found[0].message
+
+    def test_flags_undeclared_span_literal(self, tmp_path):
+        """Literal span names are held to DECLARED_SPANS through every
+        way of opening one."""
+        found = _lint_snippet(tmp_path, """
+            from ..core import flight_recorder
+            from ..core import flight_recorder as _flight_recorder
+            def f(req, name):
+                with flight_recorder.span("setup.typo"):
+                    pass
+                with flight_recorder.span("serve.step"):      # declared
+                    with _flight_recorder.span("serve.stepp"):
+                        pass
+                flight_recorder.record_span("serve.sync", 0, 1)  # declared
+                flight_recorder.record_span("serve.snyc", 0, 1)
+                req.stage_span("serve.queue_wiat", 0, 1)
+                req.stage_span("serve.prefill", 0, 1)          # declared
+                with flight_recorder.span(name):     # dynamic: fine
+                    pass
+            """, "paddle_tpu/serving/whatever.py")
+        assert _rules_of(found) == ["event-name"] and len(found) == 4
+        assert sorted(f.message.split("'")[1] for f in found) == [
+            "serve.queue_wiat", "serve.snyc", "serve.stepp",
+            "setup.typo"]
+        assert all("DECLARED_SPANS" in f.message for f in found)
 
     def test_exemptions_and_marker(self, tmp_path):
         src = """

@@ -5,6 +5,9 @@ tier-1 (deliberately NOT marked slow); the budget is ~50x the measured
 cost on a warm CPython, so scheduler noise doesn't flake it."""
 import time
 
+import pytest
+
+from paddle_tpu.core import flight_recorder as _fr
 from paddle_tpu.core import monitor
 from paddle_tpu.profiler import RecordEvent, metrics
 
@@ -218,3 +221,208 @@ def test_slo_tick_disabled_under_budget():
         f"registry-off slo.tick costs {best:.2f}µs/op "
         f"(budget {BUDGET_US}µs) — the off path must stay a bool "
         "check")
+
+
+# ------------------------------------------------- spans (ISSUE 26)
+# The recorder is ON by default and in every benchmark run, and the
+# scheduler opens spans inside every iteration. Off, a span is the bool
+# check, held to the absolute 1 µs of the gates above. On, what the
+# recorder adds is measured on the real calls, with and without it:
+# ``engine.step()`` of a live engine whose decode program is swapped for
+# one that hands its lanes back unchanged (the host side of a steady
+# decode iteration, polls included, with no device work to drown it),
+# the tracing of one admission through the engine's own ``_dequeued`` /
+# ``_sync`` / ``_first_token``, and ``TrainStep`` / ``DistributedTrainStep``
+# calls with the jitted step swapped the same way. Thread CPU time, the
+# least of many short batches, on less off. Measured here: 6.5 µs an
+# iteration (2.75 records), 8.7 µs an admission, 3.8-4.5 µs a TrainStep
+# call and 2.5-2.8 µs a DistributedTrainStep call; under eight busy
+# processes on the eight cores the same to within 0.3 µs.
+
+STEP_BUDGET_US = 25.0            # added per engine.step(), and per admission
+# ISSUE 26 asks for 5 µs and a call reads 2.5-4.5; the gate holds it
+# to twice that, since a threshold a tenth above a reading would flake
+TRAIN_STEP_BUDGET_US = 10.0
+
+
+def _cpu_us(fn, n: int = 50, batches: int = 100) -> float:
+    """µs of this thread's CPU time per call of ``fn``: the least of
+    ``batches`` batches of ``n`` (a batch that kept its core)."""
+    best = float("inf")
+    for _ in range(batches):
+        t0 = time.thread_time()
+        for _ in range(n):
+            fn()
+        best = min(best, (time.thread_time() - t0) / n * 1e6)
+    return best
+
+
+def _on_less_off(fn) -> float:
+    """What the recorder adds to one call of ``fn``, in µs."""
+    was = _fr.is_enabled()
+    try:
+        cost = {}
+        for on in (True, False) * 4:
+            _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=on)
+            fn()  # warm up
+            cost[on] = min(cost.get(on, float("inf")), _cpu_us(fn))
+            if on:
+                assert _fr.events()  # truly on
+        return cost[True] - cost[False]
+    finally:
+        _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=was)
+
+
+def _span_with_set():
+    with _fr.span("serve.sync", site="poll", steps_queued=3) as sp:
+        sp.set(emitted=1)
+
+
+def test_span_off_under_budget():
+    was = _fr.is_enabled()
+    _fr.disable()
+    try:
+        _cpu_us(_span_with_set, 2000, 1)  # warm up
+        best = _cpu_us(_span_with_set, 2000, 30)
+        assert _fr.events() == [] or was  # truly off
+    finally:
+        _fr.configure(on=was)
+    assert best < RECORDER_BUDGET_US, (
+        f"flight_recorder.span with the recorder off costs {best:.2f}"
+        f"µs (budget {RECORDER_BUDGET_US}µs) — the off path must stay "
+        "the bool check")
+
+
+class _Frozen:
+    """Stands in for a jitted step: hands back what it was given (no
+    device work, nothing donated), with the jit cache's size reader."""
+
+    def __init__(self, out):
+        self._out = out
+
+    def __call__(self, *args):
+        return self._out(*args)
+
+    def _cache_size(self):
+        return 1
+
+
+def _tiny_engine():
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import Config
+    from paddle_tpu.models.gpt import gpt
+    from paddle_tpu.serving import RequestParams, ServingEngine
+    paddle.seed(0)
+    m = gpt("test-tiny")
+    m.eval()
+    spec = [paddle.to_tensor(np.zeros((2, 12), np.int32))]
+    cfg = (Config().from_layer(m, spec)
+           .enable_generation(max_new_tokens=64, prefill_buckets=(16,),
+                              max_batch=2))
+    # the default poll cadence and sampling, as a benchmark run has them
+    eng = ServingEngine(cfg)
+    handles = [eng.submit(np.arange(1, 9, dtype=np.int32) + i,
+                          RequestParams(max_new_tokens=64))
+               for i in range(2)]
+    for _ in range(2 * eng.poll_every):
+        eng.step()
+    assert all(h.status.value == "running" for h in handles)
+    return eng, handles
+
+
+def test_engine_step_spans_under_budget():
+    """Added host time per ``engine.step()`` in steady decode, recorder
+    on less off: ``serve.step`` and ``serve.dispatch`` every iteration,
+    ``serve.poll`` with its ``serve.sync`` every ``poll_every``-th, the
+    ``set()`` calls and the sampled request's decode segment."""
+    eng, _ = _tiny_engine()
+    try:
+        frozen = _Frozen(lambda state, *lanes: lanes)
+        eng._exe_step = lambda: frozen   # both lanes live for ever
+        _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
+        polls0 = eng.stats["polls"]
+        for _ in range(4 * eng.poll_every):
+            eng.step()
+        per_step = len(_fr.events()) / (4 * eng.poll_every)
+        assert eng.stats["polls"] - polls0 == 4
+        assert 2.5 <= per_step <= 3.0, per_step
+        added = _on_less_off(eng.step)
+    finally:
+        eng.shutdown()
+    assert added < STEP_BUDGET_US, (
+        f"the recorder adds {added:.1f}µs to one engine.step() "
+        f"(budget {STEP_BUDGET_US}µs)")
+
+
+def test_admission_spans_under_budget():
+    """What one admission's tracing adds (about one iteration in eight
+    of the busiest benchmark cell has one): its ``serve.admit``, the
+    queue wait's end, the prefill's ``serve.sync`` and the first
+    token's stamp, through the engine's own methods."""
+    eng, (req, _) = _tiny_engine()
+
+    def admission():
+        with _fr.span("serve.admit", req=req.id, slot=0, bucket=16,
+                      prompt=8) as sp:
+            t0 = eng._dequeued(req, sp, 16)
+            _, t1 = eng._sync("prefill", int)
+            eng._first_token(req, t0, t1, 16)
+
+    try:
+        added = _on_less_off(admission)
+    finally:
+        eng.shutdown()
+    assert added < STEP_BUDGET_US, (
+        f"the recorder adds {added:.1f}µs to one admission (budget "
+        f"{STEP_BUDGET_US}µs)")
+
+
+def _train_step(kind):
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu import nn, optimizer
+    model = nn.Linear(4, 2)
+    opt = optimizer.SGD(learning_rate=0.1, parameters=model.parameters())
+
+    def loss_fn(o, y):
+        return ((o - y) ** 2).mean()
+    x = paddle.to_tensor(np.ones((8, 4), np.float32))
+    y = paddle.to_tensor(np.zeros((8, 2), np.float32))
+    if kind == "TrainStep":
+        return paddle.jit.TrainStep(model, opt, loss_fn), (x, y)
+    from paddle_tpu.distributed import fleet
+    fleet.init(strategy=fleet.DistributedStrategy(
+        hybrid_configs={"dp_degree": 8}))
+    step = fleet.DistributedTrainStep(
+        fleet.distributed_model(model), fleet.distributed_optimizer(opt),
+        loss_fn)
+    return step, (x, y)
+
+
+@pytest.mark.parametrize("kind", ["TrainStep", "DistributedTrainStep"])
+def test_train_step_span_under_budget(kind):
+    """Added host time per train-step call, recorder on less off: the
+    ``train.step`` span and the jit cache's size and the persistent
+    cache's hit count read around the dispatch."""
+    import paddle_tpu.distributed as dist
+    try:
+        step, batch = _train_step(kind)
+        loss = step(*batch)._data           # compiles
+        step._jitted = _Frozen(
+            lambda params, opt_state, *rest: (loss, params, opt_state))
+        if kind == "DistributedTrainStep":
+            # placing the batch on the mesh is a millisecond of host
+            # time in which 3 µs cannot be seen
+            placed = step._prepare(batch)
+            step._prepare = lambda batch: placed
+        _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
+        for _ in range(50):
+            step(*batch)
+        assert [e[2]["name"] for e in _fr.events()] == ["train.step"] * 50
+        added = _on_less_off(lambda: step(*batch))
+    finally:
+        dist.set_hybrid_communicate_group(None)
+    assert added < TRAIN_STEP_BUDGET_US, (
+        f"the recorder adds {added:.1f}µs to one {kind} call (budget "
+        f"{TRAIN_STEP_BUDGET_US}µs)")

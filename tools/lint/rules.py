@@ -261,33 +261,44 @@ def check_metric_names(ctx: FileContext) -> List[LintFinding]:
 # free to name events as it likes
 _EVENT_NAME_EXEMPT = frozenset({"paddle_tpu/core/flight_recorder.py"})
 
-_DECLARED_EVENTS_CACHE: Optional[Set[str]] = None
+# (DECLARED_EVENTS, DECLARED_SPANS) as parsed
+_DECLARED_EVENTS_CACHE: Optional[tuple] = None
 
 
-def _declared_events() -> Set[str]:
-    """The DECLARED_EVENTS literal parsed out of core/flight_recorder.py
-    (AST only, the _declared_metrics precedent)."""
+def _declared_events() -> tuple:
+    """The DECLARED_EVENTS set literal and the keys of the
+    DECLARED_SPANS table, parsed out of core/flight_recorder.py (AST
+    only, the _declared_metrics precedent)."""
     global _DECLARED_EVENTS_CACHE
     if _DECLARED_EVENTS_CACHE is not None:
         return _DECLARED_EVENTS_CACHE
     from . import repo_root
     fr_path = os.path.join(repo_root(), "paddle_tpu", "core",
                            "flight_recorder.py")
-    declared: Set[str] = set()
+    events: Set[str] = set()
+    spans: Set[str] = set()
     try:
         with open(fr_path, "r", encoding="utf-8") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
-            if isinstance(node, ast.Assign) and any(
-                    isinstance(t, ast.Name) and t.id == "DECLARED_EVENTS"
-                    for t in node.targets):
-                for sub in ast.walk(node.value):
-                    if isinstance(sub, ast.Constant) and \
-                            isinstance(sub.value, str):
-                        declared.add(sub.value)
+            if not isinstance(node, ast.Assign):
+                continue
+            names = {t.id for t in node.targets
+                     if isinstance(t, ast.Name)}
+            if "DECLARED_EVENTS" in names:
+                events.update(
+                    sub.value for sub in ast.walk(node.value)
+                    if isinstance(sub, ast.Constant)
+                    and isinstance(sub.value, str))
+            elif "DECLARED_SPANS" in names and \
+                    isinstance(node.value, ast.Dict):
+                spans.update(
+                    k.value for k in node.value.keys
+                    if isinstance(k, ast.Constant)
+                    and isinstance(k.value, str))
     except OSError:
         pass
-    _DECLARED_EVENTS_CACHE = declared
+    _DECLARED_EVENTS_CACHE = (events, spans)
     return _DECLARED_EVENTS_CACHE
 
 
@@ -295,25 +306,35 @@ def _declared_events() -> Set[str]:
 def check_event_names(ctx: FileContext) -> List[LintFinding]:
     """Literal event names passed to ``flight_recorder.record(...)``
     in the framework must be declared in
-    ``core/flight_recorder.DECLARED_EVENTS``: an undeclared name is a
-    stream no post-mortem tooling greps for and no docs/events.md row
-    explains (the DECLARED_METRICS contract, applied to the black
-    box). Span names (``record_span`` / ``Request.span``) are
-    per-request dynamic and exempt; dynamic ``record(kind_var)``
-    names are the recorders' business, same as metric-name."""
+    ``core/flight_recorder.DECLARED_EVENTS``, and literal span names
+    passed to ``flight_recorder.span / record_span(...)`` or
+    ``Request.stage_span(...)`` in its ``DECLARED_SPANS`` table: an
+    undeclared name is a stream no post-mortem tooling (and no
+    benchmark reader) greps for and no docs/events.md row explains
+    (the DECLARED_METRICS contract, applied to the black box). The
+    sampled per-request segments (``Request.span``, an f-string name)
+    and dynamic ``record(kind_var)`` names are the recorders'
+    business, same as metric-name."""
     if not ctx.relpath.startswith("paddle_tpu/") \
             or ctx.relpath in _EVENT_NAME_EXEMPT or ctx.is_test_file:
         return []
-    declared = _declared_events()
-    if not declared:
+    events, spans = _declared_events()
+    if not events:
         return []  # flight_recorder.py unreadable: no bogus cascade
     findings = []
     for node in ast.walk(ctx.tree):
         if not (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "record"
-                and _dotted(node.func.value).split(".")[-1]
-                == "flight_recorder"):
+                and isinstance(node.func, ast.Attribute)):
+            continue
+        attr = node.func.attr
+        on_recorder = _dotted(node.func.value).split(".")[-1] \
+            .lstrip("_") == "flight_recorder"
+        if attr == "record" and on_recorder:
+            declared, table = events, "DECLARED_EVENTS"
+        elif attr == "stage_span" or (
+                on_recorder and attr in ("span", "record_span")):
+            declared, table = spans, "DECLARED_SPANS"
+        else:
             continue
         if not (node.args and isinstance(node.args[0], ast.Constant)
                 and isinstance(node.args[0].value, str)):
@@ -321,11 +342,12 @@ def check_event_names(ctx: FileContext) -> List[LintFinding]:
         name = node.args[0].value
         if name in declared or ctx.allowed(node, "event-name"):
             continue
+        kind = "event" if table == "DECLARED_EVENTS" else "span"
         findings.append(LintFinding(
             ctx.relpath, node.lineno, node.col_offset, "event-name",
-            f"flight-recorder event {name!r} is not declared in "
-            "core/flight_recorder.DECLARED_EVENTS; declare it there "
-            "(with an EVENT_DOC entry) or fix the typo"))
+            f"flight-recorder {kind} {name!r} is not declared in "
+            f"core/flight_recorder.{table}; declare it there (with "
+            "its one-line description) or fix the typo"))
     return findings
 
 

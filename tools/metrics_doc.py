@@ -70,16 +70,32 @@ recorder's ring, as declared in `core/flight_recorder.DECLARED_EVENTS`
 (enforced by the `event-name` lint rule). Events surface in auto-dumps
 (Perfetto JSON + plaintext tail), `/flightrecorder`, and — merged
 across ranks by `tools/trace_merge.py` — the fleet post-mortem
-timeline. Request-trace SPANS carry dynamic per-request names and are
-not listed here.
+timeline.
 
 | Event | Description |
+|---|---|
+"""
+
+_SPANS_HEADER = """
+## Spans
+
+Every span name declared in `core/flight_recorder.DECLARED_SPANS` (the
+same lint rule holds literal names to it). A span carries its id and
+its parent's, so a reader (`flight_recorder.spans_between`) can compute
+self time; each one opened with `flight_recorder.span()` is also a
+`jax.profiler.TraceAnnotation`, so it lies on the host plane of any
+device trace being taken. The sampled per-request segments
+(`req<id>.decode`, `req<id>.prefill_chunk`; 1 request in
+`trace_sample`) carry dynamic names and are not listed.
+
+| Span | Opened in, covers (fields) |
 |---|---|
 """
 
 
 def render_events() -> str:
     from paddle_tpu.core.flight_recorder import (DECLARED_EVENTS,
+                                                 DECLARED_SPANS,
                                                  EVENT_DOC)
     missing = DECLARED_EVENTS - set(EVENT_DOC)
     extra = set(EVENT_DOC) - DECLARED_EVENTS
@@ -89,7 +105,10 @@ def render_events() -> str:
             f"missing={sorted(missing)} extra={sorted(extra)}")
     rows = [f"| `{name}` | {EVENT_DOC[name]} |"
             for name in sorted(EVENT_DOC)]
-    return _EVENTS_HEADER + "\n".join(rows) + "\n"
+    spans = [f"| `{name}` | {DECLARED_SPANS[name]} |"
+             for name in sorted(DECLARED_SPANS)]
+    return (_EVENTS_HEADER + "\n".join(rows) + "\n"
+            + _SPANS_HEADER + "\n".join(spans) + "\n")
 
 
 def _docs_dir() -> str:
