@@ -25,28 +25,32 @@ def cached_attention(q, k, v, cache, layer_idx, *, decode: bool,
     if decode:
         s = q.shape[1]
         mask_len = cache.kv_len + s  # includes the new rows
-        # int8 cache (QuantKVCache/QuantPagedKVCache): the layer's
-        # scale sidecars ride as two extra operands — dequant fuses
-        # in-register, the wide cache is never materialized
-        scales = () if getattr(cache, "k_scale", None) is None else \
-            (cache.k_scale[layer_idx], cache.v_scale[layer_idx])
+        quant = getattr(cache, "k_scale", None) is not None
         if getattr(cache, "page_table", None) is not None:
             # paged cache: attend the pooled pages through the row's
             # page table (index-map indirection on TPU, gather+mask
-            # off it — bitwise-equal either way)
+            # off it — bitwise-equal either way). The kernel takes the
+            # STACKED pools (and scale sidecars) and picks the layer in
+            # its index map: slicing cache.k[layer_idx] here would copy
+            # a whole layer's pool every step
             from ..kernels.flash_attention import \
                 flash_attention_decode_paged
+            scales = (cache.k_scale, cache.v_scale) if quant else ()
             out = dispatch(
                 "flash_attention_decode_paged",
                 lambda q_, kp, vp, pt, kl, *sc:
                     flash_attention_decode_paged(
-                        q_, kp, vp, pt, kl,
+                        q_, kp, vp, pt, kl, layer_idx,
                         **(dict(k_scale=sc[0], v_scale=sc[1])
                            if sc else {})),
-                (q, cache.k[layer_idx], cache.v[layer_idx],
-                 cache.page_table, mask_len) + scales, {},
-                differentiable=False)
+                (q, cache.k, cache.v, cache.page_table, mask_len)
+                + scales, {}, differentiable=False)
             return out, cache
+        # int8 cache (QuantKVCache): the layer's scale sidecars ride as
+        # two extra operands — dequant fuses in-register, the wide
+        # cache is never materialized
+        scales = (cache.k_scale[layer_idx], cache.v_scale[layer_idx]) \
+            if quant else ()
         from ..kernels.flash_attention import (
             MAX_DECODE_QLEN, flash_attention_chunk,
             flash_attention_decode)

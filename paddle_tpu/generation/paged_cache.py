@@ -10,13 +10,32 @@ built natively on the decode kernel's index-map indirection (the same
 mechanism its GQA head mapping already uses):
 
 - **PagedKVCache** (device pytree): ``k, v`` pools of shape
-  ``[layers, n_pages, page_size, heads, head_dim]``, a per-row int32
+  ``[layers, n_pages, heads, page_size, head_dim]``, a per-row int32
   ``page_table [batch, pages_per_row]``, and the familiar per-row
   ``kv_len``. ``update``/``install_row``/``reset_rows`` are
   pure-functional (donated in the engine's compiled programs, same as
   the dense cache) and every write resolves its destination page
   through the table in-trace — the page ids are DATA, so one compiled
   program serves every allocation layout.
+- **Why tokens sit on the second-minor axis.** The TPU tiles an
+  array's two minor dimensions, so ``(page_size, head_dim)`` minor puts
+  tokens on sublanes and ``head_dim`` on lanes: one page of one head is
+  one contiguous run of tiles, exactly the ``(page, head_dim)`` block
+  the paged decode kernel streams. The kernel's index map picks
+  ``(layer, page id, head)`` out of the STACKED pool, so no program
+  ever slices a layer out of the pool or transposes it (with heads on
+  sublanes, ``[.., page, heads, head_dim]``, both copies were forced:
+  52 ms of a 110 ms decode step at 6.7B widths: PERF.md section 6,
+  PR 27). A page stays a whole unit of ``heads * page_size *
+  head_dim`` contiguous elements per layer, so everything that works
+  on page ids (prefix sharing, copy-on-write, reclaim) is untouched by
+  the order inside a page.
+- **Writes enumerate layer and head in the scatter's indices**, leaving
+  ``head_dim`` as the only window dimension. A scatter whose window
+  spans ``heads`` and ``head_dim`` (``pool.at[l, page, :, off]``) makes
+  XLA's layout assignment flip the whole stacked pool around every
+  write: two pool-sized copies per token. ``tests/test_tpu_compile.py``
+  holds the compiled programs to no pool-sized temporary.
 - **Page 0 is the reserved null page**: masked install positions,
   out-of-table positions, and idle engine lanes (``kv_len == 0``, the
   finished-slot contract) all route their writes there. Nothing ever
@@ -60,6 +79,38 @@ __all__ = ["PagedKVCache", "QuantPagedKVCache", "PageAllocator",
            "AdmissionPlan"]
 
 
+def _scatter_tokens(buf, layer: int, page, off, new):
+    """Write ``new`` ([batch, s, heads, ...]) into ``buf`` ([layers,
+    n_pages, heads, page_size, ...]) at ``(layer, page[i], :, off[i])``
+    for the flat token ``i``. Layer and head are enumerated in the
+    indices, not spanned by the window (see the module docstring)."""
+    heads = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :]
+    flat = new.reshape((-1,) + new.shape[2:]).astype(buf.dtype)
+    return buf.at[layer, page[:, None], heads, off[:, None]].set(flat)
+
+
+def _install_pages(buf, rows, page, valid):
+    """Write the positions ``valid`` ([n * page_size] bool) of the
+    batch-1 dense rows ``rows`` ([layers, 1, t, heads, ...], ``t`` at
+    most ``n * page_size``) into the pages ``page`` ([n] int32) of
+    ``buf``, whole pages at a time: gather the n pages, merge, scatter
+    them back with (layer, page) in the indices and the page itself,
+    ``heads * page_size * head_dim`` contiguous elements, as the window.
+    Positions not ``valid`` keep what the page held. (Token by token, as
+    ``_scatter_tokens`` writes, an admission is 262,144 rows of 256 B at
+    6.7B widths and took 68 ms on the v5e: PERF.md section 6, PR 27.)"""
+    n, ps = page.shape[0], buf.shape[3]
+    rows = rows[:, 0]
+    new = jnp.pad(rows, ((0, 0), (0, n * ps - rows.shape[1]))
+                  + ((0, 0),) * (rows.ndim - 2))
+    new = new.reshape((rows.shape[0], n, ps) + rows.shape[2:])
+    new = jnp.swapaxes(new, 2, 3).astype(buf.dtype)  # [L, n, H, ps, ..]
+    keep = valid.reshape((1, n, 1, ps) + (1,) * (buf.ndim - 4))
+    layers = jnp.arange(buf.shape[0], dtype=jnp.int32)[:, None]
+    at = (layers, page[None, :])
+    return buf.at[at].set(jnp.where(keep, new, buf[at]))
+
+
 @jax.tree_util.register_pytree_node_class
 class PagedKVCache:
     """Paged K/V pool + per-row page tables + per-row valid lengths.
@@ -98,7 +149,7 @@ class PagedKVCache:
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def batch(self) -> int:
@@ -128,9 +179,9 @@ class PagedKVCache:
                page_size: int, pages_per_row: int, num_heads: int,
                head_dim: int, dtype=jnp.float32,
                cache_dtype=None) -> "PagedKVCache":
-        shape = (num_layers, n_pages, page_size, num_heads, head_dim)
+        shape = (num_layers, n_pages, num_heads, page_size, head_dim)
         if validate_cache_dtype(cache_dtype) is not None:
-            sshape = (num_layers, n_pages, page_size, num_heads)
+            sshape = shape[:-1]
             return QuantPagedKVCache(
                 jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
                 jnp.zeros((batch, pages_per_row), jnp.int32),
@@ -155,6 +206,31 @@ class PagedKVCache:
         dead = (pos[:, 0:1] == 0) | (slot >= self.pages_per_row)
         return jnp.where(dead, 0, page), pos % self.page_size
 
+    def _token_dest(self, pos, b: int, s: int):
+        """Flat ([b * s]) (page, offset) of ``s`` tokens appended per
+        row at start position ``pos`` (scalar or [b])."""
+        pos = jnp.asarray(_raw(pos), jnp.int32)
+        if pos.ndim == 0:
+            pos = jnp.broadcast_to(pos, (b,))
+        positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        page, off = self._write_pages(positions)          # [b, s] each
+        return page.reshape(-1), off.reshape(-1)
+
+    def _span_dest(self, src, table_row, start):
+        """Where the batch-1 dense row ``src`` goes under ``table_row``,
+        page by page: ``(page [n], valid [n * page_size])``, ``n`` pages
+        covering ``src.max_len``. Only positions in ``[start, src.kv_len[0])`` and inside
+        the table are valid; a page with none goes to the null page
+        (a shared prefix page is referenced, never re-written)."""
+        ps = self.page_size
+        n = -(-src.max_len // ps)
+        pos = jnp.arange(n * ps, dtype=jnp.int32)
+        valid = ((pos >= start) & (pos < src.kv_len[0])
+                 & (pos < self.max_len)).reshape(n, ps)
+        slot = jnp.minimum(jnp.arange(n), self.pages_per_row - 1)
+        page = jnp.where(valid.any(axis=1), table_row[slot], 0)
+        return page, valid.reshape(-1)
+
     def update(self, layer: int, k_new, v_new, pos) -> "PagedKVCache":
         """Write ``k_new``/``v_new`` ([batch, s, heads, head_dim]) into
         ``layer`` at per-row start position ``pos`` through the page
@@ -164,20 +240,11 @@ class PagedKVCache:
         null page. Does NOT advance ``kv_len`` (same contract as the
         dense cache: the model advances it once per forward)."""
         k_new, v_new = _raw(k_new), _raw(v_new)
-        pos = jnp.asarray(_raw(pos), jnp.int32)
-        if pos.ndim == 0:
-            pos = jnp.broadcast_to(pos, (k_new.shape[0],))
-        b, s = k_new.shape[0], k_new.shape[1]
-        positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        page, off = self._write_pages(positions)          # [b, s] each
-        page_f, off_f = page.reshape(-1), off.reshape(-1)
-
-        def write(buf, new):
-            flat = new.reshape((b * s,) + new.shape[2:]).astype(buf.dtype)
-            return buf.at[layer, page_f, off_f].set(flat)
-
-        return PagedKVCache(write(self.k, k_new), write(self.v, v_new),
-                            self.page_table, self.kv_len)
+        page, off = self._token_dest(pos, *k_new.shape[:2])
+        return PagedKVCache(
+            _scatter_tokens(self.k, layer, page, off, k_new),
+            _scatter_tokens(self.v, layer, page, off, v_new),
+            self.page_table, self.kv_len)
 
     def install_row(self, src: KVCache, slot, table_row,
                     start) -> "PagedKVCache":
@@ -191,24 +258,11 @@ class PagedKVCache:
         program serves every slot and every allocation layout."""
         slot = jnp.asarray(_raw(slot), jnp.int32)
         table_row = jnp.asarray(_raw(table_row), jnp.int32)
-        start = jnp.asarray(_raw(start), jnp.int32)
-        length = src.kv_len[0]
-        t = src.max_len
-        pos = jnp.arange(t, dtype=jnp.int32)
-        page_slot = pos // self.page_size
-        page = table_row[jnp.minimum(page_slot, self.pages_per_row - 1)]
-        valid = (pos >= start) & (pos < length) & \
-            (page_slot < self.pages_per_row)
-        page = jnp.where(valid, page, 0)
-        off = pos % self.page_size
-
-        def write(buf, row):  # row: [layers, t, heads, head_dim]
-            return buf.at[:, page, off].set(row.astype(buf.dtype))
-
+        spanned = self.install_span(src, table_row, start)
         return PagedKVCache(
-            write(self.k, src.k[:, 0]), write(self.v, src.v[:, 0]),
+            spanned.k, spanned.v,
             self.page_table.at[slot].set(table_row),
-            self.kv_len.at[slot].set(length))
+            self.kv_len.at[slot].set(src.kv_len[0]))
 
     def install_span(self, src: KVCache, table_row,
                      start) -> "PagedKVCache":
@@ -224,22 +278,10 @@ class PagedKVCache:
         boundary)."""
         table_row = jnp.asarray(_raw(table_row), jnp.int32)
         start = jnp.asarray(_raw(start), jnp.int32)
-        length = src.kv_len[0]
-        t = src.max_len
-        pos = jnp.arange(t, dtype=jnp.int32)
-        page_slot = pos // self.page_size
-        page = table_row[jnp.minimum(page_slot, self.pages_per_row - 1)]
-        valid = (pos >= start) & (pos < length) & \
-            (page_slot < self.pages_per_row)
-        page = jnp.where(valid, page, 0)
-        off = pos % self.page_size
-
-        def write(buf, row):  # row: [layers, t, heads, head_dim]
-            return buf.at[:, page, off].set(row.astype(buf.dtype))
-
-        return PagedKVCache(
-            write(self.k, src.k[:, 0]), write(self.v, src.v[:, 0]),
-            self.page_table, self.kv_len)
+        page, valid = self._span_dest(src, table_row, start)
+        return PagedKVCache(_install_pages(self.k, src.k, page, valid),
+                            _install_pages(self.v, src.v, page, valid),
+                            self.page_table, self.kv_len)
 
     def positions(self, s: int):
         """Absolute positions of ``s`` appended tokens per row — the
@@ -289,7 +331,7 @@ class PagedKVCache:
 class QuantPagedKVCache(PagedKVCache):
     """Int8 page pool: K/V pages stored int8 with per-(slot, head) bf16
     scales in sidecar pools ``k_scale``/``v_scale``
-    ([layers, n_pages, page_size, heads]) plus the scalar ``clips``
+    ([layers, n_pages, heads, page_size]) plus the scalar ``clips``
     saturation counter. The scales live IN the page (one row per
     position), so everything the allocator does at page granularity —
     shared-prefix referencing, COW privatization, LRU reclaim — carries
@@ -325,19 +367,12 @@ class QuantPagedKVCache(PagedKVCache):
         values + bf16 scales through the page table — same null-page
         routing for idle/out-of-table positions as the wide pool."""
         k_new, v_new = _raw(k_new), _raw(v_new)
-        pos = jnp.asarray(_raw(pos), jnp.int32)
-        if pos.ndim == 0:
-            pos = jnp.broadcast_to(pos, (k_new.shape[0],))
-        b, s = k_new.shape[0], k_new.shape[1]
-        positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        page, off = self._write_pages(positions)
-        page_f, off_f = page.reshape(-1), off.reshape(-1)
+        page, off = self._token_dest(pos, *k_new.shape[:2])
         kq, ks, kc = quantize_kv(k_new)
         vq, vs, vc = quantize_kv(v_new)
 
         def write(buf, new):
-            flat = new.reshape((b * s,) + new.shape[2:]).astype(buf.dtype)
-            return buf.at[layer, page_f, off_f].set(flat)
+            return _scatter_tokens(buf, layer, page, off, new)
 
         return QuantPagedKVCache(
             write(self.k, kq), write(self.v, vq), self.page_table,
@@ -353,27 +388,12 @@ class QuantPagedKVCache(PagedKVCache):
         the shared prefix pages, masked positions route to null."""
         slot = jnp.asarray(_raw(slot), jnp.int32)
         table_row = jnp.asarray(_raw(table_row), jnp.int32)
-        start = jnp.asarray(_raw(start), jnp.int32)
-        length = src.kv_len[0]
-        t = src.max_len
-        pos = jnp.arange(t, dtype=jnp.int32)
-        page_slot = pos // self.page_size
-        page = table_row[jnp.minimum(page_slot, self.pages_per_row - 1)]
-        valid = (pos >= start) & (pos < length) & \
-            (page_slot < self.pages_per_row)
-        page = jnp.where(valid, page, 0)
-        off = pos % self.page_size
-
-        def write(buf, row):  # row: [layers, t, ...]
-            return buf.at[:, page, off].set(row.astype(buf.dtype))
-
+        spanned = self.install_span(src, table_row, start)
         return QuantPagedKVCache(
-            write(self.k, src.k[:, 0]), write(self.v, src.v[:, 0]),
+            spanned.k, spanned.v,
             self.page_table.at[slot].set(table_row),
-            self.kv_len.at[slot].set(length),
-            write(self.k_scale, src.k_scale[:, 0]),
-            write(self.v_scale, src.v_scale[:, 0]),
-            self.clips + src.clips)
+            self.kv_len.at[slot].set(src.kv_len[0]),
+            spanned.k_scale, spanned.v_scale, self.clips + src.clips)
 
     def install_span(self, src, table_row,
                      start) -> "QuantPagedKVCache":
@@ -386,25 +406,16 @@ class QuantPagedKVCache(PagedKVCache):
         multiply-count every earlier chunk's clips."""
         table_row = jnp.asarray(_raw(table_row), jnp.int32)
         start = jnp.asarray(_raw(start), jnp.int32)
-        length = src.kv_len[0]
-        t = src.max_len
-        pos = jnp.arange(t, dtype=jnp.int32)
-        page_slot = pos // self.page_size
-        page = table_row[jnp.minimum(page_slot, self.pages_per_row - 1)]
-        valid = (pos >= start) & (pos < length) & \
-            (page_slot < self.pages_per_row)
-        page = jnp.where(valid, page, 0)
-        off = pos % self.page_size
+        page, valid = self._span_dest(src, table_row, start)
 
-        def write(buf, row):  # row: [layers, t, ...]
-            return buf.at[:, page, off].set(row.astype(buf.dtype))
+        def write(buf, rows):
+            return _install_pages(buf, rows, page, valid)
 
         return QuantPagedKVCache(
-            write(self.k, src.k[:, 0]), write(self.v, src.v[:, 0]),
+            write(self.k, src.k), write(self.v, src.v),
             self.page_table, self.kv_len,
-            write(self.k_scale, src.k_scale[:, 0]),
-            write(self.v_scale, src.v_scale[:, 0]),
-            self.clips)
+            write(self.k_scale, src.k_scale),
+            write(self.v_scale, src.v_scale), self.clips)
 
     # -------------------------------------------------------- slot reuse
     def reset_rows(self, rows) -> "QuantPagedKVCache":

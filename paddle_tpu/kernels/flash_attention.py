@@ -1343,18 +1343,20 @@ def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
-                         group=1, interpret=None,
+                         layer, group=1, interpret=None,
                          k_scale=None, v_scale=None):
-    """q: [B*Hq, sq<=8, D] (unscaled), pools [Hk, n_pages, page, D],
-    page_table [B, P] int32, kv_len [B]. The k/v BlockSpec index maps
-    resolve (kv head, page id) from the grid row and the
+    """q: [B*Hq, sq<=8, D] (unscaled), pools [L, n_pages, Hk, page, D]
+    (the STACKED pool of every layer, where it lies), page_table [B, P]
+    int32, kv_len [B], ``layer`` static. The k/v BlockSpec index maps
+    resolve (layer, page id, kv head) from the grid row and the
     scalar-prefetched table — page indirection rides the same
-    index-map mechanism as the GQA head mapping. ``k_scale``/``v_scale``
-    ([Hk, n_pages, page] bf16) run the int8-pool mode: the scale pages
-    resolve through the SAME table index map, dequant fused in the
-    shared accumulate body."""
+    index-map mechanism as the GQA head mapping, and the layer is one
+    more constant in it, so no layer is ever sliced out of the pool.
+    ``k_scale``/``v_scale`` ([L, n_pages, Hk, page] bf16) run the
+    int8-pool mode: this layer's scale pages resolve through the SAME
+    table index map, dequant fused in the shared accumulate body."""
     bh, sq, d = q.shape
-    hk, n_pages, page, _ = k_pool.shape
+    page = k_pool.shape[3]
     b, num_slots = page_table.shape
     hq = bh // b
     quant = k_scale is not None
@@ -1366,17 +1368,25 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
     kvl = kv_len.astype(jnp.int32)
 
     def k_index(r, j, tbl, kl):
-        return ((r % hq) // group, tbl[r // hq, j], 0, 0)
+        return (layer, tbl[r // hq, j], (r % hq) // group, 0, 0)
 
+    # the layer dim is squeezed: the kernel sees the same
+    # (1, 1, page, d) block of one head's page as ever
     in_specs = [
         pl.BlockSpec((1, qpad, d), lambda r, j, tbl, kl: (r, 0, 0)),
-        pl.BlockSpec((1, 1, page, d), k_index),
-        pl.BlockSpec((1, 1, page, d), k_index),
+        pl.BlockSpec((None, 1, 1, page, d), k_index),
+        pl.BlockSpec((None, 1, 1, page, d), k_index),
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += _scale_row_specs(page, k_index, lead=(1, 1))
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
+        # the [.., 1, page] row the spec needs is a relayout (one
+        # sublane, not Hk): make it of this layer's scales (1/128 of a
+        # layer's values), not of the stacked sidecar
+        in_specs += _scale_row_specs(
+            page, lambda r, j, tbl, kl: k_index(r, j, tbl, kl)[1:],
+            lead=(1, 1))
+        operands += [k_scale[layer][:, :, None],
+                     v_scale[layer][:, :, None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bh, num_slots),
@@ -1405,25 +1415,28 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
 
 
 def flash_attention_decode_paged(query, key_pool, value_pool,
-                                 page_table, kv_len, scale=None,
+                                 page_table, kv_len, layer, scale=None,
                                  k_scale=None, v_scale=None):
     """Decode-shaped attention over a PAGED KV cache: 1..8 new query
     tokens per row against K/V stored in a shared page pool addressed
     through per-row page tables.
 
     Int8 pool mode: with int8 pools pass ``k_scale``/``v_scale``
-    ([n_pages, page_size, num_kv_heads], the ``QuantPagedKVCache``
-    sidecars) — the scale pages resolve through the same
-    scalar-prefetched table and the dequant fuses in-kernel, so the
-    pool streams at half the HBM bytes.
+    ([layers, n_pages, num_kv_heads, page_size], the
+    ``QuantPagedKVCache`` sidecars) — the scale pages resolve through
+    the same scalar-prefetched table and the dequant fuses in-kernel,
+    so the pool streams at half the HBM bytes.
 
     query: [batch, q_len<=8, num_heads, head_dim] (framework layout).
-    key_pool/value_pool: [n_pages, page_size, num_kv_heads, head_dim] —
-    one layer's slice of a ``generation.PagedKVCache`` (new tokens
-    already written through the table). page_table: [batch,
-    pages_per_row] int32 (entry 0 = the reserved null page). kv_len:
-    [batch] int32 — valid entries per row INCLUDING the q_len new
-    positions; masking is identical to ``flash_attention_decode``.
+    key_pool/value_pool: [layers, n_pages, num_kv_heads, page_size,
+    head_dim] — the whole stacked pool of a ``generation.PagedKVCache``
+    (new tokens already written through the table); ``layer`` (static)
+    names the layer to attend, and is resolved where the page id is, in
+    the kernel's index map: the pool is read where it lies.
+    page_table: [batch, pages_per_row] int32 (entry 0 = the reserved
+    null page). kv_len: [batch] int32 — valid entries per row INCLUDING
+    the q_len new positions; masking is identical to
+    ``flash_attention_decode``.
 
     TPU with a lane-aligned page size runs the Pallas kernel (page ids
     resolved in the k/v BlockSpec index maps from the scalar-prefetched
@@ -1432,7 +1445,7 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
     bit-for-bit (garbage in pages past kv_len is masked to exact
     zeros, so paged results are bitwise-equal to the dense cache)."""
     b, sq, hq, d = query.shape
-    ps, hk = key_pool.shape[1], key_pool.shape[2]
+    hk, ps = key_pool.shape[2], key_pool.shape[3]
     num_slots = page_table.shape[1]
     if sq > _DECODE_QPAD:
         raise ValueError(
@@ -1447,43 +1460,34 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
     if quant and (k_scale is None or v_scale is None):
         raise ValueError(
             "flash_attention_decode_paged: int8 pools need "
-            "k_scale/v_scale ([n_pages, page_size, kv_heads] — the "
-            "QuantPagedKVCache sidecars); an unscaled int8 pool cannot "
-            "be dequantized")
+            "k_scale/v_scale ([layers, n_pages, kv_heads, page_size] — "
+            "the QuantPagedKVCache sidecars); an unscaled int8 pool "
+            "cannot be dequantized")
     kv_len = jnp.asarray(kv_len, jnp.int32)
+    qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
     use_pallas = (jax.default_backend() == "tpu"
                   and ps % 128 == 0 and d in (64, 128, 256))
     if use_pallas:
-        qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
-        kp = jnp.transpose(key_pool, (2, 0, 1, 3))    # [hk, pages, ps, d]
-        vp = jnp.transpose(value_pool, (2, 0, 1, 3))
-        ksp = vsp = None
-        if quant:
-            ksp = jnp.transpose(k_scale, (2, 0, 1))   # [hk, pages, ps]
-            vsp = jnp.transpose(v_scale, (2, 0, 1))
-        out = _paged_decode_pallas(qt, kp, vp, page_table, kv_len,
-                                   float(scale), group=group,
-                                   k_scale=ksp, v_scale=vsp)
+        out = _paged_decode_pallas(qt, key_pool, value_pool, page_table,
+                                   kv_len, float(scale), layer,
+                                   group=group, k_scale=k_scale,
+                                   v_scale=v_scale)
         return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
-    # XLA fallback: gather the row's pages into the logical
-    # [b, pages_per_row * page_size, hk, d] layout and run the exact
-    # dense decode math — t equals the dense cache's max_len, so the
-    # reduction order (and thus every bit) matches the dense engine
-    k_rows = key_pool[page_table].reshape(b, num_slots * ps, hk, d)
-    v_rows = value_pool[page_table].reshape(b, num_slots * ps, hk, d)
+    # XLA fallback: gather the row's pages ([b, slots, hk, ps, d]) into
+    # the logical per-head rows [b * hk, pages_per_row * page_size, d]
+    # and run the exact dense decode math — t equals the dense cache's
+    # max_len, so the reduction order (and thus every bit) matches the
+    # dense engine
     t = num_slots * ps
-    qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
-    kt = jnp.swapaxes(k_rows, 1, 2).reshape(b * hk, t, d)
-    vt = jnp.swapaxes(v_rows, 1, 2).reshape(b * hk, t, d)
-    kst = vst = None
-    if quant:
-        ks_rows = k_scale[page_table].reshape(b, t, hk)
-        vs_rows = v_scale[page_table].reshape(b, t, hk)
-        kst = jnp.swapaxes(ks_rows, 1, 2).reshape(b * hk, t)
-        vst = jnp.swapaxes(vs_rows, 1, 2).reshape(b * hk, t)
-    kl = jnp.repeat(kv_len, hk)
-    out = _decode_xla(qt, kt, vt, kl, float(scale), group=group,
-                      ks=kst, vs=vst)
+
+    def rows(pool):  # [b, slots, hk, ps, ...] -> [b * hk, t, ...]
+        g = jnp.swapaxes(pool[layer, page_table], 1, 2)
+        return g.reshape((b * hk, t) + pool.shape[4:])
+
+    out = _decode_xla(qt, rows(key_pool), rows(value_pool),
+                      jnp.repeat(kv_len, hk), float(scale), group=group,
+                      ks=rows(k_scale) if quant else None,
+                      vs=rows(v_scale) if quant else None)
     return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
 
 

@@ -665,8 +665,8 @@ class ServingEngine:
                     # reclaim carry them for free) + the saturation counter
                     from ..generation.paged_cache import QuantPagedKVCache
                     L, _, _, H, D = cache_aval.k.shape
-                    pool = (L, self._alloc.n_pages, self.page_size, H, D)
-                    spool = (L, self._alloc.n_pages, self.page_size, H)
+                    pool = (L, self._alloc.n_pages, H, self.page_size, D)
+                    spool = pool[:-1]
                     self._cache = QuantPagedKVCache(
                         jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
                         jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
@@ -681,7 +681,7 @@ class ServingEngine:
                     # prefill aval, rows replaced by the page pool + tables
                     from ..generation.paged_cache import PagedKVCache
                     L, _, _, H, D = cache_aval.k.shape
-                    pool = (L, self._alloc.n_pages, self.page_size, H, D)
+                    pool = (L, self._alloc.n_pages, H, self.page_size, D)
                     self._cache = PagedKVCache(
                         jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
                         jax.device_put(np.zeros(pool, cache_aval.v.dtype)),

@@ -78,6 +78,13 @@ def _counter(name):
 # ---------------------------------------------------------- cache unit
 
 
+def _logical_rows(pool, pages):
+    """The pages ``pages`` of ``pool`` ([L, n_pages, H, page, ...]) as
+    the dense cache lays a row out: [L, len(pages) * page, H, ...]."""
+    g = np.swapaxes(np.asarray(pool)[:, np.asarray(pages)], 2, 3)
+    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+
+
 def test_paged_update_matches_dense_and_null_routes():
     """Writes through the page table land where the dense ring would
     put them; a dead lane (write base 0 — the engine's parked-slot
@@ -103,7 +110,7 @@ def test_paged_update_matches_dense_and_null_routes():
     for r, pos in enumerate((5, 9)):
         page, off = table[r][pos // ps], pos % ps
         np.testing.assert_array_equal(
-            np.asarray(paged.k[:, page, off]),
+            np.asarray(paged.k[:, page, :, off]),
             np.asarray(dense.k[:, r, pos]))
     # dead lane: kv_len 0 -> the write must land on the null page only
     dead = paged.with_kv_len(paged.kv_len.at[1].set(0))
@@ -139,11 +146,138 @@ def test_install_row_skips_shared_prefix_positions():
     # page 1 (positions 0..7, below start=8) kept its sentinel content
     np.testing.assert_array_equal(np.asarray(out.k[:, 1]), sentinel)
     # pages 2..3 carry the row's positions 8..19
-    np.testing.assert_array_equal(np.asarray(out.k[:, 2]),
-                                  np.asarray(row.k[:, 0, 8:16]))
-    np.testing.assert_array_equal(np.asarray(out.k[:, 3, :4]),
-                                  np.asarray(row.k[:, 0, 16:20]))
+    np.testing.assert_array_equal(_logical_rows(out.k, [2, 3])[:, :12],
+                                  np.asarray(row.k[:, 0, 8:20]))
     assert int(np.asarray(out.kv_len)[0]) == 20
+
+
+@pytest.mark.parametrize("n", [21, 27], ids=["ends-in-page", "past-table"])
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["wide", "int8"])
+def test_install_span_merges_into_whole_pages(cache_dtype, n):
+    """The install writes whole pages (gather, merge, scatter back), so
+    it has to leave every position outside ``[start, kv_len)`` as it
+    was: a start inside a page, an end inside a page, a source row that
+    is no whole number of pages and one longer than the table."""
+    rng = np.random.RandomState(5)
+    L, T, H, D, ps, start = 2, 44, 2, 8, 8, 11
+    row = KVCache.create(L, 1, T, H, D, cache_dtype=cache_dtype)
+    for layer in range(L):
+        row = row.update(layer,
+                         jnp.asarray(rng.randn(1, T, H, D), jnp.float32),
+                         jnp.asarray(rng.randn(1, T, H, D), jnp.float32),
+                         jnp.zeros((1,), jnp.int32))
+    row = row.with_kv_len(n)
+    paged = PagedKVCache.create(L, 1, n_pages=8, page_size=ps,
+                                pages_per_row=3, num_heads=H, head_dim=D,
+                                cache_dtype=cache_dtype)
+    names = ("k", "v") + (("k_scale", "v_scale") if cache_dtype else ())
+    for name in names:      # every page starts as a sentinel
+        setattr(paged, name, jnp.full_like(getattr(paged, name), 3))
+    out = paged.install_span(row, jnp.asarray([5, 2, 6], jnp.int32),
+                             jnp.asarray(start, jnp.int32))
+    assert not np.asarray(out.page_table).any()
+    assert not np.asarray(out.kv_len).any()
+    for name in names:
+        got = _logical_rows(getattr(out, name), [5, 2, 6])   # 24 positions
+        want = np.asarray(getattr(row, name))[:, 0]
+        # page 5 (positions 0..7) lies below start: not written at all;
+        # positions 8..10 of page 2 keep the sentinel, 11..min(n, 24)
+        # are the row's (24..26 are past the 3-page table), the rest of
+        # page 6 keeps the sentinel
+        end = min(n, 24)
+        assert (got[:, :start] == 3).all() and (got[:, end:] == 3).all()
+        np.testing.assert_array_equal(got[:, start:end],
+                                      want[:, start:end])
+        untouched = np.asarray(getattr(out, name))[:, [0, 1, 3, 4, 7]]
+        assert (untouched == 3).all()
+
+
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["wide", "int8"])
+def test_pool_shape_properties_read_the_right_axes(cache_dtype):
+    """The pool is [layers, n_pages, heads, page_size, head_dim] (tokens
+    on the second-minor axis, the block the decode kernel streams):
+    every size differs here, so a property reading the wrong axis
+    shows."""
+    c = PagedKVCache.create(2, 3, n_pages=11, page_size=8,
+                            pages_per_row=5, num_heads=4, head_dim=16,
+                            cache_dtype=cache_dtype)
+    assert c.k.shape == c.v.shape == (2, 11, 4, 8, 16)
+    assert (c.num_layers, c.n_pages, c.page_size) == (2, 11, 8)
+    assert (c.batch, c.pages_per_row, c.max_len) == (3, 5, 40)
+    if cache_dtype:
+        assert c.k_scale.shape == c.v_scale.shape == (2, 11, 4, 8)
+
+
+@pytest.mark.parametrize("s", [1, 4], ids=["s1", "s4"])
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["wide", "int8"])
+def test_idle_lane_write_lands_on_null_page(cache_dtype, s):
+    """An idle lane (kv_len 0) writes its ``s`` tokens to the null page
+    0 at offsets 0..s-1 of every head and to no other page, decode and
+    speculative-verify windows alike; the live lane beside it writes
+    through its table."""
+    rng = np.random.RandomState(3)
+    L, B, H, D, ps, P = 2, 2, 2, 8, 8, 2
+    table = np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P)
+    c = PagedKVCache.create(L, B, n_pages=1 + B * P, page_size=ps,
+                            pages_per_row=P, num_heads=H, head_dim=D,
+                            cache_dtype=cache_dtype)
+    c = c.with_kv_len(jnp.asarray([0, 6], np.int32))
+    c.page_table = jnp.asarray(table)
+    k = jnp.asarray(rng.randn(B, s, H, D), jnp.float32)
+    out = c.update(1, k, k, c.kv_len)
+    dense = KVCache.create(L, B, P * ps, H, D, cache_dtype=cache_dtype)
+    dense = dense.update(1, k, k, jnp.asarray([0, 6], np.int32))
+    for name in ("k", "v") + (("k_scale", "v_scale") if cache_dtype
+                              else ()):
+        got, want = getattr(out, name), np.asarray(getattr(dense, name))
+        # the idle lane's stale table names pages 1..2: untouched
+        assert not np.asarray(got)[:, table[0]].any()
+        np.testing.assert_array_equal(
+            _logical_rows(got, [0])[1, :s], want[1, 0, :s])
+        np.testing.assert_array_equal(
+            _logical_rows(got, table[1])[1, 6:6 + s], want[1, 1, 6:6 + s])
+        assert not np.asarray(got)[0].any()      # layer 0 never written
+
+
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["wide", "int8"])
+def test_install_row_then_gather_equals_dense_row_bitwise(cache_dtype):
+    """install_row, then the off-TPU gather of the stacked pool at each
+    layer, attends exactly what the dense decode attends over the
+    batch-1 row it was installed from: bit for bit, both dtypes."""
+    from paddle_tpu.kernels.flash_attention import (
+        flash_attention_decode, flash_attention_decode_paged)
+    rng = np.random.RandomState(4)
+    L, T, H, D, ps, n = 2, 32, 2, 8, 8, 19
+    row = KVCache.create(L, 1, T, H, D, cache_dtype=cache_dtype)
+    for layer in range(L):
+        row = row.update(layer,
+                         jnp.asarray(rng.randn(1, n, H, D), jnp.float32),
+                         jnp.asarray(rng.randn(1, n, H, D), jnp.float32),
+                         jnp.zeros((1,), jnp.int32))
+    row = row.with_kv_len(n)
+    paged = PagedKVCache.create(L, 2, n_pages=9, page_size=ps,
+                                pages_per_row=T // ps, num_heads=H,
+                                head_dim=D, cache_dtype=cache_dtype)
+    table_row = jnp.asarray([7, 2, 5, 0], jnp.int32)
+    paged = paged.install_row(row, 1, table_row, 0)
+    assert np.asarray(paged.page_table)[1].tolist() == [7, 2, 5, 0]
+    assert np.asarray(paged.kv_len).tolist() == [0, n]
+    q = jnp.asarray(rng.randn(1, 1, 2 * H, D), jnp.float32)
+    for layer in range(L):
+        sc = dict(k_scale=paged.k_scale, v_scale=paged.v_scale) \
+            if cache_dtype else {}
+        got = flash_attention_decode_paged(
+            q, paged.k, paged.v, paged.page_table[1:], paged.kv_len[1:],
+            layer, **sc)
+        sc = dict(k_scale=row.k_scale[layer], v_scale=row.v_scale[layer]) \
+            if cache_dtype else {}
+        want = flash_attention_decode(q, row.k[layer], row.v[layer],
+                                      row.kv_len, **sc)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ----------------------------------------------------------- allocator
@@ -194,28 +328,28 @@ def test_allocator_exhaustion_returns_none():
 # ------------------------------------------------------- paged kernel
 
 
-def test_paged_pallas_kernel_interpret_matches_fallback():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_pallas_kernel_interpret_matches_fallback(layer):
     """The scalar-prefetch Pallas kernel (interpret mode off-TPU) and
     the XLA gather fallback agree — the same index-map indirection the
-    GQA head mapping uses, extended to page ids."""
+    GQA head mapping uses, extended to page ids and to the layer of the
+    stacked pool, which both read in place."""
     from paddle_tpu.kernels.flash_attention import (
         _paged_decode_pallas, flash_attention_decode_paged)
     rng = np.random.RandomState(1)
-    B, P, ps, Hk, D, Hq, sq = 2, 4, 8, 2, 64, 4, 2
-    pool_k = rng.randn(1 + B * P, ps, Hk, D).astype(np.float32)
-    pool_v = rng.randn(1 + B * P, ps, Hk, D).astype(np.float32)
+    L, B, P, ps, Hk, D, Hq, sq = 3, 2, 4, 8, 2, 64, 4, 2
+    pool_k = jnp.asarray(rng.randn(L, 1 + B * P, Hk, ps, D), jnp.float32)
+    pool_v = jnp.asarray(rng.randn(L, 1 + B * P, Hk, ps, D), jnp.float32)
     table = np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P)
     kv_len = np.array([13, 27], np.int32)
     q = rng.randn(B, sq, Hq, D).astype(np.float32)
     ref = flash_attention_decode_paged(
-        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
-        jnp.asarray(table), jnp.asarray(kv_len))
+        jnp.asarray(q), pool_k, pool_v, jnp.asarray(table),
+        jnp.asarray(kv_len), layer)
     qt = jnp.swapaxes(jnp.asarray(q), 1, 2).reshape(B * Hq, sq, D)
-    kp = jnp.transpose(jnp.asarray(pool_k), (2, 0, 1, 3))
-    vp = jnp.transpose(jnp.asarray(pool_v), (2, 0, 1, 3))
-    out = _paged_decode_pallas(qt, kp, vp, jnp.asarray(table),
+    out = _paged_decode_pallas(qt, pool_k, pool_v, jnp.asarray(table),
                                jnp.asarray(kv_len), float(D ** -0.5),
-                               group=Hq // Hk, interpret=True)
+                               layer, group=Hq // Hk, interpret=True)
     out = jnp.swapaxes(out.reshape(B, Hq, sq, D), 1, 2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)

@@ -164,8 +164,12 @@ def test_quant_paged_install_bitwise():
     table = jnp.asarray(np.array([1, 2, 3, 4], np.int32))
     paged = paged.install_row(row, 0, table, 0)
     tb = np.asarray(table)
-    kp = np.asarray(paged.k)[:, tb].reshape(L, T, H, D)
-    sp = np.asarray(paged.k_scale)[:, tb].reshape(L, T, H)
+
+    def rows(pool):  # [L, n_pages, H, page, ..] -> the row's [L, T, H, ..]
+        return np.swapaxes(np.asarray(pool)[:, tb], 2, 3).reshape(
+            (L, T, H) + pool.shape[4:])
+
+    kp, sp = rows(paged.k), rows(paged.k_scale)
     np.testing.assert_array_equal(kp[:, :10], np.asarray(row.k)[:, 0, :10])
     np.testing.assert_array_equal(sp[:, :10],
                                   np.asarray(row.k_scale)[:, 0, :10])
@@ -177,8 +181,7 @@ def test_quant_paged_install_bitwise():
     prow = prow.update(0, jnp.asarray(np.concatenate([k1, k1])),
                        jnp.asarray(np.concatenate([v1, v1])),
                        prow.kv_len)
-    kq = np.asarray(prow.k)[:, tb].reshape(L, T, H, D)
-    sq = np.asarray(prow.k_scale)[:, tb].reshape(L, T, H)
+    kq, sq = rows(prow.k), rows(prow.k_scale)
     np.testing.assert_array_equal(kq[0, 10], np.asarray(drow.k)[0, 0, 10])
     np.testing.assert_array_equal(sq[0, 10],
                                   np.asarray(drow.k_scale)[0, 0, 10])

@@ -16,7 +16,9 @@ import, in a ``skipif`` or in a ``parametrize`` argument — so that under
 xdist only the worker that is given this file loads the TPU library.
 """
 import importlib
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,23 +155,124 @@ def test_chunk_prefill(one_chip, as_on_tpu, d, heads, q_len, quant):
     assert KERNEL in compiled_text(fn, q, kv, kv, kv_len, *scales)
 
 
+def pool_avals(one_chip, d, heads, quant, layers, batch):
+    """(PagedKVCache of avals, sds): ``layers`` stacked layers of a
+    page pool that holds ``batch`` full rows and the null page."""
+    from paddle_tpu.generation.paged_cache import (PagedKVCache,
+                                                   QuantPagedKVCache)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    slots = CACHE_LEN // PAGE
+    shape = (layers, batch * slots + 1, heads, PAGE, d)
+    pool = sds(shape, jnp.int8 if quant else BF16)
+    table, kv_len = sds((batch, slots), jnp.int32), sds((batch,), jnp.int32)
+    if not quant:
+        return PagedKVCache(pool, pool, table, kv_len), sds
+    scale = sds(shape[:-1], BF16)
+    return QuantPagedKVCache(pool, pool, table, kv_len, scale, scale,
+                             sds((), jnp.int32)), sds
+
+
+def paged_scales(cache):
+    if cache.cache_dtype is None:
+        return {}
+    return dict(k_scale=cache.k_scale, v_scale=cache.v_scale)
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("d,heads", WIDTHS)
 def test_paged_decode(one_chip, as_on_tpu, d, heads, quant):
-    batch, slots = 8, CACHE_LEN // PAGE
-    n_pages = batch * slots + 1
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
-                                                 sharding=one_chip)
-    q = sds((batch, 1, heads, d), BF16)
-    pool = sds((n_pages, PAGE, heads, d), jnp.int8 if quant else BF16)
-    scales = [sds((n_pages, PAGE, heads), BF16)] * 2 if quant else []
-    table = sds((batch, slots), jnp.int32)
-    kv_len = sds((batch,), jnp.int32)
+    cache, sds = pool_avals(one_chip, d, heads, quant, layers=2, batch=8)
 
-    def fn(q, kp, vp, table, kv_len, *sc):
+    def fn(q, cache):
         return fa.flash_attention_decode_paged(
-            q, kp, vp, table, kv_len,
-            **(dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}))
+            q, cache.k, cache.v, cache.page_table, cache.kv_len, 1,
+            **paged_scales(cache))
 
-    assert KERNEL in compiled_text(fn, q, pool, pool, table, kv_len,
-                                   *scales)
+    assert KERNEL in compiled_text(fn, sds((8, 1, heads, d), BF16), cache)
+
+
+# ---- the page pool is read and written where it lies (gpt3-6.7b widths)
+#
+# An 8-layer stacked pool of 32 heads of 128. One layer of it is 0.27 GB
+# of bf16 here (0.54 GB in the cell, whose pool is twice as many pages):
+# a program that slices a layer out of the pool, transposes it, or lets
+# XLA's layout assignment flip the pool around a scatter, copies that
+# much on every call. None of the programs below may hold one.
+
+POOL_LAYERS, POOL_BATCH, POOL_HEADS, POOL_D = 8, 32, 32, 128
+RELAYOUTS = ("copy", "transpose", "slice", "reshape")
+HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def pool_sized_relayouts(text, layer_elements):
+    """Instructions of ``text`` (fused ones included) that are a copy,
+    transpose, slice or reshape, async halves too, of at least one
+    layer's pool."""
+    found = []
+    for line in text.splitlines():
+        m = HLO_RESULT.match(line)
+        if not m or not m.group(2).startswith(RELAYOUTS):
+            continue
+        if math.prod(int(n) for n in m.group(1).split(",") if n) \
+                >= layer_elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def compiled_donating(fn, *avals):
+    return jax.jit(fn, donate_argnums=(0,)).lower(*avals).compile() \
+        .as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("s", [1, 4], ids=["s1", "s4"])
+def test_paged_step_reads_the_pool_in_place(one_chip, as_on_tpu, s, quant):
+    """Per-layer ``update`` (decode, and a speculative window of 4) then
+    the paged decode, over all 8 layers of a donated pool."""
+    cache, sds = pool_avals(one_chip, POOL_D, POOL_HEADS, quant,
+                            POOL_LAYERS, POOL_BATCH)
+    new = sds((POOL_BATCH, s, POOL_HEADS, POOL_D), BF16)
+
+    def fn(cache, q, k, v):
+        out = 0
+        for layer in range(POOL_LAYERS):
+            cache = cache.update(layer, k, v, cache.kv_len)
+            out += fa.flash_attention_decode_paged(
+                q, cache.k, cache.v, cache.page_table, cache.kv_len + s,
+                layer, **paged_scales(cache))
+        return cache, out
+
+    text = compiled_donating(fn, cache, new, new, new)
+    assert text.count(KERNEL) >= POOL_LAYERS
+    assert not pool_sized_relayouts(text, cache.k.size // POOL_LAYERS)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("program", ["install_row", "install_span"])
+def test_paged_install_writes_the_pool_in_place(one_chip, program, quant):
+    """Admission and chunked prefill scatter a batch-1 dense row into
+    the donated pool: no kernel, and no pool-sized temporary."""
+    from paddle_tpu.generation.kv_cache import KVCache, QuantKVCache
+    cache, sds = pool_avals(one_chip, POOL_D, POOL_HEADS, quant,
+                            POOL_LAYERS, POOL_BATCH)
+    row = (POOL_LAYERS, 1, CACHE_LEN, POOL_HEADS, POOL_D)
+    kv, one = sds(row, cache.k.dtype), sds((1,), jnp.int32)
+    if quant:
+        scale = sds(row[:-1], BF16)
+        src = QuantKVCache(kv, kv, one, scale, scale, sds((), jnp.int32))
+    else:
+        src = KVCache(kv, kv, one)
+    table_row = sds((CACHE_LEN // PAGE,), jnp.int32)
+    at = sds((), jnp.int32)
+    if program == "install_row":
+        text = compiled_donating(
+            lambda c, src, slot, row, start:
+                c.install_row(src, slot, row, start),
+            cache, src, at, table_row, at)
+    else:
+        text = compiled_donating(
+            lambda c, src, row, start: c.install_span(src, row, start),
+            cache, src, table_row, at)
+    assert not pool_sized_relayouts(text, cache.k.size // POOL_LAYERS)
