@@ -179,7 +179,9 @@ DECLARED_SPANS = {
     "serve.poll": "one scheduler poll inside serve.step (steps = decode "
                   "steps it covers, emitted = tokens the lanes advanced "
                   "since the last poll, admitted = lanes polled for the "
-                  "first time, completed, evicted, live)",
+                  "first time, completed, evicted, live; under block "
+                  "diffusion also forwards = lane-forwards and commits = "
+                  "blocks committed since the last poll)",
     "serve.queue_wait": "every request: submit -> popped from the queue, "
                         "which is its admitted_at whether or not the "
                         "prefill then succeeds (trace id; req, bucket; "

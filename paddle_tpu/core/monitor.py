@@ -49,6 +49,12 @@ Metric name scheme (what the summary views group by):
     gen.spec.proposed / .accepted   speculative draft tokens in/out of
                                 the single-dispatch verify
     gen.spec.accept_rate        gauge: accepted/proposed, last window
+    gen.diffusion.forwards / .unmasked / .commits   block diffusion:
+                                lane-forwards, tokens unmasked, blocks
+                                committed (drained at each poll)
+    moe.rows / moe.expert_rows_max   dropless expert layers: (token,
+                                expert) rows computed, and the busiest
+                                expert's rows, summed over layers and steps
     serve.requests{status=...}  terminal request outcomes (completed/
                                 cancelled/rejected) — QPS = rate of this
     serve.queue_depth           gauge: requests waiting for a slot
@@ -110,6 +116,8 @@ DECLARED_METRICS = frozenset({
     "gen.cache.pages_allocated", "gen.cache.pages_freed",
     "gen.cache.quant.bytes_saved", "gen.cache.quant.scale_clips",
     "gen.spec.proposed", "gen.spec.accepted", "gen.spec.accept_rate",
+    "gen.diffusion.forwards", "gen.diffusion.unmasked",
+    "gen.diffusion.commits", "moe.rows", "moe.expert_rows_max",
     "serve.requests", "serve.queue_depth", "serve.ttft",
     "serve.token_latency", "serve.slot_occupancy", "serve.cancellations",
     "serve.prefill.chunks", "serve.prefill.chunk_tokens",
@@ -264,6 +272,24 @@ METRIC_DOC = {
     "gen.spec.accept_rate": ("gauge", (),
                              "accepted/proposed over the last recorded "
                              "speculative window batch"),
+    "gen.diffusion.forwards": ("counter", (),
+                               "block-diffusion lane-forwards: one per "
+                               "live lane per engine step, denoise or "
+                               "commit"),
+    "gen.diffusion.unmasked": ("counter", (),
+                               "tokens unmasked by block-diffusion "
+                               "denoise steps (= output tokens emitted)"),
+    "gen.diffusion.commits": ("counter", (),
+                              "blocks committed: the forward that writes "
+                              "a final block's K and V and opens the "
+                              "next block"),
+    "moe.rows": ("counter", (),
+                 "(token, expert) rows the dropless expert layers "
+                 "computed, summed over layers and steps"),
+    "moe.expert_rows_max": ("counter", (),
+                            "rows of the busiest expert, summed over "
+                            "layers and steps (x experts / moe.rows = "
+                            "load imbalance)"),
     "serve.requests": ("counter", ("status",),
                        "requests reaching a terminal status: completed "
                        "| cancelled | rejected (QPS = rate of this)"),
@@ -702,6 +728,31 @@ def record_speculative(proposed: int, accepted: int):
             float(accepted) / float(proposed))
     if accepted:
         metrics.counter("gen.spec.accepted").inc(int(accepted))
+
+
+def record_block_diffusion(forwards: int, unmasked: int, commits: int):
+    """Block-diffusion progress since the last record (the serving
+    engine records once per scheduler poll, from on-device counters)."""
+    if not enabled:
+        return
+    if forwards:
+        metrics.counter("gen.diffusion.forwards").inc(int(forwards))
+    if unmasked:
+        metrics.counter("gen.diffusion.unmasked").inc(int(unmasked))
+    if commits:
+        metrics.counter("gen.diffusion.commits").inc(int(commits))
+
+
+def record_moe_routing(rows: int, rows_max: int):
+    """Dropless expert layers' routing since the last record: rows
+    computed and the busiest expert's rows, both summed over layers and
+    steps (the engine drains the device counters at each poll)."""
+    if not enabled:
+        return
+    if rows:
+        metrics.counter("moe.rows").inc(int(rows))
+    if rows_max:
+        metrics.counter("moe.expert_rows_max").inc(int(rows_max))
 
 
 def record_cache_occupancy(frac: float):

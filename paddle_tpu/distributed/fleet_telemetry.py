@@ -210,8 +210,14 @@ class MetricsPublisher:
                 self.sync_clock()
             if self._prev is not None and \
                     self.store.keys(self._resync_key):
-                self._prev = None    # aggregator asked: go absolute
-                self.store.delete(self._resync_key)
+                # the aggregator asked: go absolute, and STAY absolute
+                # until it has read one full snapshot (it then clears
+                # the request). Were the request cleared here, a reader
+                # slower than this writer would find the one full
+                # payload already overwritten by the next delta, ask
+                # again, and never catch up (a loaded host: the
+                # publisher's period passes several times a poll)
+                self._prev = None
             new_prev, delta = metrics.snapshot_delta(self._prev)
             # the fleet meta-plane (fleet.*) is produced by the
             # aggregator; republishing our local copy would collide
@@ -395,10 +401,11 @@ class FleetAggregator:
             except (TimeoutError, RuntimeError, OSError):
                 ages[rank] = None
         # ---- merge phase: view lock held, in-memory only
-        resyncs = []
+        resyncs, answered = [], []
         with self._lock:
             for rank, payload in payloads:
-                self._apply(rank, payload, resyncs)
+                if self._apply(rank, payload, resyncs):
+                    answered.append(rank)
             stale = 0
             for rank, st in self._ranks.items():
                 st.age_s = ages.get(rank)
@@ -422,7 +429,14 @@ class FleetAggregator:
         self.straggler.observe(step_totals)
         self._slo_ring.sample_state(fleet_state)
         self.slo_evaluator.evaluate()
-        # ---- resync writes: store I/O again, lock released
+        # ---- resync writes: store I/O again, lock released. A full
+        # snapshot just applied answers any request for one: clear it,
+        # so that the rank goes back to deltas
+        for rank in answered:
+            try:
+                self.store.delete(f"{self._ns}/resync/{rank}")
+            except (TimeoutError, RuntimeError, OSError) as e:
+                monitor.record_swallowed("fleet.resync", e)
         for rank, st in resyncs:
             try:
                 self.store.set(f"{self._ns}/resync/{rank}", True)
@@ -454,7 +468,8 @@ class FleetAggregator:
         return state, totals
 
     def _apply(self, rank: int, payload: dict, resyncs: list):
-        # caller holds self._lock
+        # caller holds self._lock; True when a FULL snapshot was applied
+        # (it answers any resync request: the poll then clears it)
         inc = int(payload.get("incarnation", 0))
         seq = int(payload.get("seq", 0))
         pid = int(payload.get("pid", 0))
@@ -474,6 +489,10 @@ class FleetAggregator:
                     f"pid {st.pid} and pid {pid}: give each replica a "
                     f"distinct PADDLE_REPLICA_ID (or rank)"))
         fresh_stream = st is None or st.incarnation != inc
+        if not fresh_stream:
+            # health is the rank's state now, not a delta: it is never
+            # held back by a gap in the metrics stream
+            st.health = dict(payload.get("health") or {})
         if fresh_stream and not delta.get("full"):
             # mid-stream join (aggregator restarted, or a relaunched
             # rank whose first full publish we missed): hold the old
@@ -499,6 +518,7 @@ class FleetAggregator:
         st.health = dict(payload.get("health") or {})
         st.clock_offset_ns = int(payload.get("clock_offset_ns", 0))
         st.resync_pending = False
+        return bool(delta.get("full"))
 
     def _request_resync(self, rank: int, st: Optional[_RankState],
                         inc: int, replica: str, resyncs: list):

@@ -17,6 +17,12 @@ loss, Fedus et al.), 'gshard' (top-2 + load-balance loss, Lepikhin et al.).
 Auxiliary loss is exposed as `layer.l_aux` (a traced value when called
 under jit: read it in the SAME trace, e.g. inside the loss closure —
 `aux_loss(model)` sums it over all MoE sublayers).
+
+`DroplessMoE` is the expert layer of present-day sparse models (many
+narrow gated experts, several a token): no capacity and no dropped token.
+Tokens are sorted by expert and each projection is ONE grouped product
+over the experts' stacked weights (`jax.lax.ragged_dot`), so the cost is
+the rows routed, not `[tokens, E, capacity]`.
 """
 from __future__ import annotations
 
@@ -165,6 +171,112 @@ class MoEMLP(Layer):
         ye = sharded_constraint(ye, P("ep", None, None))
         y = jnp.einsum("tec,ecm->tm", combine.astype(xf.dtype), ye)
         return y.reshape(shape), aux
+
+
+def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
+                 norm_topk_prob: bool = True):
+    """Pure-jax body of :class:`DroplessMoE` on raw arrays.
+
+    x [T, H]; router_w [H, E]; w_gate_up [E, H, 2F] (gate then up);
+    w_down [E, F, H]. Returns (y [T, H], rows [E] int32: how many
+    (token, expert) rows each expert computed).
+
+    Router: softmax over ALL experts in float32, the ``top_k`` largest,
+    renormalised over the chosen (``norm_topk_prob``). Experts: the
+    T * top_k (token, expert) rows sorted by expert, one grouped product
+    for gate+up, SiLU(gate) * up, one for down, then each token's k rows
+    weighted and summed in float32. Nothing is dropped; an expert no
+    token chose is an empty group."""
+    t, h = x.shape
+    e, f = w_down.shape[0], w_down.shape[1]
+    with jax.named_scope("moe_router"):
+        # a float32 product in earnest (the TPU's default would round
+        # both operands to bfloat16): the 8th and 9th probabilities lie
+        # close, and which of them is chosen changes the output
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if norm_topk_prob:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+    with jax.named_scope("moe_experts"):
+        flat = idx.reshape(-1)                          # [T*k] expert ids
+        order = jnp.argsort(flat, stable=True)          # rows by expert
+        rows = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        xs = x[order // top_k]                          # [T*k, H]
+        gu = jax.lax.ragged_dot(xs, w_gate_up, rows,
+                                preferred_element_type=jnp.float32)
+        z = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(x.dtype)
+        ys = jax.lax.ragged_dot(z, w_down, rows,
+                                preferred_element_type=jnp.float32)
+        # back to (token, k) order, weight, sum the k rows of a token
+        ys = ys[jnp.argsort(order)].reshape(t, top_k, h)
+        y = jnp.einsum("tkh,tk->th", ys, top)
+    return y.astype(x.dtype), rows
+
+
+class DroplessMoE(Layer):
+    """Dropless sparse-expert FFN: ``num_experts`` SiLU-gated experts of
+    width ``d_expert``, ``top_k`` a token, no shared expert, no drops.
+
+    Holds the experts stacked: ``gate_up`` [E, d_model, 2 * d_expert]
+    (gate then up, side by side so one grouped product feeds both) and
+    ``down`` [E, d_expert, d_model], sharded over 'ep'. After a forward,
+    ``rows`` holds that call's per-expert row counts (a traced value
+    under jit: read it in the SAME trace — :func:`routing_stats`)."""
+
+    def __init__(self, d_model: int, d_expert: int, num_experts: int,
+                 top_k: int, norm_topk_prob: bool = True,
+                 std: float = 0.02, down_std: Optional[float] = None,
+                 dtype=None):
+        # dtype: the stacked experts are nearly all of a sparse model;
+        # built in float32 first, a model served in bfloat16 on one chip
+        # would not fit beside its own cast
+        super().__init__()
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} outside [1, {num_experts}]")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.router = self.create_parameter(
+            (d_model, num_experts), default_initializer=I.Normal(0.0, std))
+        self.router.spec = P()
+        self.gate_up = self.create_parameter(
+            (num_experts, d_model, 2 * d_expert), dtype=dtype,
+            default_initializer=I.Normal(0.0, std))
+        self.gate_up.spec = P("ep", None, None)
+        self.down = self.create_parameter(
+            (num_experts, d_expert, d_model), dtype=dtype,
+            default_initializer=I.Normal(0.0, down_std or std))
+        self.down.spec = P("ep", None, None)
+        self.rows = None
+
+    def forward(self, x):
+        shape = x.shape
+        y, rows = _dispatch(
+            "dropless_moe",
+            lambda x_, r, gu, dn: dropless_moe(
+                x_.reshape(-1, shape[-1]), r, gu, dn, self.top_k,
+                self.norm_topk_prob),
+            (x, self.router, self.gate_up, self.down), {})
+        self.rows = rows
+        return y.reshape(shape)
+
+
+def routing_stats(model: Layer):
+    """(rows, rows_max) summed over every :class:`DroplessMoE` sublayer's
+    last forward: the (token, expert) rows computed and the busiest
+    expert's rows, int32 scalars (traced under jit: call in the same
+    trace as the forward, like :func:`aux_loss`). None when the model has
+    no such layer."""
+    total = None
+    for layer in model.sublayers(include_self=True):
+        if isinstance(layer, DroplessMoE) and layer.rows is not None:
+            r = layer.rows._data if isinstance(layer.rows, Tensor) \
+                else layer.rows
+            one = (jnp.sum(r), jnp.max(r))
+            total = one if total is None \
+                else (total[0] + one[0], total[1] + one[1])
+    return total
 
 
 def aux_loss(model: Layer):

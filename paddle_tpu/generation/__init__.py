@@ -5,6 +5,7 @@ flash attention, sampling, and the jitted (prefill, decode) pair behind
 See docs/architecture.md "Generation & KV cache".
 """
 from .api import GenerationConfig, GenerationSession, generate  # noqa: F401
+from .block_diffusion import BlockDiffusionConfig  # noqa: F401
 from .kv_cache import (KVCache, QuantKVCache,  # noqa: F401
                        quantize_kv, resolve_cache_dtype)
 from .paged_cache import (AdmissionPlan, PageAllocator,  # noqa: F401
@@ -21,5 +22,5 @@ __all__ = [
     "AdmissionPlan",
     "sample", "apply_temperature", "apply_top_k", "apply_top_p",
     "SpeculativeConfig", "SpeculativeSession", "ngram_propose",
-    "spec_accept",
+    "spec_accept", "BlockDiffusionConfig",
 ]

@@ -11,17 +11,56 @@ semantics) can never silently diverge between models; only the
 from __future__ import annotations
 
 
+def block_causal_attention(q, k, v, block: int):
+    """Prefill self-attention under the BLOCK-causal mask of generation
+    by diffusion over blocks (query i sees key j iff
+    ``j // block <= i // block``), q/k/v raw [b, s, heads, head_dim]
+    with grouped kv heads. The flash kernel (``block=``) where it
+    applies, else the XLA softmax under an explicit mask."""
+    import jax
+    import jax.numpy as jnp
+    from ..nn.functional.attention import _FLASH_MIN_SEQ, _sdpa_xla
+    s, d = q.shape[1], q.shape[-1]
+    if jax.default_backend() == "tpu" and s >= _FLASH_MIN_SEQ \
+            and s % 128 == 0 and d in (64, 128, 256):
+        from ..kernels.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=True, block=block)
+    g = q.shape[2] // k.shape[2]
+    if g > 1:       # query head i reads kv head i // g
+        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    blk = jnp.arange(s, dtype=jnp.int32) // block
+    return _sdpa_xla(q, k, v, mask=blk[None, :] <= blk[:, None])
+
+
 def cached_attention(q, k, v, cache, layer_idx, *, decode: bool,
-                     causal: bool, attn_mask=None):
+                     causal: bool, attn_mask=None, block=None):
     """Write ``k``/``v`` ([b, s, heads, head_dim] Tensors) into
     ``cache`` at layer ``layer_idx`` and attend. Returns (out, cache);
     ``out`` is [b, s, heads, head_dim]. Decode reads the cached prefix
     via ``kernels.flash_attention_decode`` with per-row ragged masking
     at ``kv_len + s``; prefill is plain self-attention over the fresh
-    window (``causal`` per model family, ``attn_mask`` honored)."""
+    window (``causal`` per model family, ``attn_mask`` honored).
+
+    ``block`` (generation by diffusion over blocks): prefill attends
+    block-causally, and a decode window is one block whose ``s``
+    positions all see each other as well as the cached prefix
+    (``window_causal=False``)."""
+    if block is not None:
+        import jax
+        # the region a device trace tells apart from causal attention
+        with jax.named_scope("block_attn"):
+            return _cached_attention(q, k, v, cache, layer_idx, decode,
+                                     causal, attn_mask, block)
+    return _cached_attention(q, k, v, cache, layer_idx, decode, causal,
+                             attn_mask, None)
+
+
+def _cached_attention(q, k, v, cache, layer_idx, decode, causal, attn_mask,
+                      block):
     from ..core.tensor import dispatch
     from ..nn import functional as F
     cache = cache.update(layer_idx, k, v, cache.kv_len)
+    wc = {} if block is None else {"window_causal": False}
     if decode:
         s = q.shape[1]
         mask_len = cache.kv_len + s  # includes the new rows
@@ -40,7 +79,7 @@ def cached_attention(q, k, v, cache, layer_idx, *, decode: bool,
                 "flash_attention_decode_paged",
                 lambda q_, kp, vp, pt, kl, *sc:
                     flash_attention_decode_paged(
-                        q_, kp, vp, pt, kl, layer_idx,
+                        q_, kp, vp, pt, kl, layer_idx, **wc,
                         **(dict(k_scale=sc[0], v_scale=sc[1])
                            if sc else {})),
                 (q, cache.k, cache.v, cache.page_table, mask_len)
@@ -55,6 +94,10 @@ def cached_attention(q, k, v, cache, layer_idx, *, decode: bool,
             MAX_DECODE_QLEN, flash_attention_chunk,
             flash_attention_decode)
         if s > MAX_DECODE_QLEN:
+            if block is not None:
+                raise NotImplementedError(
+                    f"a block of {s} positions exceeds the decode "
+                    f"kernels' window of {MAX_DECODE_QLEN}")
             # chunk-prefill window (serving's chunked admission): a
             # C-token slice of a long prompt attends the cache written
             # by the earlier chunks — decode-shaped ragged masking,
@@ -72,10 +115,15 @@ def cached_attention(q, k, v, cache, layer_idx, *, decode: bool,
         out = dispatch(
             "flash_attention_decode",
             lambda q_, kc, vc, kl, *sc: flash_attention_decode(
-                q_, kc, vc, kl,
+                q_, kc, vc, kl, **wc,
                 **(dict(k_scale=sc[0], v_scale=sc[1]) if sc else {})),
             (q, cache.k[layer_idx], cache.v[layer_idx], mask_len)
             + scales, {}, differentiable=False)
+    elif block is not None:
+        out = dispatch(
+            "block_causal_attention",
+            lambda q_, k_, v_: block_causal_attention(q_, k_, v_, block),
+            (q, k, v), {}, differentiable=False)
     else:
         out = F.scaled_dot_product_attention(
             q, k, v, attn_mask=attn_mask, is_causal=causal,

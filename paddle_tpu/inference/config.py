@@ -99,7 +99,8 @@ class Config:
                           temperature: float = 1.0, top_k: int = 0,
                           top_p: float = 1.0, eos_token_id=None,
                           pad_token_id=None, speculative=None,
-                          draft_model=None, kv_cache_dtype=None):
+                          draft_model=None, kv_cache_dtype=None,
+                          block_diffusion=None):
         """Generation serving mode: the predictor AOT-compiles one
         (prefill, decode) executable pair per prompt bucket at build
         time and batches ``Predictor.generate()`` requests at that
@@ -124,9 +125,20 @@ class Config:
         this config: int8 values + per-(position, head) bf16 scales,
         dequant fused inside the decode kernels — half the cache HBM
         streamed per token, double the slots/pages a fixed pool
-        holds."""
+        holds.
+
+        ``block_diffusion`` (a dict of ``generation.BlockDiffusionConfig``
+        fields: ``block_length``, ``denoising_steps``, ``remasking``,
+        ``confidence_threshold``, ``mask_token_id``) serves a model that
+        generates by diffusion over blocks on the ServingEngine: every
+        step forwards each lane's block and unmasks its most confident
+        positions or commits it. Greedy, inline prefill, no speculation;
+        the layer takes ``block_length=`` through the KV-cache
+        protocol."""
+        from ..generation.block_diffusion import as_block_diffusion_config
         from ..generation.kv_cache import validate_cache_dtype
         from ..generation.speculative import as_spec_config
+        as_block_diffusion_config(block_diffusion)  # validate eagerly
         as_spec_config(speculative, draft_model)  # validate eagerly
         validate_cache_dtype(kv_cache_dtype)      # validate eagerly too
         self._generation = dict(
@@ -136,7 +148,8 @@ class Config:
             temperature=float(temperature), top_k=int(top_k),
             top_p=float(top_p), eos_token_id=eos_token_id,
             pad_token_id=pad_token_id, speculative=speculative,
-            draft_model=draft_model, kv_cache_dtype=kv_cache_dtype)
+            draft_model=draft_model, kv_cache_dtype=kv_cache_dtype,
+            block_diffusion=block_diffusion)
         return self
 
     def enable_serving(self, max_queue: int = 64, poll_every: int = 4,

@@ -292,6 +292,9 @@ _SALT_FILES = (
     "serving/engine.py",
     "inference/precision.py",
     "core/tensor.py",
+    # pieces a model file assembles from outside its own module
+    "models/decoder.py",
+    "distributed/parallel/moe.py",
 )
 _framework_salt_cache: Optional[str] = None
 

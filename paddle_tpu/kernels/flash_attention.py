@@ -74,9 +74,17 @@ def _pick_block(seq: int, preferred: int) -> int:
 
 # ---------------------------------------------------------------- forward
 
+def _causal_rows(rows, block):
+    """The last column a query row may see under the (block-)causal
+    mask: itself, or with ``block`` B > 1 the end of its block of B
+    (``j // B <= i // B``; B divides every kernel block, so the k-block
+    skips and prefix buckets of the causal kernels hold unchanged)."""
+    return rows if block == 1 else rows // block * block + (block - 1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal, offset,
-                block_q, block_k, num_kblocks, kv_len=None):
+                block_q, block_k, num_kblocks, kv_len=None, block=1):
     # q_ref holds q * (scale * log2e); scores are base-2 logits
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -105,7 +113,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 + iq * block_q + offset
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
                 + ik * block_k
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            s = jnp.where(_causal_rows(rows, block) >= cols, s, _NEG_INF)
         if kv_len is not None:  # padded keys: mask cols beyond kv_len
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
                 + ik * block_k
@@ -131,7 +139,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], _LSE_LANES))
 
 
-def _whole_k_attn(q, k, v, iq, block_q, offset, causal, kv_len, out_dtype):
+def _whole_k_attn(q, k, v, iq, block_q, offset, causal, kv_len, out_dtype,
+                  block=1):
     """One-shot softmax-attention over a q-block against the given K/V
     columns (assumed to start at col 0). Returns (o, lse) values."""
     s = jax.lax.dot_general(
@@ -141,7 +150,7 @@ def _whole_k_attn(q, k, v, iq, block_q, offset, causal, kv_len, out_dtype):
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
             + iq * block_q + offset
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = jnp.where(_causal_rows(rows, block) >= cols, s, _NEG_INF)
     if kv_len is not None:
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols < kv_len, s, _NEG_INF)
@@ -164,7 +173,7 @@ def _whole_k_attn(q, k, v, iq, block_q, offset, causal, kv_len, out_dtype):
 
 def _fwd_kernel_whole_k(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                         causal, offset, block_q, num_qblocks,
-                        causal_splits=1, kv_len=None):
+                        causal_splits=1, kv_len=None, block=1):
     """Single-k-block forward: the whole K/V is one block, so the online
     rescale machinery (m/l/acc scratch, alpha corrections) degenerates —
     this variant drops it entirely. This IS the hot path for the
@@ -190,19 +199,20 @@ def _fwd_kernel_whole_k(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             def _branch(prefix=prefix):
                 o, lse = _whole_k_attn(
                     q_ref[0], k_ref[0, :prefix], v_ref[0, :prefix], iq,
-                    block_q, offset, causal, kv_len, o_ref.dtype)
+                    block_q, offset, causal, kv_len, o_ref.dtype, block)
                 o_ref[0] = o
                 lse_ref[0] = jnp.broadcast_to(
                     lse, (lse.shape[0], _LSE_LANES))
     else:
         o, lse = _whole_k_attn(
             q_ref[0], k_ref[0], v_ref[0], iq, block_q,
-            offset, causal, kv_len, o_ref.dtype)
+            offset, causal, kv_len, o_ref.dtype, block)
         o_ref[0] = o
         lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], _LSE_LANES))
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None,
+               block=1):
     bh, sq, d = q.shape
     sk = k.shape[1]
     bq = _pick_block(sq, block_q)
@@ -238,7 +248,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None):
         kernel = functools.partial(
             _fwd_kernel_whole_k, causal=causal, offset=sk - sq,
             block_q=bq, num_qblocks=nq, causal_splits=n_splits,
-            kv_len=kv_len)
+            kv_len=kv_len, block=block)
         grid = (bh, nq)
         out, lse = pl.pallas_call(
             kernel,
@@ -263,7 +273,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_len=None):
         return out, lse
     kernel = functools.partial(
         _fwd_kernel, causal=causal, offset=sk - sq,
-        block_q=bq, block_k=bk, num_kblocks=nk, kv_len=kv_len)
+        block_q=bq, block_k=bk, num_kblocks=nk, kv_len=kv_len,
+        block=block)
     grid = (bh, nq, nk)
     out, lse = pl.pallas_call(
         kernel,
@@ -866,7 +877,8 @@ def _decode_init(m_scr, l_scr, acc_scr):
 
 
 def _decode_accumulate(q, k, v, col_base, kv_len, sq,
-                       m_scr, l_scr, acc_scr, ks=None, vs=None):
+                       m_scr, l_scr, acc_scr, ks=None, vs=None,
+                       window_causal=True):
     """One k-block of the decode online softmax — the ONE copy of the
     accumulate math shared by the dense and paged decode kernels, so
     their numerics can never silently diverge (the paged/dense
@@ -875,7 +887,11 @@ def _decode_accumulate(q, k, v, col_base, kv_len, sq,
     Query row i sits at global position kv_len - sq + i: it may attend
     keys at cols <= kv_len - sq + i (ragged causal; ``col_base`` is
     this block's first logical column). Rows past sq-1 are padding;
-    their outputs are sliced off outside.
+    their outputs are sliced off outside. With ``window_causal=False``
+    every row attends every valid column (``cols < kv_len``): the
+    denoise window of block diffusion, whose positions all see each
+    other — the mask then does not depend on the row, so the caller may
+    stack the query heads of one kv head into the rows.
 
     ``ks``/``vs`` ([1, bk] per-column dequant scales) switch on the
     int8-cache mode: k/v arrive int8 and the dequant FUSES into the
@@ -900,7 +916,10 @@ def _decode_accumulate(q, k, v, col_base, kv_len, sq,
             preferred_element_type=jnp.float32)      # [qpad, bk] base-2
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col_base
-    s = jnp.where(cols - rows <= kv_len - sq, s, _NEG_INF)
+    if window_causal:
+        s = jnp.where(cols - rows <= kv_len - sq, s, _NEG_INF)
+    else:
+        s = jnp.where(cols < kv_len, s, _NEG_INF)
     m_prev = m_scr[:, 0:1]
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
@@ -930,7 +949,7 @@ def _decode_write_out(o_ref, l_scr, acc_scr):
 
 
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq, block_k,
-                   num_kblocks, group, quant=False):
+                   num_kblocks, group, quant=False, window_causal=True):
     # q_ref holds q * (scale * log2e); scores are base-2 logits. In
     # quant mode two per-column bf16 scale rows ([1, 1, bk], same index
     # map as k/v) follow the caches, and the shared accumulate body
@@ -956,16 +975,24 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq, block_k,
         _decode_accumulate(q_ref[0], k_ref[0], v_ref[0], ik * block_k,
                            kv_len, sq, m_scr, l_scr, acc_scr,
                            ks=ks_ref[0] if quant else None,
-                           vs=vs_ref[0] if quant else None)
+                           vs=vs_ref[0] if quant else None,
+                           window_causal=window_causal)
 
     @pl.when(ik == num_kblocks - 1)
     def _finalize():
         _decode_write_out(o_ref, l_scr, acc_scr)
 
 
+def _window_qpad(sq: int) -> int:
+    """Query rows padded to whole fp32 sublane tiles (8 for a causal
+    window of <= MAX_DECODE_QLEN; more where a full window stacks the
+    query heads of one kv head)."""
+    return -(-sq // _DECODE_QPAD) * _DECODE_QPAD
+
+
 def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
                    block_k=_DECODE_BLOCK_K, group=1,
-                   k_scale=None, v_scale=None):
+                   k_scale=None, v_scale=None, window_causal=True):
     """q: [B*Hq, sq<=8, D] (unscaled), caches [B*Hk, T, D], kv_len
     [B*Hk]. GQA/MQA (``group`` = Hq//Hk > 1) maps each query head to
     its kv head via the k/v BlockSpec index maps (grid row b reads
@@ -979,7 +1006,7 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
     bh, sq, d = q.shape
     t = k_cache.shape[1]
     quant = k_scale is not None
-    qpad = _DECODE_QPAD
+    qpad = _window_qpad(sq)
     q = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     if sq < qpad:
         q = jnp.pad(q, ((0, 0), (0, qpad - sq), (0, 0)))
@@ -1006,7 +1033,9 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sq=sq, block_k=bk,
-                          num_kblocks=nk, group=group, quant=quant),
+                          num_kblocks=nk, group=group, quant=quant,
+                          **({} if window_causal
+                             else {"window_causal": False})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, qpad, d), q.dtype),
         cost_estimate=pl.CostEstimate(
@@ -1021,7 +1050,7 @@ def _decode_pallas(q, k_cache, v_cache, kv_len, scale,
 
 
 def _decode_xla(q, k_cache, v_cache, kv_len, scale, group=1,
-                ks=None, vs=None):
+                ks=None, vs=None, window_causal=True):
     """Fallback decode attention (CPU/interpret, or cache lengths off
     the 128 grid): fp32 masked softmax over [B*Hk, group, sq, T]
     scores — fine at decode sizes, never used for training shapes.
@@ -1040,8 +1069,9 @@ def _decode_xla(q, k_cache, v_cache, kv_len, scale, group=1,
         s = s * ks.astype(jnp.float32)[:, None, None, :]
     rows = jnp.arange(sq, dtype=jnp.int32)[None, None, :, None]
     cols = jnp.arange(t, dtype=jnp.int32)[None, None, None, :]
-    valid = cols - rows <= \
-        (kv_len.astype(jnp.int32)[:, None, None, None] - sq)
+    kl = kv_len.astype(jnp.int32)[:, None, None, None]
+    valid = (cols - rows <= kl - sq) if window_causal \
+        else jnp.broadcast_to(cols < kl, s.shape)
     s = jnp.where(valid, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -1057,9 +1087,29 @@ def _decode_xla(q, k_cache, v_cache, kv_len, scale, group=1,
     return out.reshape(bhq, sq, d)
 
 
+def _fold_group(query, hk: int):
+    """[b, sq, hq, d] -> [b * hk, group * sq, d]: the query heads of one
+    kv head stacked into the rows of ONE decode-kernel grid row, so its
+    K/V pages stream once for the group instead of once a query head.
+    Only a row-independent mask (``window_causal=False``) allows it."""
+    b, sq, hq, d = query.shape
+    g = hq // hk
+    return query.reshape(b, sq, hk, g, d).transpose(0, 2, 3, 1, 4) \
+        .reshape(b * hk, g * sq, d)
+
+
+def _unfold_group(out, b: int, sq: int, hq: int):
+    """Inverse of :func:`_fold_group` on the kernel's output."""
+    hk, d = out.shape[0] // b, out.shape[-1]
+    g = hq // hk
+    return out.reshape(b, hk, g, sq, d).transpose(0, 3, 1, 2, 4) \
+        .reshape(b, sq, hq, d)
+
+
 def flash_attention_decode(query, key_cache, value_cache, kv_len,
                            scale=None, block_k=_DECODE_BLOCK_K,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           window_causal=True):
     """Decode-shaped attention: 1..8 new query tokens per row against a
     cached K/V with per-row valid lengths.
 
@@ -1075,7 +1125,10 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
     one layer's slice of a ``generation.KVCache`` (new tokens already
     written). kv_len: [batch] int32 — valid entries per row INCLUDING
     the q_len new positions; query row i attends cache columns
-    ``<= kv_len - q_len + i`` (ragged causal). GQA/MQA (kv heads
+    ``<= kv_len - q_len + i`` (ragged causal), or with
+    ``window_causal=False`` every column ``< kv_len`` (the denoise
+    window of block diffusion: its positions all see each other; the
+    query heads of a kv head then share one grid row). GQA/MQA (kv heads
     dividing q heads) attends by HEAD-INDEX MAPPING: query head h reads
     cache head ``h // (hq//hk)`` directly — the kernel's k/v BlockSpecs
     (and the fallback's grouped einsum) index the hk-sized caches, so
@@ -1107,7 +1160,10 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
     # query rows [b, h] flatten so that row i's kv row is i // group
     # (b*hq = (b*hk)*group, batch-major): the group-size broadcast is
     # pure indexing, never a materialized repeat of the caches
-    qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
+    if window_causal:
+        qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
+    else:
+        qt, group = _fold_group(query, hk), 1
     kt = jnp.swapaxes(key_cache, 1, 2).reshape(b * hk, t, d)
     vt = jnp.swapaxes(value_cache, 1, 2).reshape(b * hk, t, d)
     kst = vst = None
@@ -1118,12 +1174,15 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
     kl = jnp.repeat(kv_len, hk)                       # [B*Hk] int32
     use_pallas = (jax.default_backend() == "tpu"
                   and t % 128 == 0 and d in (64, 128, 256))
+    wc = {} if window_causal else {"window_causal": False}
     if use_pallas:
         out = _decode_pallas(qt, kt, vt, kl, float(scale), block_k,
-                             group=group, k_scale=kst, v_scale=vst)
+                             group=group, k_scale=kst, v_scale=vst, **wc)
     else:
         out = _decode_xla(qt, kt, vt, kl, float(scale), group=group,
-                          ks=kst, vs=vst)
+                          ks=kst, vs=vst, **wc)
+    if not window_causal:
+        return _unfold_group(out, b, sq, hq)
     return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
 
 
@@ -1306,7 +1365,7 @@ def flash_attention_chunk(query, key_cache, value_cache, kv_len,
 
 def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
                          *rest, sq, page_size, num_page_slots, heads_q,
-                         quant=False):
+                         quant=False, window_causal=True):
     # q_ref holds q * (scale * log2e); scores are base-2 logits. The
     # accumulate body is the SAME _decode_accumulate as the dense
     # kernel — only the k-block addressing differs (pages through the
@@ -1335,7 +1394,8 @@ def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
                            j * page_size, kv_len, sq,
                            m_scr, l_scr, acc_scr,
                            ks=ks_ref[0, 0] if quant else None,
-                           vs=vs_ref[0, 0] if quant else None)
+                           vs=vs_ref[0, 0] if quant else None,
+                           window_causal=window_causal)
 
     @pl.when(j == num_page_slots - 1)
     def _finalize():
@@ -1344,7 +1404,7 @@ def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
 
 def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
                          layer, group=1, interpret=None,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, window_causal=True):
     """q: [B*Hq, sq<=8, D] (unscaled), pools [L, n_pages, Hk, page, D]
     (the STACKED pool of every layer, where it lies), page_table [B, P]
     int32, kv_len [B], ``layer`` static. The k/v BlockSpec index maps
@@ -1360,7 +1420,7 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
     b, num_slots = page_table.shape
     hq = bh // b
     quant = k_scale is not None
-    qpad = _DECODE_QPAD
+    qpad = _window_qpad(sq)
     q = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
     if sq < qpad:
         q = jnp.pad(q, ((0, 0), (0, qpad - sq), (0, 0)))
@@ -1400,7 +1460,9 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, sq=sq, page_size=page,
                           num_page_slots=num_slots, heads_q=hq,
-                          quant=quant),
+                          quant=quant,
+                          **({} if window_causal
+                             else {"window_causal": False})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, qpad, d), q.dtype),
         cost_estimate=pl.CostEstimate(
@@ -1416,7 +1478,8 @@ def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
 
 def flash_attention_decode_paged(query, key_pool, value_pool,
                                  page_table, kv_len, layer, scale=None,
-                                 k_scale=None, v_scale=None):
+                                 k_scale=None, v_scale=None,
+                                 window_causal=True):
     """Decode-shaped attention over a PAGED KV cache: 1..8 new query
     tokens per row against K/V stored in a shared page pool addressed
     through per-row page tables.
@@ -1436,7 +1499,9 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
     page_table: [batch, pages_per_row] int32 (entry 0 = the reserved
     null page). kv_len: [batch] int32 — valid entries per row INCLUDING
     the q_len new positions; masking is identical to
-    ``flash_attention_decode``.
+    ``flash_attention_decode``, ``window_causal=False`` (every row sees
+    the whole window; the query heads of a kv head share a grid row, so
+    each page streams once a kv head) included.
 
     TPU with a lane-aligned page size runs the Pallas kernel (page ids
     resolved in the k/v BlockSpec index maps from the scalar-prefetched
@@ -1464,15 +1529,23 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
             "the QuantPagedKVCache sidecars); an unscaled int8 pool "
             "cannot be dequantized")
     kv_len = jnp.asarray(kv_len, jnp.int32)
-    qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
+    wc = {}
+    if window_causal:
+        qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
+    else:
+        qt, group, wc = _fold_group(query, hk), 1, {"window_causal": False}
+
+    def unflatten(out):
+        if not window_causal:
+            return _unfold_group(out, b, sq, hq)
+        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+
     use_pallas = (jax.default_backend() == "tpu"
                   and ps % 128 == 0 and d in (64, 128, 256))
     if use_pallas:
-        out = _paged_decode_pallas(qt, key_pool, value_pool, page_table,
-                                   kv_len, float(scale), layer,
-                                   group=group, k_scale=k_scale,
-                                   v_scale=v_scale)
-        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+        return unflatten(_paged_decode_pallas(
+            qt, key_pool, value_pool, page_table, kv_len, float(scale),
+            layer, group=group, k_scale=k_scale, v_scale=v_scale, **wc))
     # XLA fallback: gather the row's pages ([b, slots, hk, ps, d]) into
     # the logical per-head rows [b * hk, pages_per_row * page_size, d]
     # and run the exact dense decode math — t equals the dense cache's
@@ -1484,21 +1557,27 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
         g = jnp.swapaxes(pool[layer, page_table], 1, 2)
         return g.reshape((b * hk, t) + pool.shape[4:])
 
-    out = _decode_xla(qt, rows(key_pool), rows(value_pool),
-                      jnp.repeat(kv_len, hk), float(scale), group=group,
-                      ks=rows(k_scale) if quant else None,
-                      vs=rows(v_scale) if quant else None)
-    return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+    return unflatten(_decode_xla(
+        qt, rows(key_pool), rows(value_pool), jnp.repeat(kv_len, hk),
+        float(scale), group=group, ks=rows(k_scale) if quant else None,
+        vs=rows(v_scale) if quant else None, **wc))
 
 
 def flash_attention(query, key, value, causal=False, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    block=1):
     """Flash attention over [batch, seq, num_heads, head_dim] inputs
     (framework layout; matches F.scaled_dot_product_attention).
 
     Supports self- and cross-attention (different kv length), causal
     masking, grouped-query attention (kv heads dividing q heads), and
     gradients via the Pallas backward kernels.
+
+    ``block`` B > 1 with ``causal`` widens the mask to BLOCK-causal
+    (query i sees key j iff ``j // B <= i // B``: the prefill mask of
+    generation by diffusion over blocks). B must divide 128, so every
+    kernel block holds whole mask blocks. Forward only: the backward
+    kernels know the plain causal mask alone.
     """
     b, sq, hq, d = query.shape
     hk = key.shape[2]
@@ -1514,6 +1593,17 @@ def flash_attention(query, key, value, causal=False, scale=None,
     vt = jnp.swapaxes(value, 1, 2).reshape(b * hq, sk, d)
     q_pad = (-sq) % 128
     k_pad = (-sk) % 128
+    block = int(block)
+    if block != 1:
+        if not causal or block < 1 or 128 % block or sq != sk \
+                or q_pad:
+            raise NotImplementedError(
+                f"flash_attention: block={block} needs causal=True, a "
+                "block length dividing 128 and equal q/k lengths on the "
+                f"128 grid (got causal={causal}, q={sq}, k={sk})")
+        out, _ = _flash_fwd(qt, kt, vt, float(scale), True, int(block_q),
+                            int(block_k), block=block)
+        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
     if (q_pad or k_pad) and causal:
         # the diagonal offset under asymmetric padding is not worth the
         # complexity; fail clearly so scaled_dot_product_attention's

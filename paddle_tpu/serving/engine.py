@@ -51,6 +51,18 @@ The reference ships serving as a whole layer (paddle/fluid/inference,
   ``gen.spec.*`` at each poll. Greedy outputs stay bitwise-equal to
   sequential decode; drain/eviction semantics are unchanged (partial
   results are accepted-only).
+- **block diffusion on the slots**
+  (``enable_generation(block_diffusion={...})``): the step forwards each
+  lane's current BLOCK of ``block_length`` positions and either unmasks
+  its most confident masked positions (a denoise step) or, once none is
+  masked, advances the cache past it and opens the next (a commit step)
+  — which of the two is per-lane data, so one program serves every
+  phase (``generation/block_diffusion.py``). A lane emits 0, 1 or
+  several tokens a step, out of order inside its block: ``_steps``, the
+  poll, ``Request.n_emitted``, ``stats["emitted_tokens"]``, budgets and
+  page planning all count TOKENS UNMASKED, not steps. The prefill
+  commits the prompt's whole blocks (block-causal) and samples nothing;
+  ``Request.unmask_steps`` comes back with ``tokens``.
 - **SLA observability**: the ``serve.*`` metrics family (requests by
   terminal status, queue-depth gauge, TTFT + per-token latency
   histograms, slot occupancy, cancellations) flows through
@@ -226,6 +238,24 @@ class ServingEngine:
                     "model-free prompt-lookup drafter); draft-model "
                     "speculation is a generate()/Predictor path for now")
             overhang = self._spec.k if self._spec is not None else 0
+            # block diffusion on the slots: the step forwards each lane's
+            # block and unmasks or commits (generation/block_diffusion).
+            # The last block may reach block_length - 1 positions past
+            # the budget: the same slack a verify window needs.
+            from ..generation.block_diffusion import \
+                as_block_diffusion_config
+            self._bd = as_block_diffusion_config(
+                opts.get("block_diffusion"))
+            if self._bd is not None:
+                if self._spec is not None:
+                    raise ValueError(
+                        "block_diffusion and speculative decoding are "
+                        "two step programs: enable one")
+                if opts["do_sample"] or opts["eos_token_id"] is not None:
+                    raise ValueError(
+                        "block_diffusion serves greedy requests to "
+                        "their budget (no sampling, no eos stop)")
+                overhang = self._bd.block_length
 
             max_pos = getattr(getattr(layer, "cfg", None),
                               "max_position_embeddings", None)
@@ -332,6 +362,10 @@ class ServingEngine:
             ct = _opt(prefill_chunk_tokens, "prefill_chunk_tokens",
                       int(env_ct) if env_ct.isdigit() else None)
             self.prefill_chunk_tokens = None
+            if ct is not None and self._bd is not None:
+                raise ValueError(
+                    "chunked prefill attends causally inside a chunk; "
+                    "block_diffusion prefills block-causally, inline")
             if ct is not None:
                 ct = int(ct)
                 if ct < 1:
@@ -372,13 +406,22 @@ class ServingEngine:
             sp = self._sp
             cfg = self._cfg
 
+            bd = self._bd
+            bd_kw = {} if bd is None else {"block_length": bd.block_length}
+
             def prefill_fn(state_vals, ids, plen, key, cfg, cache_len):
                 params = sp.materialize(state_vals)
                 out = functional_call(
                     layer, dict(zip(names, params)), Tensor(ids),
                     use_cache=True, prompt_len=plen, cache_max_len=cache_len,
-                    **cache_kw)
+                    **cache_kw, **bd_kw)
                 logits, cache = _expect_logits_cache(out)
+                if bd is not None:
+                    # the prefill commits the prompt's whole blocks and
+                    # samples nothing (logits predict the token AT a
+                    # position): the head falls out of the program
+                    none = jnp.zeros((ids.shape[0],), jnp.int32)
+                    return none, cache, key, none.astype(bool)
                 logits = _unwrap(logits)[:, -1].astype(jnp.float32)
                 k0, k1 = jax.random.split(key)
                 tok = sample(logits, k0, **_sample_cfg(cfg))
@@ -440,6 +483,53 @@ class ServingEngine:
                     accepted, pin_finished_kv=True)
                 return (tok, cache, k1, finished, steps, budget, out_buf,
                         tok_buf, tok_len, proposed, accepted)
+
+            def block_step_fn(state_vals, cache, finished, steps, budget,
+                              out_buf, ustep_buf, blk, blk_step, out0,
+                              counters, moe_counters, bd):
+                from ..distributed.parallel.moe import routing_stats
+                from ..generation.block_diffusion import apply_block_step
+                params = sp.materialize(state_vals)
+                kv0 = cache.kv_len
+                out = functional_call(layer, dict(zip(names, params)),
+                                      Tensor(blk), cache=cache, **bd_kw)
+                logits, cache = _expect_logits_cache(out)
+                logits = _unwrap(logits).astype(jnp.float32)
+                (cache, finished, steps, out_buf, ustep_buf, blk, blk_step,
+                 out0, counters) = apply_block_step(
+                    logits, bd, cache, kv0, finished, steps, budget,
+                    out_buf, ustep_buf, blk, blk_step, out0, counters)
+                # a dropless expert layer's routing of this forward: rows
+                # computed and the busiest expert's, summed over layers
+                moe = routing_stats(layer)
+                if moe is not None:
+                    moe_counters = moe_counters + jnp.stack(moe) \
+                        .astype(jnp.int32)
+                return (cache, finished, steps, budget, out_buf, ustep_buf,
+                        blk, blk_step, out0, counters, moe_counters)
+
+            paged_engine = self._alloc is not None
+
+            def block_admit_fn(cache, finished, steps, budget, out_buf,
+                               ustep_buf, blk, blk_step, out0, slot,
+                               row_cache, row_budget, first_blk, first_out0,
+                               *paged):
+                # the prefill row holds the prompt's whole blocks; the
+                # lane opens on the first generated block (the prompt's
+                # left-over tokens, then masks). paged = (table_row,
+                # start) on a paged engine
+                if paged_engine:
+                    cache = cache.install_row(row_cache, slot, *paged)
+                else:
+                    cache = cache.copy_row_from(row_cache, 0, slot)
+                return (cache, finished.at[slot].set(row_budget < 1),
+                        steps.at[slot].set(0),
+                        budget.at[slot].set(row_budget),
+                        out_buf.at[slot].set(0),
+                        ustep_buf.at[slot].set(-1),
+                        blk.at[slot].set(first_blk),
+                        blk_step.at[slot].set(0),
+                        out0.at[slot].set(first_out0))
 
             def admit_lanes(tok, finished, steps, budget, out_buf, slot,
                             first_tok, first_fin, row_budget):
@@ -571,6 +661,9 @@ class ServingEngine:
             else:
                 self._admit_fn = paged_admit_fn if spec is None \
                     else paged_spec_admit_fn
+            if bd is not None:
+                self._step_fn, self._admit_fn = block_step_fn, \
+                    block_admit_fn
             # executable persistence: every program warmup() compiles goes
             # through jit.compile_cache (this store, or the process default
             # when None) so a relaunched engine loads instead of recompiling
@@ -587,7 +680,13 @@ class ServingEngine:
             # the _intent tuples are the TPU donation design regardless of
             # the running backend — audit() and memory_plan() gate against
             # THEM, the jit wiring applies them only where donation works
-            if spec is None:
+            if bd is not None:
+                # every lane of the block step round-trips in place; the
+                # admit donates them and the prefill row
+                self._step_donate_intent = tuple(range(1, 12))
+                self._admit_donate_intent = tuple(range(9)) + (10,)
+                step_static = (12,)
+            elif spec is None:
                 self._step_donate_intent = (1, 2, 3, 4, 5, 6, 7)
                 self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7)
                 step_static = (8,)
@@ -733,6 +832,24 @@ class ServingEngine:
                     self._proposed = jax.device_put(np.zeros((), np.int32))
                     self._accepted = jax.device_put(np.zeros((), np.int32))
                     self._spec_seen = (0, 0)   # host mirror for poll deltas
+                if bd is not None:
+                    # block lanes (generation/block_diffusion): the
+                    # current block, its denoise step, its first output
+                    # index; per token the step that unmasked it; and the
+                    # on-device counters the poll drains into
+                    # gen.diffusion.* and moe.*
+                    Bl = bd.block_length
+                    self._ustep_buf = jax.device_put(
+                        np.full((B, cap), -1, np.int8))
+                    self._blk = jax.device_put(
+                        np.full((B, Bl), bd.mask_token_id, np.int32))
+                    self._blk_step = jax.device_put(np.zeros((B,), np.int32))
+                    self._out0 = jax.device_put(np.zeros((B,), np.int32))
+                    self._bd_counters = jax.device_put(
+                        np.zeros((3,), np.int32))
+                    self._moe_counters = jax.device_put(
+                        np.zeros((2,), np.int32))
+                    self._bd_seen = np.zeros((5,), np.int64)
                 # bytes handed to device_put; the transfer is not awaited
                 # here (the first program that reads them waits for it)
                 alloc_sp.set(bytes=sum(
@@ -775,7 +892,8 @@ class ServingEngine:
                               cancelled=0, rejected=0, slots_reused=0,
                               decode_steps=0, prefills=0, prefill_chunks=0,
                               spec_proposed=0, spec_accepted=0,
-                              emitted_tokens=0, polls=0)
+                              emitted_tokens=0, polls=0,
+                              diffusion_forwards=0, diffusion_commits=0)
             # top-K most expensive terminal requests (heap of
             # (total_s, req id, cost dict)) — the /slo cost table
             self._cost_top: List[tuple] = []
@@ -905,6 +1023,7 @@ class ServingEngine:
             program=("serving",) + tuple(cache_key),
             generation=repr(self._cfg),
             speculative=repr(self._spec),
+            block_diffusion=repr(self._bd),
             buckets=tuple(self.buckets),
             shape=(self.max_batch, self.max_len, self.max_new_tokens),
             paged=(None if self._alloc is None else
@@ -949,7 +1068,20 @@ class ServingEngine:
             sds((1,), jnp.int32), sds((2,), jnp.uint32), self._cfg,
             self.max_len))
 
+    def _block_lanes(self):
+        """The block-diffusion step's lane operands, in its order; the
+        first nine are the lanes an admission installs into (the two
+        counter vectors after them are the step's alone)."""
+        return (self._cache, self._finished, self._steps, self._budget,
+                self._out_buf, self._ustep_buf, self._blk, self._blk_step,
+                self._out0, self._bd_counters, self._moe_counters)
+
     def _exe_step(self):
+        if self._bd is not None:
+            return self._compiled(
+                ("block_step",), lambda: self._step_jit.lower(
+                    self._state, *self._block_lanes(), self._bd),
+                donation=self._step_donate)
         if self._spec is None:
             return self._compiled(
                 ("step",), lambda: self._step_jit.lower(
@@ -985,6 +1117,11 @@ class ServingEngine:
             paged = () if self._alloc is None else (
                 jax.ShapeDtypeStruct((self.pages_per_row,), jnp.int32),
                 scalar)
+            if self._bd is not None:
+                return self._admit_jit.lower(
+                    *self._block_lanes()[:9], scalar, row_cache_a, scalar,
+                    jax.ShapeDtypeStruct((self._bd.block_length,),
+                                         jnp.int32), scalar, *paged)
             if self._spec is None:
                 return self._admit_jit.lower(
                     self._cache, self._tok, self._finished, self._steps,
@@ -1077,6 +1214,11 @@ class ServingEngine:
             raise ValueError(
                 f"prompt of {ids.size} tokens exceeds the largest "
                 f"compiled prefill bucket {self.buckets[-1]}")
+        if self._bd is not None and ids.size < self._bd.block_length:
+            raise ValueError(
+                f"prompt of {ids.size} tokens is shorter than one block "
+                f"({self._bd.block_length}): block diffusion commits the "
+                "prompt's whole blocks before it generates")
         params = params if params is not None else RequestParams()
         budget = self.max_new_tokens if params.max_new_tokens is None \
             else int(params.max_new_tokens)
@@ -1325,6 +1467,12 @@ class ServingEngine:
         ids = np.full((1, bucket), self._cfg.pad_value, np.int32)
         ids[0, :req.prompt.size] = req.prompt
         plen = np.array([req.prompt.size], np.int32)
+        if self._bd is not None:
+            # only the prompt's whole blocks are committed; what is left
+            # over opens the first generated block
+            from ..generation.block_diffusion import first_block
+            whole, first_blk, first_out0 = first_block(req.prompt, self._bd)
+            plen[0] = whole
         exe = self._exe_prefill(bucket)
         tok, row_cache, self._key, fin = exe(
             self._state, jnp.asarray(ids), jnp.asarray(plen), self._key)
@@ -1350,7 +1498,17 @@ class ServingEngine:
             table_np[:len(pages)] = pages
             paged_args = (jnp.asarray(table_np),
                           jnp.asarray(plan.shared_len, jnp.int32))
-        if self._spec is None:
+        if self._bd is not None:
+            (self._cache, self._finished, self._steps, self._budget,
+             self._out_buf, self._ustep_buf, self._blk, self._blk_step,
+             self._out0) = admit(
+                # host scalars go in as they are: jnp.asarray(x, int32)
+                # dispatches a conversion program for each, and the
+                # device waits while the host queues them
+                *self._block_lanes()[:9], np.int32(slot), row_cache,
+                np.int32(req.budget), first_blk, np.int32(first_out0),
+                *paged_args)
+        elif self._spec is None:
             (self._cache, self._tok, self._finished, self._steps,
              self._budget, self._out_buf) = admit(
                 self._cache, self._tok, self._finished, self._steps,
@@ -1600,7 +1758,13 @@ class ServingEngine:
     def _dispatch_decode(self):
         exe = self._exe_step()
         with flight_recorder.span("serve.dispatch") as sp:
-            if self._spec is None:
+            if self._bd is not None:
+                (self._cache, self._finished, self._steps, self._budget,
+                 self._out_buf, self._ustep_buf, self._blk,
+                 self._blk_step, self._out0, self._bd_counters,
+                 self._moe_counters) = exe(self._state,
+                                           *self._block_lanes())
+            elif self._spec is None:
                 (self._tok, self._cache, self._key, self._finished,
                  self._steps, self._budget, self._out_buf) = exe(
                     self._state, self._tok, self._cache, self._key,
@@ -1636,6 +1800,12 @@ class ServingEngine:
         int32 scalars — no extra sync cadence)."""
         fin = np.asarray(self._finished)  # lint: host-sync-ok (scheduler poll, every poll_every steps)
         steps = np.asarray(self._steps)  # lint: host-sync-ok (same poll read)
+        if self._bd is not None:
+            # forwards / unmasked / commits, then the experts' rows and
+            # busiest-expert rows: five int32 scalars in the same window
+            return (fin, steps,
+                    np.asarray(self._bd_counters),  # lint: host-sync-ok (same poll read)
+                    np.asarray(self._moe_counters))  # lint: host-sync-ok (same poll read)
         if self._spec is None:
             return fin, steps, 0, 0
         return (fin, steps,
@@ -1653,7 +1823,22 @@ class ServingEngine:
         covered, self._steps_since_poll = self._steps_since_poll, 0
         (fin, steps, prop, acc), t_ns = self._sync("poll",
                                                    self._read_lanes)
-        if self._spec is not None:
+        forwards = commits = None
+        if self._bd is not None:
+            # lifetime int32 counters that may wrap: modular deltas, as
+            # the speculation counters below
+            # (_read_lanes hands the two counter vectors back in the
+            # speculation counters' places)
+            seen = np.concatenate([prop, acc]).astype(np.int64)
+            d = (seen - self._bd_seen) % (1 << 32)
+            self._bd_seen = seen
+            forwards, unmasked, commits, rows, rows_max = (
+                int(x) for x in d)
+            self.stats["diffusion_forwards"] += forwards
+            self.stats["diffusion_commits"] += commits
+            monitor.record_block_diffusion(forwards, unmasked, commits)
+            monitor.record_moe_routing(rows, rows_max)
+        elif self._spec is not None:
             # the device counters are lifetime-monotonic int32 and WRAP
             # on a long-lived engine; per-poll deltas are tiny, so
             # modular subtraction recovers them exactly across the wrap
@@ -1714,8 +1899,10 @@ class ServingEngine:
             emitted += n - req.n_emitted
             req.n_emitted = n
             if fin[i]:
-                row, _ = self._sync(
-                    "row", lambda: np.asarray(self._out_buf[i]))  # lint: host-sync-ok (one row read per completion)
+                row, _ = self._sync("row", lambda: self._read_row(i))
+                if self._bd is not None:
+                    req.unmask_steps = row[1][:n]
+                    row = row[0]
                 self._complete(req, row[:n])
                 completed += 1
                 # freed in place; the next admission overwrites the row
@@ -1733,7 +1920,9 @@ class ServingEngine:
         self.stats["polls"] += 1
         sp.set(steps=covered, emitted=emitted, admitted=admitted,
                completed=completed, evicted=evicted,
-               live=sum(s is not None for s in self._slots))
+               live=sum(s is not None for s in self._slots),
+               **({} if forwards is None
+                  else {"forwards": forwards, "commits": commits}))
         # expire queued requests that can no longer meet their deadline
         with self._qlock:
             for req in list(self._queue):
@@ -1753,6 +1942,16 @@ class ServingEngine:
             # burn rates at most once per ring period (fast path is a
             # float compare — gated in test_overhead_gate)
             slo_mod.tick()
+
+    def _read_row(self, slot: int):
+        """One lane's output row; under block diffusion ``(tokens,
+        unmask steps)``, both in position order."""
+        if self._bd is None:
+            return np.asarray(self._out_buf[slot])  # lint: host-sync-ok (one row read per completion)
+        # both rows behind ONE wait: a second blocking read is a second
+        # round trip during which the device has nothing queued
+        return jax.device_get((self._out_buf[slot],  # lint: host-sync-ok (one row read per completion)
+                               self._ustep_buf[slot]))
 
     def _complete(self, req: Request, toks: np.ndarray):
         eos = self._cfg.eos_token_id
@@ -1791,8 +1990,14 @@ class ServingEngine:
         self._cache, self._finished = exe(
             self._cache, self._finished, jnp.asarray(slot, jnp.int32))
         if n_done:
-            row, _ = self._sync(
-                "row", lambda: np.asarray(self._out_buf[slot]))  # lint: host-sync-ok (partial row on eviction)
+            row, _ = self._sync("row", lambda: self._read_row(slot))
+            if self._bd is not None:
+                # tokens are unmasked out of order inside a block: the
+                # partial result is the prefix before the first position
+                # still masked
+                row, usteps = row
+                n_done = int(np.argmax(np.append(usteps, -1) < 0))
+                req.unmask_steps = usteps[:n_done]
             req.tokens = row[:n_done].astype(np.int32)
             req.n_emitted = n_done
         self._slots[slot] = None  # lint: lock-discipline-ok (eviction runs under the caller's pump lock)
@@ -2188,7 +2393,12 @@ class ServingEngine:
         sds = jax.ShapeDtypeStruct
         state = tuple(sds(tuple(v.shape), v.dtype) for v in self._state)
         key = sds((2,), jnp.uint32)
-        if self._spec is None:
+        if self._bd is not None:
+            decode = plan_memory(
+                self._step_fn, state, *self._block_lanes(), self._bd,
+                static_argnums=(12,), donate=self._step_donate_intent,
+                name="serving.decode")
+        elif self._spec is None:
             decode = plan_memory(
                 self._step_fn, state, self._tok, self._cache, key,
                 self._finished, self._steps, self._budget,
@@ -2225,7 +2435,7 @@ class ServingEngine:
                      f"{self.prefill_chunk_tokens}")
         if decode.arg_bytes is not None:
             weights = decode.arg_bytes[0]
-            kv = decode.arg_bytes[2]
+            kv = decode.arg_bytes[1 if self._bd is not None else 2]
             lanes = sum(decode.arg_bytes) - weights - kv
             resident = sum(decode.arg_bytes)
             predicted = max(decode.peak_bytes,
@@ -2293,7 +2503,18 @@ class ServingEngine:
         # pytree and every lane stay in place across admissions)
         paged_a = () if self._alloc is None else (
             sds((self.pages_per_row,), jnp.int32), scalar)
-        if self._spec is None:
+        if self._bd is not None:
+            reports["decode"] = _audit(
+                self._step_fn, state, *self._block_lanes(), self._bd,
+                static_argnums=(12,), donate=self._step_donate_intent,
+                name=f"{base}.decode", **audit_kw)
+            reports["admit"] = _audit(
+                self._admit_fn, *self._block_lanes()[:9], scalar,
+                row_cache_a, scalar,
+                sds((self._bd.block_length,), jnp.int32), scalar,
+                *paged_a, donate=self._admit_donate_intent,
+                name=f"{base}.admit", **audit_kw)
+        elif self._spec is None:
             reports["decode"] = _audit(
                 self._step_fn, state, self._tok, self._cache, self._key,
                 self._finished, self._steps, self._budget, self._out_buf,

@@ -108,7 +108,10 @@ class Request:
         self.status = RequestStatus.QUEUED
         self.detail = ""
         self.tokens: Optional[np.ndarray] = None   # eos-trimmed on success
-        self.n_emitted = 0                    # raw tokens incl. eos
+        # block diffusion only: for each of ``tokens``, the denoise step
+        # of its block at which it was unmasked (int8); filled with it
+        self.unmask_steps: Optional[np.ndarray] = None
+        self.n_emitted = 0                    # tokens so far, incl. eos
         self.submitted_at = time.monotonic()
         # when the request LEFT THE QUEUE for a slot (the end of its
         # serve.queue_wait), not "its admission succeeded": a request

@@ -179,6 +179,41 @@ class TestPublisherAggregator:
         agg.poll()
         assert agg.fleet_registry()[key].value == 3
 
+    def test_resync_survives_a_reader_slower_than_the_writer(self, store):
+        """The rank publishes twice a poll (a loaded host): the request
+        for a full snapshot stays up until the aggregator has read one,
+        so the full payload cannot be overwritten unread; and health,
+        which is state and no delta, is never held back by the gap."""
+        metrics.enable()
+        c = metrics.counter("t.slow")
+        c.inc()
+        health = {"ready": False}
+        pub = ft.MetricsPublisher(store, period_s=0.2,
+                                  health_fn=lambda: dict(health))
+        agg = ft.FleetAggregator(store, period_s=0.2)
+        pub.publish_now()
+        agg.poll()
+        key = "t.slow{incarnation=0,rank=0,replica=0}"
+        # rounds of two publishes a poll: a gap (the view holds, a full
+        # snapshot is asked for), then both publishes are full and the
+        # newest is applied, whichever the reader finds; and so on: the
+        # view is never more than one round old
+        for total, seen in ((3, 1), (5, 5), (7, 5), (9, 9)):
+            health["ready"] = total > 3
+            c.inc()
+            pub.publish_now()
+            c.inc()
+            pub.publish_now()
+            agg.poll()
+            assert agg.healthz()["ranks"]["0"]["ready"] is (total > 3)
+            assert agg.fleet_registry()[key].value == seen
+        # the request was cleared when answered: deltas again, and a
+        # reader that keeps pace follows them
+        c.inc()
+        assert pub.publish_now()["delta"].get("full") is not True
+        agg.poll()
+        assert agg.fleet_registry()[key].value == 10
+
     def test_new_incarnation_replaces_stream(self, store, monkeypatch):
         metrics.enable()
         metrics.counter("t.inc").inc(7)

@@ -192,6 +192,51 @@ def test_paged_decode(one_chip, as_on_tpu, d, heads, quant):
     assert KERNEL in compiled_text(fn, sds((8, 1, heads, d), BF16), cache)
 
 
+# ---- block diffusion at the published widths of its cell: 32 query
+# heads over 4 kv heads of 128, blocks of 4, 128 experts of 768 top-8
+
+def test_block_causal_prefill_kernel(one_chip, as_on_tpu):
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, BF16,  # noqa: E731
+                                             sharding=one_chip)
+
+    def fn(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, block=4)
+
+    text = compiled_text(fn, sds((1, 1024, 32, 128)), sds((1, 1024, 4, 128)),
+                         sds((1, 1024, 4, 128)))
+    assert KERNEL in text and "flash_fwd" in text
+
+
+def test_full_window_paged_decode_stacks_the_group(one_chip, as_on_tpu):
+    """A block of 4 queries under ``window_causal=False``: the 8 query
+    heads of a kv head share one grid row of 32 query rows, so the
+    kernel's q block is [32, 128] and its grid has batch x 4 rows."""
+    cache, sds = pool_avals(one_chip, 128, 4, False, layers=2, batch=8)
+
+    def fn(q, cache):
+        return fa.flash_attention_decode_paged(
+            q, cache.k, cache.v, cache.page_table, cache.kv_len, 1,
+            window_causal=False)
+
+    text = compiled_text(fn, sds((8, 4, 32, 128), BF16), cache)
+    assert KERNEL in text and "flash_decode_paged" in text
+    assert "bf16[32,32,128]" in text        # [batch x kv heads, 8 x 4, d]
+
+
+def test_grouped_expert_products(one_chip, as_on_tpu):
+    from paddle_tpu.distributed.parallel.moe import dropless_moe
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, BF16,  # noqa: E731
+                                             sharding=one_chip)
+
+    def fn(x, router, gate_up, down):
+        return dropless_moe(x, router, gate_up, down, 8)
+
+    text = compiled_text(fn, sds((512, 2048)), sds((2048, 128)),
+                         sds((128, 2048, 1536)), sds((128, 768, 2048)))
+    # XLA's own grouped-product kernel, once for gate+up and once for down
+    assert text.count("ragged-dot") >= 2 and KERNEL in text
+
+
 # ---- the page pool is read and written where it lies (gpt3-6.7b widths)
 #
 # An 8-layer stacked pool of 32 heads of 128. One layer of it is 0.27 GB
