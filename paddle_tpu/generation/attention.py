@@ -72,9 +72,15 @@ def _cached_attention(q, k, v, cache, layer_idx, decode, causal, attn_mask,
             # STACKED pools (and scale sidecars) and picks the layer in
             # its index map: slicing cache.k[layer_idx] here would copy
             # a whole layer's pool every step
+            import jax.numpy as jnp
+
             from ..kernels.flash_attention import \
                 flash_attention_decode_paged
             scales = (cache.k_scale, cache.v_scale) if quant else ()
+            # a row that holds nothing is idle (the pool's contract: its
+            # write above went to the null page): it attends nothing,
+            # so the kernel walks no page for it
+            mask_len = jnp.where(cache.kv_len > 0, mask_len, 0)
             out = dispatch(
                 "flash_attention_decode_paged",
                 lambda q_, kp, vp, pt, kl, *sc:

@@ -854,19 +854,21 @@ MAX_DECODE_QLEN = _DECODE_QPAD
 _DECODE_BLOCK_K = 512
 
 
-def _decode_scratch(rows, d):
-    """m / l / acc scratch of the decode-family online softmax."""
-    return [pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32)]
+def _decode_scratch(rows, d, lead=()):
+    """m / l / acc scratch of the decode-family online softmax
+    (``lead``: the batch dims of a kernel that attends several heads
+    at once)."""
+    return [pltpu.VMEM(lead + (rows, _LANES), jnp.float32),
+            pltpu.VMEM(lead + (rows, _LANES), jnp.float32),
+            pltpu.VMEM(lead + (rows, d), jnp.float32)]
 
 
-def _scale_row_specs(bk, index_map, lead=(1,)):
+def _scale_row_specs(bk, index_map):
     """BlockSpecs for the k/v per-column scale rows of an int8 cache.
-    The row rides a singleton second-to-last dim ([..., 1, T] blocked
-    (..., 1, bk)): a 1-row block of a taller 2-D array is off the
+    The row rides a singleton second-to-last dim ([B, 1, T] blocked
+    (1, 1, bk)): a 1-row block of a taller 2-D array is off the
     (8, 128) tiling, a block equal to the array's own dim is on it."""
-    spec = pl.BlockSpec(lead + (1, bk), index_map)
+    spec = pl.BlockSpec((1, 1, bk), index_map)
     return [spec, spec]
 
 
@@ -878,22 +880,30 @@ def _decode_init(m_scr, l_scr, acc_scr):
 
 def _decode_accumulate(q, k, v, col_base, kv_len, sq,
                        m_scr, l_scr, acc_scr, ks=None, vs=None,
-                       window_causal=True):
+                       window_causal=True, row_pos=None):
     """One k-block of the decode online softmax — the ONE copy of the
-    accumulate math shared by the dense and paged decode kernels, so
-    their numerics can never silently diverge (the paged/dense
+    accumulate math shared by the dense, chunk and paged decode kernels,
+    so their numerics can never silently diverge (the paged/dense
     bitwise-parity gate depends on them staying locked together).
+
+    Operands are [rows, D] x [bk, D] (dense, chunk), or carry leading
+    batch dims ([heads, rows, D] x [heads, bk, D]: the paged kernel
+    attends all kv heads of a page in one pair of batched products);
+    the scratch has the operands' rank.
 
     Query row i sits at global position kv_len - sq + i: it may attend
     keys at cols <= kv_len - sq + i (ragged causal; ``col_base`` is
     this block's first logical column). Rows past sq-1 are padding;
-    their outputs are sliced off outside. With ``window_causal=False``
+    their outputs are sliced off outside. ``row_pos`` (int32, shaped to
+    broadcast against the score tile) replaces the row's own index as
+    its position i in the window, for a caller that stacks several
+    query heads into the rows. With ``window_causal=False``
     every row attends every valid column (``cols < kv_len``): the
     denoise window of block diffusion, whose positions all see each
-    other — the mask then does not depend on the row, so the caller may
-    stack the query heads of one kv head into the rows.
+    other — the mask then does not depend on the row.
 
-    ``ks``/``vs`` ([1, bk] per-column dequant scales) switch on the
+    ``ks``/``vs`` ([1, bk] per-column dequant scales, or
+    [heads, 1, bk]) switch on the
     int8-cache mode: k/v arrive int8 and the dequant FUSES into the
     score tile instead of ever widening the cache block —
     ``s[i,j] = (q[i] . k_int8[j]) * ks[j]`` (scaling score columns ==
@@ -902,50 +912,53 @@ def _decode_accumulate(q, k, v, col_base, kv_len, sq,
     [qpad, bk] tile as lane-aligned row-vector broadcasts — no
     transposes, no materialized wide K/V, HBM traffic stays int8."""
     quant = ks is not None
+    lead = tuple(range(q.ndim - 2))           # batch dims: the heads
+    last = q.ndim - 1
+    qk_dims = (((last,), (last,)), (lead, lead))
+    pv_dims = (((last,), (last - 1,)), (lead, lead))
     if quant:
         # int8 -> f32 in-register is exact (|v| <= 127); the matmul
         # runs at f32 either way (preferred_element_type)
         s = jax.lax.dot_general(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
+            q.astype(jnp.float32), k.astype(jnp.float32), qk_dims,
             preferred_element_type=jnp.float32)      # [qpad, bk] base-2
         s = s * ks.astype(jnp.float32)               # fused K dequant
     else:
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, qk_dims,
             preferred_element_type=jnp.float32)      # [qpad, bk] base-2
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col_base
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, last) + col_base
     if window_causal:
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, last - 1) \
+            if row_pos is None else row_pos
         s = jnp.where(cols - rows <= kv_len - sq, s, _NEG_INF)
     else:
         s = jnp.where(cols < kv_len, s, _NEG_INF)
-    m_prev = m_scr[:, 0:1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_prev = m_scr[..., 0:1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp2(m_prev - m_new)
     p = jnp.exp2(s - m_new)
-    l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_new = l_scr[..., 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     if quant:
         # fused V dequant: fold the per-column scale into the softmax
         # weights (l stays the sum of the UNSCALED p — v's scale
         # belongs to the values, not the normalizer)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p * vs.astype(jnp.float32), v.astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p * vs.astype(jnp.float32), v.astype(jnp.float32), pv_dims,
             preferred_element_type=jnp.float32)
     else:
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, pv_dims,
             preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
-def _decode_write_out(o_ref, l_scr, acc_scr):
-    l = l_scr[:, 0:1]
+def _decode_result(l_scr, acc_scr, dtype):
+    l = l_scr[..., 0:1]
     l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows → zeros
-    o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    return (acc_scr[...] / l_safe).astype(dtype)
 
 
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq, block_k,
@@ -980,7 +993,7 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq, block_k,
 
     @pl.when(ik == num_kblocks - 1)
     def _finalize():
-        _decode_write_out(o_ref, l_scr, acc_scr)
+        o_ref[0] = _decode_result(l_scr, acc_scr, o_ref.dtype)
 
 
 def _window_qpad(sq: int) -> int:
@@ -1088,22 +1101,24 @@ def _decode_xla(q, k_cache, v_cache, kv_len, scale, group=1,
 
 
 def _fold_group(query, hk: int):
-    """[b, sq, hq, d] -> [b * hk, group * sq, d]: the query heads of one
-    kv head stacked into the rows of ONE decode-kernel grid row, so its
-    K/V pages stream once for the group instead of once a query head.
-    Only a row-independent mask (``window_causal=False``) allows it."""
+    """[b, sq, hq, d] -> [b, hk, group * sq, d]: the query heads of one
+    kv head stacked into the rows of ONE decode-kernel tile (the window
+    position minor), so its K/V pages stream once for the group
+    instead of once a query head. The dense kernel's mask goes by the
+    row, so only a row-independent one (``window_causal=False``) lets
+    it fold; the paged kernel tells a row's position by ``row % sq``."""
     b, sq, hq, d = query.shape
     g = hq // hk
     return query.reshape(b, sq, hk, g, d).transpose(0, 2, 3, 1, 4) \
-        .reshape(b * hk, g * sq, d)
+        .reshape(b, hk, g * sq, d)
 
 
-def _unfold_group(out, b: int, sq: int, hq: int):
+def _unfold_group(out, sq: int):
     """Inverse of :func:`_fold_group` on the kernel's output."""
-    hk, d = out.shape[0] // b, out.shape[-1]
-    g = hq // hk
+    b, hk, rows, d = out.shape
+    g = rows // sq
     return out.reshape(b, hk, g, sq, d).transpose(0, 3, 1, 2, 4) \
-        .reshape(b, sq, hq, d)
+        .reshape(b, sq, hk * g, d)
 
 
 def flash_attention_decode(query, key_cache, value_cache, kv_len,
@@ -1163,7 +1178,7 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
     if window_causal:
         qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
     else:
-        qt, group = _fold_group(query, hk), 1
+        qt, group = _fold_group(query, hk).reshape(b * hk, -1, d), 1
     kt = jnp.swapaxes(key_cache, 1, 2).reshape(b * hk, t, d)
     vt = jnp.swapaxes(value_cache, 1, 2).reshape(b * hk, t, d)
     kst = vst = None
@@ -1182,7 +1197,7 @@ def flash_attention_decode(query, key_cache, value_cache, kv_len,
         out = _decode_xla(qt, kt, vt, kl, float(scale), group=group,
                           ks=kst, vs=vst, **wc)
     if not window_causal:
-        return _unfold_group(out, b, sq, hq)
+        return _unfold_group(out.reshape(b, hk, -1, d), sq)
     return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
 
 
@@ -1240,7 +1255,7 @@ def _chunk_kernel(kvlen_ref, q_ref, k_ref, v_ref, *rest, sq_total,
 
     @pl.when(ik == num_kblocks - 1)
     def _finalize():
-        _decode_write_out(o_ref, l_scr, acc_scr)
+        o_ref[0] = _decode_result(l_scr, acc_scr, o_ref.dtype)
 
 
 def _chunk_pallas(q, k_cache, v_cache, kv_len, scale,
@@ -1352,128 +1367,168 @@ def flash_attention_chunk(query, key_cache, value_cache, kv_len,
 #
 # Decode attention over the block-table paged KV cache
 # (generation.paged_cache.PagedKVCache): K/V live in a shared pool of
-# fixed-size pages and each batch row names its pages in an int32 page
-# table. The kernel extends the dense decode kernel's existing
-# indirection mechanisms — per-row kv_len from SMEM, GQA head mapping
-# in the k/v BlockSpec index maps — one step further: the k-block
-# index map reads the PAGE ID from the scalar-prefetched table, so the
-# pool streams through VMEM page by page and the logical [max_len]
-# row is never materialized. Off-TPU (and for page sizes off the 128
-# grid) an XLA gather fallback materializes the gathered rows with
-# IDENTICAL math to the dense _decode_xla path — the bitwise-parity
-# gate between paged and dense serving rests on that.
+# fixed-size pages and each batch row (a lane) names its pages in an
+# int32 page table. The pool is [layers, pages, kv heads, page, D], so
+# a page of ALL kv heads of a layer is one contiguous block. The kernel
+# walks the VALID pages only: the lanes' ``cdiv(kv_len, page)`` first
+# table entries are listed lane after lane (``_paged_work_list``), the
+# grid has one step per listed page (its bound is the list's length, a
+# value of the call, not of the trace), and a step's K and V block is
+# that page with all its kv heads, resolved from the scalar-prefetched
+# list and table in the index map and copied where it lies by the
+# pipeline, which has the next page (the first page of the next lane
+# too) in flight while this one is attended. The heads are the batch
+# dimension of the two products. Table slots past ``kv_len`` cost
+# nothing: no grid step, no copy; a lane that holds nothing is never
+# visited. Off-TPU (and for page sizes off the 128 grid) an XLA gather
+# fallback materializes the gathered rows with IDENTICAL math to the
+# dense _decode_xla path — the bitwise-parity gate between paged and
+# dense serving rests on that.
 
-def _paged_decode_kernel(table_ref, kvlen_ref, q_ref, k_ref, v_ref,
-                         *rest, sq, page_size, num_page_slots, heads_q,
+_PAGED_COPY_BYTES = 1 << 20     # most K (or V) bytes one step copies
+
+
+def _paged_heads(hk, page, d, itemsize):
+    """kv heads a grid step copies and attends: as many as keep a page's
+    copy within ``_PAGED_COPY_BYTES`` (a divisor of ``hk``; all of them
+    at the widths served today: 32 heads of 128 are 1 MB a page in bf16,
+    4 heads 128 KB, 12 heads of 64 192 KB). K and V blocks are double
+    buffered: 4 MB of VMEM at the limit. More heads than that walk the
+    list once a block of heads."""
+    per_head = page * d * itemsize
+    return max(h for h in range(1, hk + 1) if hk % h == 0
+               and (h == 1 or h * per_head <= _PAGED_COPY_BYTES))
+
+
+def _paged_work_list(kv_len, page, num_slots):
+    """(lane, slot, count): the valid (lane, table slot) pairs in lane
+    order, padded to the static ``lanes * num_slots``, and how many
+    there are. A few small fusions on [lanes * slots, lanes] ints,
+    the same for every layer of a step, so XLA computes them once."""
+    lanes = kv_len.shape[0]
+    pages = jnp.clip(-(-kv_len // page), 0, num_slots)
+    ends = jnp.cumsum(pages)
+    item = jnp.arange(lanes * num_slots, dtype=jnp.int32)
+    lane = jnp.minimum(
+        jnp.sum(item[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        lanes - 1)
+    return lane, item - (ends - pages)[lane], ends[-1]
+
+
+def _paged_decode_kernel(lane_ref, slot_ref, table_ref, kvlen_ref,
+                         q_ref, k_ref, v_ref, *rest, sq, group, page_size,
                          quant=False, window_causal=True):
-    # q_ref holds q * (scale * log2e); scores are base-2 logits. The
+    # q_ref holds q * (scale * log2e) as [heads, rows, D]: the rows of
+    # a kv head are its ``group`` query heads x ``sq`` window positions
+    # (padded to the sublane tile); scores are base-2 logits. The
     # accumulate body is the SAME _decode_accumulate as the dense
-    # kernel — only the k-block addressing differs (pages through the
+    # kernel, run with the kv heads as a batch dimension — only the
+    # k-block addressing differs (the listed pages through the
     # scalar-prefetched table vs contiguous blocks). Quant mode adds
-    # the per-page scale rows ([1, 1, 1, page], same table-resolved
-    # index map as the pools) and fuses the dequant in the shared body.
+    # the page's scale rows ([heads, page], same index map as the
+    # pools) and fuses the dequant in the shared body.
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
-    r = pl.program_id(0)           # flattened [batch, q-head] row
-    j = pl.program_id(1)           # page slot within the row's table
+    item = pl.program_id(1)
+    lane, j = lane_ref[item], slot_ref[item]
 
     @pl.when(j == 0)
     def _init():
         _decode_init(m_scr, l_scr, acc_scr)
 
-    kv_len = kvlen_ref[r // heads_q]   # this row's valid cache length
+    kv_len = kvlen_ref[lane]       # this lane's valid cache length
+    row_pos = None
+    if window_causal and group > 1:
+        # rows are (query head of the group, window position), the
+        # position minor: row r of a kv head sits at position r % sq
+        row_pos = jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (1, q_ref.shape[1], 1), 1), sq) if sq > 1 else 0
+    _decode_accumulate(q_ref[...], k_ref[...], v_ref[...],
+                       j * page_size, kv_len, sq, m_scr, l_scr, acc_scr,
+                       ks=ks_ref[...][:, None] if quant else None,
+                       vs=vs_ref[...][:, None] if quant else None,
+                       window_causal=window_causal, row_pos=row_pos)
 
-    # page slots entirely past the valid prefix skip their compute
-    # (their DMA still runs; the grid is static — same caveat as the
-    # dense decode kernel's k-block skip)
-    @pl.when(j * page_size < kv_len)
-    def _compute():
-        _decode_accumulate(q_ref[0], k_ref[0, 0], v_ref[0, 0],
-                           j * page_size, kv_len, sq,
-                           m_scr, l_scr, acc_scr,
-                           ks=ks_ref[0, 0] if quant else None,
-                           vs=vs_ref[0, 0] if quant else None,
-                           window_causal=window_causal)
-
-    @pl.when(j == num_page_slots - 1)
+    @pl.when((j + 1) * page_size >= kv_len)    # the lane's last page
     def _finalize():
-        _decode_write_out(o_ref, l_scr, acc_scr)
+        o_ref[...] = _decode_result(l_scr, acc_scr, o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pool, v_pool, page_table, kv_len, scale,
-                         layer, group=1, interpret=None,
-                         k_scale=None, v_scale=None, window_causal=True):
-    """q: [B*Hq, sq<=8, D] (unscaled), pools [L, n_pages, Hk, page, D]
-    (the STACKED pool of every layer, where it lies), page_table [B, P]
-    int32, kv_len [B], ``layer`` static. The k/v BlockSpec index maps
-    resolve (layer, page id, kv head) from the grid row and the
-    scalar-prefetched table — page indirection rides the same
-    index-map mechanism as the GQA head mapping, and the layer is one
-    more constant in it, so no layer is ever sliced out of the pool.
-    ``k_scale``/``v_scale`` ([L, n_pages, Hk, page] bf16) run the
-    int8-pool mode: this layer's scale pages resolve through the SAME
-    table index map, dequant fused in the shared accumulate body."""
-    bh, sq, d = q.shape
+                         layer, sq, interpret=None, k_scale=None,
+                         v_scale=None, window_causal=True):
+    """q: [B, Hk, group * sq, D] (unscaled; the query heads of a kv
+    head stacked into its rows, the window position minor), pools
+    [L, n_pages, Hk, page, D] (the STACKED pool of every layer, where
+    it lies), page_table [B, P] int32, kv_len [B], ``layer`` static.
+    The grid is (blocks of kv heads, listed pages); the k/v index map
+    resolves (layer, page id, head block) from the scalar-prefetched
+    work list and table, so no layer is ever sliced out of the pool and
+    nothing past ``kv_len`` is read. ``k_scale``/``v_scale``
+    ([L, n_pages, Hk, page] bf16) run the int8-pool mode: a page's
+    scales of all heads are one block through the SAME index map,
+    dequant fused in the shared accumulate body."""
+    b, hk, rows, d = q.shape
     page = k_pool.shape[3]
-    b, num_slots = page_table.shape
-    hq = bh // b
+    num_slots = page_table.shape[1]
     quant = k_scale is not None
-    qpad = _window_qpad(sq)
+    qpad = _window_qpad(rows)
     q = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
-    if sq < qpad:
-        q = jnp.pad(q, ((0, 0), (0, qpad - sq), (0, 0)))
-    table = page_table.astype(jnp.int32)
+    if rows < qpad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, qpad - rows), (0, 0)))
+    heads = _paged_heads(hk, page, d, k_pool.dtype.itemsize)
     kvl = kv_len.astype(jnp.int32)
+    lane, slot, count = _paged_work_list(kvl, page, num_slots)
 
-    def k_index(r, j, tbl, kl):
-        return (layer, tbl[r // hq, j], (r % hq) // group, 0, 0)
+    def q_index(h, i, lane, slot, tbl, kl):
+        return (lane[i], h, 0, 0)
 
-    # the layer dim is squeezed: the kernel sees the same
-    # (1, 1, page, d) block of one head's page as ever
-    in_specs = [
-        pl.BlockSpec((1, qpad, d), lambda r, j, tbl, kl: (r, 0, 0)),
-        pl.BlockSpec((None, 1, 1, page, d), k_index),
-        pl.BlockSpec((None, 1, 1, page, d), k_index),
-    ]
+    def page_index(h, i, lane, slot, tbl, kl):
+        return (layer, tbl[lane[i] * num_slots + slot[i]], h, 0, 0)
+
+    # layer and page dims are squeezed: the kernel sees a page of
+    # ``heads`` kv heads, [heads, page, d], and their query rows
+    q_spec = pl.BlockSpec((None, heads, qpad, d), q_index)
+    kv_spec = pl.BlockSpec((None, None, heads, page, d), page_index)
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
     if quant:
-        # the [.., 1, page] row the spec needs is a relayout (one
-        # sublane, not Hk): make it of this layer's scales (1/128 of a
-        # layer's values), not of the stacked sidecar
-        in_specs += _scale_row_specs(
-            page, lambda r, j, tbl, kl: k_index(r, j, tbl, kl)[1:],
-            lead=(1, 1))
-        operands += [k_scale[layer][:, :, None],
-                     v_scale[layer][:, :, None]]
+        scale_spec = pl.BlockSpec((None, None, heads, page),
+                                  lambda *a: page_index(*a)[:4])
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh, num_slots),
+        num_scalar_prefetch=4,
+        grid=(hk // heads, count),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qpad, d),
-                               lambda r, j, tbl, kl: (r, 0, 0)),
-        scratch_shapes=_decode_scratch(qpad, d),
+        out_specs=q_spec,
+        scratch_shapes=_decode_scratch(qpad, d, lead=(heads,)),
     )
     kv_bytes = k_pool.dtype.itemsize * num_slots * page * d \
         + (k_scale.dtype.itemsize * num_slots * page if quant else 0)
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sq=sq, page_size=page,
-                          num_page_slots=num_slots, heads_q=hq,
-                          quant=quant,
+        functools.partial(_paged_decode_kernel, sq=sq, group=rows // sq,
+                          page_size=page, quant=quant,
                           **({} if window_causal
                              else {"window_causal": False})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, qpad, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * qpad * num_slots * page * d,
-            bytes_accessed=bh * (qpad * d * q.dtype.itemsize
-                                 + 2 * kv_bytes),
-            transcendentals=bh * qpad * num_slots * page),
+            flops=4 * b * hk * qpad * num_slots * page * d,
+            bytes_accessed=b * hk * (qpad * d * q.dtype.itemsize
+                                     + 2 * kv_bytes),
+            transcendentals=b * hk * qpad * num_slots * page),
         interpret=_interpret() if interpret is None else interpret,
         name="flash_decode_paged",
-    )(table, kvl, *operands)
-    return out[:, :sq]
+    )(lane, slot, page_table.astype(jnp.int32).reshape(-1), kvl, *operands)
+    # a lane that holds nothing is never visited and its block never
+    # written: it attends nothing, so it is zeros
+    out = jnp.where((kvl > 0)[:, None, None, None], out, 0)
+    return out[:, :, :rows]
 
 
 def flash_attention_decode_paged(query, key_pool, value_pool,
@@ -1500,15 +1555,18 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
     null page). kv_len: [batch] int32 — valid entries per row INCLUDING
     the q_len new positions; masking is identical to
     ``flash_attention_decode``, ``window_causal=False`` (every row sees
-    the whole window; the query heads of a kv head share a grid row, so
-    each page streams once a kv head) included.
+    the whole window) included. A row with ``kv_len`` 0 attends nothing
+    and gives zeros.
 
-    TPU with a lane-aligned page size runs the Pallas kernel (page ids
-    resolved in the k/v BlockSpec index maps from the scalar-prefetched
-    table — no gather ever materializes the logical row); other
-    backends gather the row's pages and run the dense XLA decode math
-    bit-for-bit (garbage in pages past kv_len is masked to exact
-    zeros, so paged results are bitwise-equal to the dense cache)."""
+    TPU with a lane-aligned page size runs the Pallas kernel: it walks
+    the rows' valid pages alone (``cdiv(kv_len, page_size)`` table
+    entries a row; entries past them are never read, whatever they
+    name), a page of all kv heads in one copy, the query heads of a kv
+    head stacked in the rows of its tile — no gather ever materializes
+    the logical row. Other backends gather the row's pages and run the
+    dense XLA decode math bit-for-bit (garbage in pages past kv_len is
+    masked to exact zeros, so paged results are bitwise-equal to the
+    dense cache)."""
     b, sq, hq, d = query.shape
     hk, ps = key_pool.shape[2], key_pool.shape[3]
     num_slots = page_table.shape[1]
@@ -1529,23 +1587,26 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
             "the QuantPagedKVCache sidecars); an unscaled int8 pool "
             "cannot be dequantized")
     kv_len = jnp.asarray(kv_len, jnp.int32)
-    wc = {}
-    if window_causal:
-        qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
-    else:
-        qt, group, wc = _fold_group(query, hk), 1, {"window_causal": False}
-
-    def unflatten(out):
-        if not window_causal:
-            return _unfold_group(out, b, sq, hq)
-        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
-
+    wc = {} if window_causal else {"window_causal": False}
     use_pallas = (jax.default_backend() == "tpu"
                   and ps % 128 == 0 and d in (64, 128, 256))
     if use_pallas:
-        return unflatten(_paged_decode_pallas(
-            qt, key_pool, value_pool, page_table, kv_len, float(scale),
-            layer, group=group, k_scale=k_scale, v_scale=v_scale, **wc))
+        # the query heads of a kv head ride the rows of its tile in
+        # both windows: the causal one tells them apart by row % sq
+        return _unfold_group(_paged_decode_pallas(
+            _fold_group(query, hk), key_pool, value_pool, page_table,
+            kv_len, float(scale), layer, sq, k_scale=k_scale,
+            v_scale=v_scale, **wc), sq)
+    if window_causal:
+        qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
+    else:
+        qt, group = _fold_group(query, hk).reshape(b * hk, -1, d), 1
+
+    def unflatten(out):
+        if not window_causal:
+            return _unfold_group(out.reshape(b, hk, -1, d), sq)
+        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+
     # XLA fallback: gather the row's pages ([b, slots, hk, ps, d]) into
     # the logical per-head rows [b * hk, pages_per_row * page_size, d]
     # and run the exact dense decode math — t equals the dense cache's

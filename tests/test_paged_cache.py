@@ -328,31 +328,153 @@ def test_allocator_exhaustion_returns_none():
 # ------------------------------------------------------- paged kernel
 
 
-@pytest.mark.parametrize("layer", [0, 2])
-def test_paged_pallas_kernel_interpret_matches_fallback(layer):
-    """The scalar-prefetch Pallas kernel (interpret mode off-TPU) and
-    the XLA gather fallback agree — the same index-map indirection the
-    GQA head mapping uses, extended to page ids and to the layer of the
-    stacked pool, which both read in place."""
-    from paddle_tpu.kernels.flash_attention import (
-        _paged_decode_pallas, flash_attention_decode_paged)
-    rng = np.random.RandomState(1)
-    L, B, P, ps, Hk, D, Hq, sq = 3, 2, 4, 8, 2, 64, 4, 2
-    pool_k = jnp.asarray(rng.randn(L, 1 + B * P, Hk, ps, D), jnp.float32)
-    pool_v = jnp.asarray(rng.randn(L, 1 + B * P, Hk, ps, D), jnp.float32)
-    table = np.arange(1, 1 + B * P, dtype=np.int32).reshape(B, P)
-    kv_len = np.array([13, 27], np.int32)
-    q = rng.randn(B, sq, Hq, D).astype(np.float32)
-    ref = flash_attention_decode_paged(
-        jnp.asarray(q), pool_k, pool_v, jnp.asarray(table),
-        jnp.asarray(kv_len), layer)
-    qt = jnp.swapaxes(jnp.asarray(q), 1, 2).reshape(B * Hq, sq, D)
-    out = _paged_decode_pallas(qt, pool_k, pool_v, jnp.asarray(table),
-                               jnp.asarray(kv_len), float(D ** -0.5),
-                               layer, group=Hq // Hk, interpret=True)
-    out = jnp.swapaxes(out.reshape(B, Hq, sq, D), 1, 2)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+# (sq, query heads a kv head, window_causal): the causal window of plain
+# decode and of speculative verify, MHA and GQA, and the full window of
+# block diffusion (8 query heads a kv head, a block of 4)
+_KERNEL_MODES = [pytest.param(sq, g, True, id=f"causal-q{sq}-g{g}")
+                 for sq in (1, 4, 8) for g in (1, 4)] \
+    + [pytest.param(4, 8, False, id="full-q4-g8")]
+
+
+def _ragged_pools(rng, quant, sq, window_causal, L=3, Hk=2, D=64, ps=8,
+                  P=4):
+    """A stacked pool and a ragged batch over it: a lane that holds
+    nothing, one with a single token (a causal window: with its window
+    alone), one ending exactly on a page boundary, one filling its whole
+    table, one inside a page. Every table slot past a lane's ``kv_len``
+    names page 1, which holds NaN (the int8 pools: NaN scales): returns
+    that table, and a clean one naming the null page there, for the
+    reference."""
+    kv_len = np.array([0, sq if window_causal else 1, 2 * ps, P * ps, 13],
+                      np.int32)
+    B, poison = len(kv_len), 1
+    shape = (L, 2 + B * P, Hk, ps, D)
+    k, v = rng.randn(*shape), rng.randn(*shape)
+    k[:, 0] = v[:, 0] = 0
+    table = np.full((B, P), poison, np.int32)
+    clean = np.zeros((B, P), np.int32)
+    for b in range(B):
+        n = -(-kv_len[b] // ps)
+        table[b, :n] = clean[b, :n] = 2 + b * P + np.arange(n)
+    if quant:
+        pools, scales = [], {}
+        for name, x in (("k_scale", k), ("v_scale", v)):
+            scale = np.abs(x).max(-1) / 127 + 1e-6
+            pools.append(jnp.asarray(np.round(x / scale[..., None]),
+                                     jnp.int8))
+            scale[:, poison] = np.nan
+            scales[name] = jnp.asarray(scale, jnp.bfloat16)
+    else:
+        k[:, poison] = v[:, poison] = np.nan
+        pools, scales = [jnp.asarray(k, jnp.float32),
+                         jnp.asarray(v, jnp.float32)], {}
+    return pools, scales, table, clean, kv_len
+
+
+def _paged_kernel_interpreted(q, pools, scales, table, kv_len, layer,
+                              window_causal):
+    """The Pallas kernel itself off the TPU: under the TPU interpreter,
+    whose buffers start as NaN, so a block the kernel never wrote, or a
+    page it should not have read, shows."""
+    import importlib
+    from jax.experimental.pallas import tpu as pltpu
+    # the module, not the function of the same name that kernels/ exports
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    b, sq, hq, d = q.shape
+    out = fa._paged_decode_pallas(
+        fa._fold_group(jnp.asarray(q), pools[0].shape[2]), *pools,
+        jnp.asarray(table), jnp.asarray(kv_len), float(d ** -0.5), layer,
+        sq, interpret=pltpu.InterpretParams(),
+        window_causal=window_causal, **scales)
+    return np.asarray(fa._unfold_group(out, sq))
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("sq,group,window_causal", _KERNEL_MODES)
+@pytest.mark.parametrize("quant", [False, True], ids=["wide", "int8"])
+def test_paged_pallas_kernel_interpret_matches_fallback(
+        quant, sq, group, window_causal, layer):
+    """The Pallas kernel (interpreted off-TPU) and the XLA gather
+    fallback agree, in every mode and at every layer of the stacked
+    pool, on a ragged batch: the kernel walks the lanes' valid pages
+    alone (the NaN page the slots past ``kv_len`` name never reaches a
+    result), attends all kv heads of a page at once, and tells the rows
+    of a folded causal window apart. This is the test that holds the
+    paged kernel and the dense decode math to the same numbers off the
+    chip: the fallback is ``_decode_xla`` over the gathered rows."""
+    from paddle_tpu.kernels.flash_attention import \
+        flash_attention_decode_paged
+    rng = np.random.RandomState(7)
+    pools, scales, table, clean, kv_len = _ragged_pools(
+        rng, quant, sq, window_causal)
+    hk, d = pools[0].shape[2], pools[0].shape[-1]
+    q = rng.randn(len(kv_len), sq, hk * group, d).astype(np.float32)
+    ref = np.asarray(flash_attention_decode_paged(
+        jnp.asarray(q), *pools, jnp.asarray(clean), jnp.asarray(kv_len),
+        layer, window_causal=window_causal, **scales))
+    out = _paged_kernel_interpreted(q, pools, scales, table, kv_len, layer,
+                                    window_causal)
+    live = kv_len > 0
+    assert np.isfinite(out).all()              # no NaN page, no stale block
+    assert not out[~live].any()                # nothing held: zeros
+    np.testing.assert_allclose(out[live], ref[live], atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["wide", "int8"])
+def test_paged_pallas_kernel_head_blocks_and_an_empty_batch(quant,
+                                                            monkeypatch):
+    """Where a page of all kv heads is too large a copy the kernel walks
+    the list once a block of heads (forced here: one head a step); a
+    batch that holds nothing runs no grid step at all and is zeros."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    flash_attention_decode_paged = fa.flash_attention_decode_paged
+    rng = np.random.RandomState(8)
+    pools, scales, table, clean, kv_len = _ragged_pools(rng, quant, 4, True)
+    q = rng.randn(len(kv_len), 4, 4, 64).astype(np.float32)
+    ref = np.asarray(flash_attention_decode_paged(
+        jnp.asarray(q), *pools, jnp.asarray(clean), jnp.asarray(kv_len), 1,
+        **scales))
+    monkeypatch.setattr(fa, "_PAGED_COPY_BYTES", 1)
+    assert fa._paged_heads(2, 8, 64, 4) == 1
+    out = _paged_kernel_interpreted(q, pools, scales, table, kv_len, 1,
+                                    True)
+    np.testing.assert_allclose(out[1:], ref[1:], atol=5e-5, rtol=5e-5)
+    out = _paged_kernel_interpreted(q, pools, scales, table,
+                                    np.zeros_like(kv_len), 1, True)
+    assert not out.any()
+
+
+def test_cached_attention_walks_no_page_for_an_idle_row(monkeypatch):
+    """A row of the pool that holds nothing is idle (its write goes to
+    the null page): the paged branch of the cached attention hands the
+    kernel ``kv_len`` 0 for it, not the window's length, so the kernel
+    walks no page for a lane that waits for its next request."""
+    import importlib
+
+    import paddle_tpu as paddle
+    from paddle_tpu.generation.attention import cached_attention
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    seen = {}
+    real = fa.flash_attention_decode_paged
+
+    def spy(q, kp, vp, table, kv_len, layer, **kw):
+        seen["kv_len"] = np.asarray(kv_len)
+        return real(q, kp, vp, table, kv_len, layer, **kw)
+
+    monkeypatch.setattr(fa, "flash_attention_decode_paged", spy)
+    cache = PagedKVCache.create(1, 3, n_pages=5, page_size=8,
+                                pages_per_row=2, num_heads=2, head_dim=8)
+    cache = PagedKVCache(cache.k, cache.v,
+                         jnp.asarray([[1, 2], [0, 0], [3, 0]], jnp.int32),
+                         jnp.asarray([9, 0, 4], jnp.int32))
+    rng = np.random.RandomState(3)
+    q, k, v = (paddle.to_tensor(rng.randn(3, 1, 2, 8).astype(np.float32))
+               for _ in range(3))
+    out, cache = cached_attention(q, k, v, cache, 0, decode=True,
+                                  causal=True)
+    assert seen["kv_len"].tolist() == [10, 0, 5]
+    assert np.isfinite(out.numpy()).all()
 
 
 # ------------------------------------------- THE bitwise-parity gate

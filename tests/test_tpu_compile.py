@@ -192,6 +192,42 @@ def test_paged_decode(one_chip, as_on_tpu, d, heads, quant):
     assert KERNEL in compiled_text(fn, sds((8, 1, heads, d), BF16), cache)
 
 
+# the serve cells' own shapes: (lanes, window, query / kv heads of 128,
+# pages, layers, window_causal), 16 table slots a lane
+CELL_SHAPES = [
+    pytest.param(64, 1, 32, 32, 512, 8, True, id="gpt3l8"),
+    pytest.param(128, 4, 32, 4, 1024, 6, False, id="sdar-l6"),
+]
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("lanes,sq,hq,hk,pages,layers,window_causal",
+                         CELL_SHAPES)
+def test_paged_decode_at_the_cells_shapes(one_chip, lanes, sq, hq, hk, pages,
+                                          layers, window_causal, quant,
+                                          as_on_tpu):
+    """The walk over the valid pages at the size the cells run it: a
+    page of all kv heads a step (1 MB of K at 32 heads, 128 KB at 4),
+    the last layer of the stacked pool, and no temporary worth naming
+    beside it (the work list and the padded query rows)."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    pool = sds((layers, pages, hk, PAGE, 128), jnp.int8 if quant else BF16)
+    scale = sds((layers, pages, hk, PAGE), BF16)
+
+    def fn(q, k, v, table, kv_len, *sc):
+        return fa.flash_attention_decode_paged(
+            q, k, v, table, kv_len, layers - 1, window_causal=window_causal,
+            **(dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}))
+
+    compiled = jax.jit(fn).lower(
+        sds((lanes, sq, hq, 128), BF16), pool, pool,
+        sds((lanes, 16), jnp.int32), sds((lanes,), jnp.int32),
+        *([scale, scale] if quant else [])).compile()
+    assert "flash_decode_paged" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
 # ---- block diffusion at the published widths of its cell: 32 query
 # heads over 4 kv heads of 128, blocks of 4, 128 experts of 768 top-8
 
@@ -209,8 +245,8 @@ def test_block_causal_prefill_kernel(one_chip, as_on_tpu):
 
 def test_full_window_paged_decode_stacks_the_group(one_chip, as_on_tpu):
     """A block of 4 queries under ``window_causal=False``: the 8 query
-    heads of a kv head share one grid row of 32 query rows, so the
-    kernel's q block is [32, 128] and its grid has batch x 4 rows."""
+    heads of a kv head share the 32 query rows of its tile, and a
+    lane's 4 kv heads one block: the kernel's q is [batch, 4, 32, 128]."""
     cache, sds = pool_avals(one_chip, 128, 4, False, layers=2, batch=8)
 
     def fn(q, cache):
@@ -220,7 +256,7 @@ def test_full_window_paged_decode_stacks_the_group(one_chip, as_on_tpu):
 
     text = compiled_text(fn, sds((8, 4, 32, 128), BF16), cache)
     assert KERNEL in text and "flash_decode_paged" in text
-    assert "bf16[32,32,128]" in text        # [batch x kv heads, 8 x 4, d]
+    assert "bf16[8,4,32,128]" in text       # [batch, kv heads, 8 x 4, d]
 
 
 def test_grouped_expert_products(one_chip, as_on_tpu):
