@@ -972,18 +972,14 @@ def bench_serve(dev, on_tpu):
         slo_row["within_objective"] = bool(m <= ttft_slo.objective)
     # ISSUE-14 "mem" sub-dict: the engine's static HBM plan vs one
     # measured slot-decode dispatch, plus the KV pool bytes. Runs LAST:
-    # on TPU the direct _step_jit dispatch donates the engine's state
+    # on TPU the direct step dispatch donates the engine's state
     # buffers, so the engine serves no traffic after this.
-    from paddle_tpu import analysis
     mp = engine.memory_plan()
-    margs = (engine._state, engine._tok, engine._cache, engine._key,
-             engine._finished, engine._steps, engine._budget,
-             engine._out_buf)
-    mem_plan = analysis.plan_memory(
-        engine._step_fn, *margs, engine._cfg, static_argnums=(8,),
-        donate=engine._step_donate, name="bench.serve.decode")
+    step = engine._programs[("step",)]
+    margs = (engine._state, engine._cache, engine._lanes, engine._key)
+    mem_plan = step.plan("bench.serve.decode")
     mem = _mem_sub_dict(
-        mem_plan, lambda: engine._step_jit(*margs, engine._cfg),
+        mem_plan, lambda: step.jit(*margs, engine._cfg),
         margs, mp["kv_cache_bytes"])
     mem["predicted_engine_peak_bytes"] = mp["predicted_peak_bytes"]
     return {

@@ -264,6 +264,14 @@ class KVCache:
             self.v.at[:, dst_row].set(src.v[:, src_row].astype(self.v.dtype)),
             self.kv_len.at[dst_row].set(src.kv_len[src_row]))
 
+    def install_row(self, src: "KVCache", slot) -> "KVCache":
+        """Slot admission as the serving engine's admit program spells
+        it for every cache: the batch-1 prefill cache ``src`` becomes
+        row ``slot``. A page pool's ``install_row`` takes, after these,
+        where the row goes (its page table and first written position);
+        a dense row needs neither."""
+        return self.copy_row_from(src, 0, slot)
+
     def with_kv_len(self, kv_len) -> "KVCache":
         kv_len = jnp.asarray(_raw(kv_len), jnp.int32)
         if kv_len.ndim == 0:

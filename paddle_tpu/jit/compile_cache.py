@@ -290,6 +290,7 @@ _SALT_DIRS = (
 _SALT_FILES = (
     "jit/api.py",
     "serving/engine.py",
+    "serving/programs.py",
     "inference/precision.py",
     "core/tensor.py",
     # pieces a model file assembles from outside its own module

@@ -14,9 +14,10 @@ The reference ships serving as a whole layer (paddle/fluid/inference,
 - **admission = prefill into a slot.** A queued request is prefilled
   alone (batch 1) at its prompt's shape bucket (the
   ``Config.enable_generation`` bucket set), then a jitted admit program
-  copies the row cache into the freed slot (``KVCache.copy_row_from``)
-  and resets that slot's token/finished/step/budget lanes. One admit
-  program serves every slot — the slot index is data, not shape.
+  installs the row cache into the freed slot (the cache's
+  ``install_row``) and resets that slot's token/finished/step/budget
+  lanes. One admit program serves every slot — the slot index is data,
+  not shape.
 - **paged KV cache + shared-prefix reuse**
   (``enable_serving(paged=True)``): the dense ring is replaced by a
   pool of fixed-size pages addressed through per-slot int32 page
@@ -68,6 +69,11 @@ The reference ships serving as a whole layer (paddle/fluid/inference,
   histograms, slot occupancy, cancellations) flows through
   ``core.monitor`` into the existing Perfetto export.
 
+This module is the SCHEDULER. The device programs, their operands and
+donation, and the three step modes (plain decode, n-gram speculation,
+block diffusion) are ``serving/programs.py``: the engine holds one
+program table and one mode object, and calls them.
+
 Host syncs are confined to the scheduler's poll cadence (every
 ``poll_every`` decode steps: two [batch]-lane reads), one small sync
 per admission (the TTFT measurement point), and one row read per
@@ -98,12 +104,32 @@ import numpy as np
 from ..core import flight_recorder, monitor
 from ..core import slo as slo_mod
 from ..core.tensor import Tensor
-from ..generation.api import (GenerationConfig, _expect_logits_cache,
-                              _round_up, _sample_cfg)
-from ..generation.sampling import sample
+from ..generation.api import GenerationConfig, _round_up
+from . import programs
 from .request import (QueueFull, Request, RequestParams, RequestStatus)
 
 __all__ = ["ServingEngine"]
+
+
+def _env_int(var: str, site: str) -> Optional[int]:
+    """The non-negative integer ``var`` holds, None when unset. Garbage
+    must not silently enable, resize or re-shape anything: it is
+    swallowed observably (``site``) and reads as unset."""
+    raw = os.environ.get(var, "").strip()
+    if raw.isdigit():
+        return int(raw)
+    if raw:
+        monitor.record_swallowed(site, ValueError(f"{var}={raw!r}"))
+    return None
+
+
+def _host_zeros(avals):
+    """Zeroed device buffers of ``avals``, built on the HOST and
+    ``device_put``: ``jnp.zeros`` would compile one tiny broadcast
+    program per shape — dead weight on the warm-relaunch path the
+    executable store keeps otherwise XLA-free."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.device_put(np.zeros(a.shape, a.dtype)), avals)
 
 
 class ServingEngine:
@@ -144,7 +170,6 @@ class ServingEngine:
                  hbm_budget=None):
         with flight_recorder.span("setup.engine_init"):
             from ..inference.precision import serving_params
-            from ..jit.api import _unwrap, functional_call
 
             layer = getattr(config, "_layer", None)
             if layer is None:
@@ -177,19 +202,15 @@ class ServingEngine:
             # recorder (and through it the Perfetto export). Default 8 keeps
             # the per-poll span cost off the steady-state p95; 0 turns the
             # sampled segments off.
-            env_sample = os.environ.get("PADDLE_TRACE_SAMPLE", "").strip()
-            if env_sample.lower() in ("off", "false", "no"):
-                env_default = 0
-            elif env_sample.isdigit():
-                env_default = int(env_sample)
+            if os.environ.get("PADDLE_TRACE_SAMPLE", "").strip().lower() \
+                    in ("off", "false", "no"):
+                env_sample = 0
             else:
-                if env_sample:  # garbage must not silently re-enable
-                    monitor.record_swallowed(
-                        "serving.trace_sample",
-                        ValueError(f"PADDLE_TRACE_SAMPLE={env_sample!r}"))
-                env_default = 8
-            self.trace_sample = int(_opt(trace_sample, "trace_sample",
-                                         env_default))
+                env_sample = _env_int("PADDLE_TRACE_SAMPLE",
+                                      "serving.trace_sample")
+            self.trace_sample = int(_opt(
+                trace_sample, "trace_sample",
+                8 if env_sample is None else env_sample))
 
             # precision: the same serving cast/quant pass the Predictor's
             # run() path audits (int8-compute may swap modules; int4
@@ -294,15 +315,10 @@ class ServingEngine:
             self._overhang = overhang
             if bool(_opt(paged, "paged", False)):  # lint: host-sync-ok (config coercion)
                 from ..generation.paged_cache import PageAllocator
-                env_ps = os.environ.get("PADDLE_KV_PAGE_SIZE", "").strip()
-                if env_ps and not env_ps.isdigit():
-                    # garbage must not silently re-shape the cache (same
-                    # contract as PADDLE_TRACE_SAMPLE above)
-                    monitor.record_swallowed(
-                        "serving.kv_page_size",
-                        ValueError(f"PADDLE_KV_PAGE_SIZE={env_ps!r}"))
+                env_ps = _env_int("PADDLE_KV_PAGE_SIZE",
+                                  "serving.kv_page_size")
                 ps = int(_opt(kv_page_size, "kv_page_size",
-                              int(env_ps) if env_ps.isdigit() else 128))
+                              128 if env_ps is None else env_ps))
                 if ps < 1 or self.max_len % ps:
                     raise ValueError(
                         f"kv_page_size {ps} must divide the cache length "
@@ -351,16 +367,9 @@ class ServingEngine:
             # (kwarg > enable_serving > PADDLE_PREFILL_CHUNK_TOKENS); paged
             # engines require page alignment so every completed chunk ends
             # on a page boundary the span-install can commit.
-            env_ct = os.environ.get("PADDLE_PREFILL_CHUNK_TOKENS",
-                                    "").strip()
-            if env_ct and not env_ct.isdigit():
-                # garbage must not silently enable/resize chunking (same
-                # contract as PADDLE_TRACE_SAMPLE / PADDLE_KV_PAGE_SIZE)
-                monitor.record_swallowed(
-                    "serving.prefill_chunk_tokens",
-                    ValueError(f"PADDLE_PREFILL_CHUNK_TOKENS={env_ct!r}"))
             ct = _opt(prefill_chunk_tokens, "prefill_chunk_tokens",
-                      int(env_ct) if env_ct.isdigit() else None)
+                      _env_int("PADDLE_PREFILL_CHUNK_TOKENS",
+                               "serving.prefill_chunk_tokens"))
             self.prefill_chunk_tokens = None
             if ct is not None and self._bd is not None:
                 raise ValueError(
@@ -402,341 +411,27 @@ class ServingEngine:
             self._chunking = None   # the (single) in-flight chunked
             #                         admission's scheduler state
 
-            names = self._sp.names
-            sp = self._sp
-            cfg = self._cfg
-
-            bd = self._bd
-            bd_kw = {} if bd is None else {"block_length": bd.block_length}
-
-            def prefill_fn(state_vals, ids, plen, key, cfg, cache_len):
-                params = sp.materialize(state_vals)
-                out = functional_call(
-                    layer, dict(zip(names, params)), Tensor(ids),
-                    use_cache=True, prompt_len=plen, cache_max_len=cache_len,
-                    **cache_kw, **bd_kw)
-                logits, cache = _expect_logits_cache(out)
-                if bd is not None:
-                    # the prefill commits the prompt's whole blocks and
-                    # samples nothing (logits predict the token AT a
-                    # position): the head falls out of the program
-                    none = jnp.zeros((ids.shape[0],), jnp.int32)
-                    return none, cache, key, none.astype(bool)
-                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-                k0, k1 = jax.random.split(key)
-                tok = sample(logits, k0, **_sample_cfg(cfg))
-                if cfg.eos_token_id is not None:
-                    finished = tok == cfg.eos_token_id
-                else:
-                    finished = jnp.zeros(tok.shape, bool)
-                return tok, cache, k1, finished
-
-            def step_fn(state_vals, tok, cache, key, finished, steps,
-                        budget, out_buf, cfg):
-                params = sp.materialize(state_vals)
-                out = functional_call(layer, dict(zip(names, params)),
-                                      Tensor(tok[:, None]), cache=cache)
-                logits, cache = _expect_logits_cache(out)
-                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-                k0, k1 = jax.random.split(key)
-                nxt = sample(logits, k0, **_sample_cfg(cfg))
-                rows = jnp.arange(nxt.shape[0], dtype=jnp.int32)
-                idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
-                # finished lanes are masked: their buffer entry and step
-                # count stay frozen while the fixed-batch step runs on
-                out_buf = out_buf.at[rows, idx].set(
-                    jnp.where(finished, out_buf[rows, idx], nxt))
-                steps = steps + jnp.where(finished, 0, 1)
-                if cfg.eos_token_id is not None:
-                    finished = finished | (nxt == cfg.eos_token_id)
-                finished = finished | (steps >= budget)
-                # dead slots: pin kv_len at 0 so an idle lane neither wraps
-                # the ring nor walks the position table out of range while
-                # it waits for its next admission
-                cache = cache.with_kv_len(
-                    jnp.where(finished, 0, cache.kv_len))
-                return nxt, cache, k1, finished, steps, budget, out_buf
-
-            spec = self._spec
-
-            def spec_step_fn(state_vals, tok, cache, key, finished, steps,
-                             budget, out_buf, tok_buf, tok_len, proposed,
-                             accepted, cfg, spec):
-                from ..generation.speculative import (apply_verify_window,
-                                                      ngram_propose)
-                params = sp.materialize(state_vals)
-                draft = ngram_propose(tok_buf, tok_len, k=spec.k,
-                                      n=spec.ngram)
-                window = jnp.concatenate([tok[:, None], draft], axis=1)
-                out = functional_call(layer, dict(zip(names, params)),
-                                      Tensor(window), cache=cache)
-                logits, cache = _expect_logits_cache(out)
-                logits = _unwrap(logits).astype(jnp.float32)
-                k0, k1 = jax.random.split(key)
-                # the shared acceptance/clamp/scatter/rollback core —
-                # pin_finished_kv is the engine's idle-lane contract (a
-                # parked slot must never wrap the ring)
-                (tok, cache, finished, steps, out_buf, tok_buf, tok_len,
-                 proposed, accepted) = apply_verify_window(
-                    logits, draft, k0, cfg, spec, tok, cache, finished,
-                    steps, budget, out_buf, tok_buf, tok_len, proposed,
-                    accepted, pin_finished_kv=True)
-                return (tok, cache, k1, finished, steps, budget, out_buf,
-                        tok_buf, tok_len, proposed, accepted)
-
-            def block_step_fn(state_vals, cache, finished, steps, budget,
-                              out_buf, ustep_buf, blk, blk_step, out0,
-                              counters, moe_counters, bd):
-                from ..distributed.parallel.moe import routing_stats
-                from ..generation.block_diffusion import apply_block_step
-                params = sp.materialize(state_vals)
-                kv0 = cache.kv_len
-                out = functional_call(layer, dict(zip(names, params)),
-                                      Tensor(blk), cache=cache, **bd_kw)
-                logits, cache = _expect_logits_cache(out)
-                logits = _unwrap(logits).astype(jnp.float32)
-                (cache, finished, steps, out_buf, ustep_buf, blk, blk_step,
-                 out0, counters) = apply_block_step(
-                    logits, bd, cache, kv0, finished, steps, budget,
-                    out_buf, ustep_buf, blk, blk_step, out0, counters)
-                # a dropless expert layer's routing of this forward: rows
-                # computed and the busiest expert's, summed over layers
-                moe = routing_stats(layer)
-                if moe is not None:
-                    moe_counters = moe_counters + jnp.stack(moe) \
-                        .astype(jnp.int32)
-                return (cache, finished, steps, budget, out_buf, ustep_buf,
-                        blk, blk_step, out0, counters, moe_counters)
-
-            paged_engine = self._alloc is not None
-
-            def block_admit_fn(cache, finished, steps, budget, out_buf,
-                               ustep_buf, blk, blk_step, out0, slot,
-                               row_cache, row_budget, first_blk, first_out0,
-                               *paged):
-                # the prefill row holds the prompt's whole blocks; the
-                # lane opens on the first generated block (the prompt's
-                # left-over tokens, then masks). paged = (table_row,
-                # start) on a paged engine
-                if paged_engine:
-                    cache = cache.install_row(row_cache, slot, *paged)
-                else:
-                    cache = cache.copy_row_from(row_cache, 0, slot)
-                return (cache, finished.at[slot].set(row_budget < 1),
-                        steps.at[slot].set(0),
-                        budget.at[slot].set(row_budget),
-                        out_buf.at[slot].set(0),
-                        ustep_buf.at[slot].set(-1),
-                        blk.at[slot].set(first_blk),
-                        blk_step.at[slot].set(0),
-                        out0.at[slot].set(first_out0))
-
-            def admit_lanes(tok, finished, steps, budget, out_buf, slot,
-                            first_tok, first_fin, row_budget):
-                # the slot's scheduler lanes after admission (shared by the
-                # dense and paged admit programs — only the cache install
-                # differs); the slot index is a traced scalar, so one
-                # program serves every slot
-                tok = tok.at[slot].set(first_tok[0])
-                steps = steps.at[slot].set(1)
-                budget = budget.at[slot].set(row_budget)
-                row = jnp.zeros((out_buf.shape[1],), jnp.int32) \
-                    .at[0].set(first_tok[0])
-                out_buf = out_buf.at[slot].set(row)
-                finished = finished.at[slot].set(
-                    first_fin[0] | (row_budget <= 1))
-                return tok, finished, steps, budget, out_buf
-
-            def drafter_lanes(tok_buf, tok_len, slot, ids_row, row_plen,
-                              first_tok):
-                # the drafter's token history: the padded prompt row with
-                # the prefill token appended — the n-gram drafter reads
-                # prompt AND emitted tokens from one buffer
-                row = ids_row.at[row_plen].set(first_tok[0])
-                return (tok_buf.at[slot].set(row),
-                        tok_len.at[slot].set(row_plen + 1))
-
-            def admit_fn(cache, tok, finished, steps, budget, out_buf,
-                         slot, row_cache, first_tok, first_fin, row_budget):
-                # install the batch-1 prefill row into the freed slot
-                cache = cache.copy_row_from(row_cache, 0, slot)
-                (tok, finished, steps, budget, out_buf) = admit_lanes(
-                    tok, finished, steps, budget, out_buf, slot, first_tok,
-                    first_fin, row_budget)
-                return cache, tok, finished, steps, budget, out_buf
-
-            def spec_admit_fn(cache, tok, finished, steps, budget, out_buf,
-                              slot, row_cache, first_tok, first_fin,
-                              row_budget, tok_buf, tok_len, ids_row,
-                              row_plen):
-                (cache, tok, finished, steps, budget, out_buf) = admit_fn(
-                    cache, tok, finished, steps, budget, out_buf, slot,
-                    row_cache, first_tok, first_fin, row_budget)
-                tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
-                                                 ids_row, row_plen,
-                                                 first_tok)
-                return (cache, tok, finished, steps, budget, out_buf,
-                        tok_buf, tok_len)
-
-            def free_fn(cache, finished, slot):
-                return cache.reset_rows(slot), finished.at[slot].set(True)
-
-            def paged_admit_fn(cache, tok, finished, steps, budget, out_buf,
-                               slot, row_cache, first_tok, first_fin,
-                               row_budget, table_row, start):
-                # paged admission: scatter the batch-1 prefill row into the
-                # pool pages named by table_row, SKIPPING the shared-prefix
-                # positions below start (they already hold this content —
-                # prefill once, reference-count many). slot/table/start are
-                # traced data — one program, every slot, every layout.
-                cache = cache.install_row(row_cache, slot, table_row, start)
-                (tok, finished, steps, budget, out_buf) = admit_lanes(
-                    tok, finished, steps, budget, out_buf, slot, first_tok,
-                    first_fin, row_budget)
-                return cache, tok, finished, steps, budget, out_buf
-
-            def paged_spec_admit_fn(cache, tok, finished, steps, budget,
-                                    out_buf, slot, row_cache, first_tok,
-                                    first_fin, row_budget, table_row, start,
-                                    tok_buf, tok_len, ids_row, row_plen):
-                (cache, tok, finished, steps, budget, out_buf) = \
-                    paged_admit_fn(cache, tok, finished, steps, budget,
-                                   out_buf, slot, row_cache, first_tok,
-                                   first_fin, row_budget, table_row, start)
-                tok_buf, tok_len = drafter_lanes(tok_buf, tok_len, slot,
-                                                 ids_row, row_plen,
-                                                 first_tok)
-                return (cache, tok, finished, steps, budget, out_buf,
-                        tok_buf, tok_len)
-
-            def chunk_fn(state_vals, ids, row_cache):
-                # one NON-final prefill chunk: decode-mode forward over the
-                # persistent batch-1 side cache — attention masks at
-                # kv_len + C with queries at offset kv_len (the chunk
-                # kernel), the C new KV rows land in the ring, kv_len
-                # advances. The logits are never read, so the LM head DCEs
-                # out of the compiled program.
-                params = sp.materialize(state_vals)
-                out = functional_call(layer, dict(zip(names, params)),
-                                      Tensor(ids), cache=row_cache)
-                _, row_cache = _expect_logits_cache(out)
-                return row_cache
-
-            def chunk_final_fn(state_vals, ids, plen, key, row_cache, cfg):
-                # the FINAL (pad-to-C) chunk: kv_len clamps to the true
-                # prompt length, the hidden state is gathered at the last
-                # REAL position, and the first token is sampled — the same
-                # (tok, row_cache, key, finished) contract as prefill_fn,
-                # so the EXISTING admit program installs the result
-                # unchanged.
-                params = sp.materialize(state_vals)
-                out = functional_call(layer, dict(zip(names, params)),
-                                      Tensor(ids), cache=row_cache,
-                                      prompt_len=plen)
-                logits, row_cache = _expect_logits_cache(out)
-                logits = _unwrap(logits)[:, -1].astype(jnp.float32)
-                k0, k1 = jax.random.split(key)
-                tok = sample(logits, k0, **_sample_cfg(cfg))
-                if cfg.eos_token_id is not None:
-                    finished = tok == cfg.eos_token_id
-                else:
-                    finished = jnp.zeros(tok.shape, bool)
-                return tok, row_cache, k1, finished
-
-            def install_span_fn(cache, row_cache, table_row, start):
-                # commit one completed chunk's positions into the pool
-                # pages the admission planner already committed — table row
-                # and kv_len stay untouched, so the slot's lane stays
-                # parked (null-page routed) until the final admit installs
-                # the pointers atomically
-                return cache.install_span(row_cache, table_row, start)
-
-            self._prefill_fn, self._free_fn = prefill_fn, free_fn
-            self._chunk_fn = chunk_fn
-            self._chunk_final_fn = chunk_final_fn
-            self._span_fn = install_span_fn
-            self._step_fn = step_fn if spec is None else spec_step_fn
-            if self._alloc is None:
-                self._admit_fn = admit_fn if spec is None else spec_admit_fn
+            # the step mode (serving/programs.py) is chosen here, once:
+            # the scheduler below calls it and never asks which it is
+            if self._bd is not None:
+                self._mode = programs.BlockDiffusion(self._bd)
+            elif self._spec is not None:
+                self._mode = programs.Speculative(self._cfg, self._spec,
+                                                  self.max_len)
             else:
-                self._admit_fn = paged_admit_fn if spec is None \
-                    else paged_spec_admit_fn
-            if bd is not None:
-                self._step_fn, self._admit_fn = block_step_fn, \
-                    block_admit_fn
+                self._mode = programs.Decode(self._cfg)
+            net = programs.Network(layer, self._sp, cache_kw,
+                                   self._mode.forward_kw)
             # executable persistence: every program warmup() compiles goes
             # through jit.compile_cache (this store, or the process default
             # when None) so a relaunched engine loads instead of recompiling
             self._exe_store = executable_store
-            # donate on TPU only (CPU/GPU donation is a no-op that warns
-            # once per program); audit() gates the TPU donation INTENT
-            tpu = jax.default_backend() == "tpu"
-            # the spec admit's drafter tok_buf/tok_len positions — shifted
-            # by the paged table_row/start args. ONE definition shared by
-            # the jit donation wiring below and audit(): the audited
-            # donation set must be the set the production program uses.
-            self._spec_admit_buf = (11, 12) if self._alloc is None \
-                else (13, 14)
-            # the _intent tuples are the TPU donation design regardless of
-            # the running backend — audit() and memory_plan() gate against
-            # THEM, the jit wiring applies them only where donation works
-            if bd is not None:
-                # every lane of the block step round-trips in place; the
-                # admit donates them and the prefill row
-                self._step_donate_intent = tuple(range(1, 12))
-                self._admit_donate_intent = tuple(range(9)) + (10,)
-                step_static = (12,)
-            elif spec is None:
-                self._step_donate_intent = (1, 2, 3, 4, 5, 6, 7)
-                self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7)
-                step_static = (8,)
-            else:
-                # the spec step additionally carries the drafter's token
-                # buffer/length lanes and the proposed/accepted counters —
-                # all donated (in-place across polls, audited as intent).
-                # The paged spec admit's tok_buf/tok_len sit two positions
-                # later (after table_row/start).
-                self._step_donate_intent = tuple(range(1, 12))
-                self._admit_donate_intent = (0, 1, 2, 3, 4, 5, 7) \
-                    + self._spec_admit_buf
-                step_static = (12, 13)
-            self._free_donate_intent = (0, 1)
-            # chunk programs: the side cache is the ONLY donated operand —
-            # it round-trips in place every chunk (chunk_fn arg 2,
-            # chunk_final_fn arg 4); the span install donates the pool
-            # pytree (arg 0) but NOT the source side cache, which the next
-            # chunk still reads
-            self._chunk_donate_intent = (2,)
-            self._chunk_final_donate_intent = (4,)
-            self._span_donate_intent = (0,)
-            self._step_donate = self._step_donate_intent if tpu else ()
-            self._admit_donate = self._admit_donate_intent if tpu else ()
-            self._free_donate = self._free_donate_intent if tpu else ()
-            self._chunk_donate = self._chunk_donate_intent if tpu else ()
-            self._chunk_final_donate = \
-                self._chunk_final_donate_intent if tpu else ()
-            self._span_donate = self._span_donate_intent if tpu else ()
-            self._prefill_jit = jax.jit(prefill_fn, static_argnums=(4, 5))
-            self._step_jit = jax.jit(
-                self._step_fn, static_argnums=step_static,
-                donate_argnums=self._step_donate)
-            self._admit_jit = jax.jit(
-                self._admit_fn, donate_argnums=self._admit_donate)
-            self._free_jit = jax.jit(
-                free_fn, donate_argnums=self._free_donate)
-            self._chunk_jit = jax.jit(
-                chunk_fn, donate_argnums=self._chunk_donate)
-            self._chunk_final_jit = jax.jit(
-                chunk_final_fn, static_argnums=(5,),
-                donate_argnums=self._chunk_final_donate)
-            self._span_jit = jax.jit(
-                install_span_fn, donate_argnums=self._span_donate)
 
             # ------------------------------------------------------- state
             self._state = tuple(self._sp.vals)
             if seed is not None:
                 self._key = jax.random.PRNGKey(int(seed))
-            elif cfg.do_sample:
+            elif self._cfg.do_sample:
                 from ..core import random as _random
                 self._key = _random.next_key()
             else:
@@ -745,48 +440,33 @@ class ServingEngine:
             B, cap = self.max_batch, self.max_new_tokens
             sds = jax.ShapeDtypeStruct
             cache_aval = jax.eval_shape(
-                lambda s, i, p, k: prefill_fn(s, i, p, k, cfg, self.max_len),
+                lambda s, i, p, k: programs.prefill_fn(
+                    net, s, i, p, k, self._cfg, self.max_len),
                 self._state, sds((B, buckets[0]), jnp.int32),
                 sds((B,), jnp.int32), self._key)[1]
             with flight_recorder.span("setup.cache_alloc") as alloc_sp:
-                # lane/cache buffers built on HOST and device_put: jnp.zeros
-                # would compile one tiny broadcast program per shape — dead
-                # weight on the warm-relaunch path the executable store keeps
-                # otherwise XLA-free
                 quant = getattr(cache_aval, "k_scale", None) is not None
-                if self._alloc is None:
-                    self._cache = jax.tree_util.tree_map(
-                        lambda a: jax.device_put(np.zeros(a.shape, a.dtype)),
-                        cache_aval)
-                elif quant:
-                    # paged int8 pool: value pages + their bf16 scale pages
-                    # (the scales live IN the page, so prefix sharing / COW /
-                    # reclaim carry them for free) + the saturation counter
-                    from ..generation.paged_cache import QuantPagedKVCache
-                    L, _, _, H, D = cache_aval.k.shape
-                    pool = (L, self._alloc.n_pages, H, self.page_size, D)
-                    spool = pool[:-1]
-                    self._cache = QuantPagedKVCache(
-                        jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
-                        jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
-                        jax.device_put(np.zeros((B, self.pages_per_row),
-                                                np.int32)),
-                        jax.device_put(np.zeros((B,), np.int32)),
-                        jax.device_put(np.zeros(spool, jnp.bfloat16)),
-                        jax.device_put(np.zeros(spool, jnp.bfloat16)),
-                        jax.device_put(np.zeros((), np.int32)))
-                else:
+                if self._alloc is not None:
                     # paged pool: layers/heads/head_dim/dtype from the dense
-                    # prefill aval, rows replaced by the page pool + tables
-                    from ..generation.paged_cache import PagedKVCache
+                    # prefill aval, rows replaced by the page pool + tables;
+                    # int8: value pages + their bf16 scale pages (the scales
+                    # live IN the page, so prefix sharing / COW / reclaim
+                    # carry them for free) + the saturation counter
+                    from ..generation.paged_cache import (PagedKVCache,
+                                                          QuantPagedKVCache)
                     L, _, _, H, D = cache_aval.k.shape
                     pool = (L, self._alloc.n_pages, H, self.page_size, D)
-                    self._cache = PagedKVCache(
-                        jax.device_put(np.zeros(pool, cache_aval.k.dtype)),
-                        jax.device_put(np.zeros(pool, cache_aval.v.dtype)),
-                        jax.device_put(np.zeros((B, self.pages_per_row),
-                                                np.int32)),
-                        jax.device_put(np.zeros((B,), np.int32)))
+                    pages = (sds(pool, cache_aval.k.dtype),
+                             sds(pool, cache_aval.v.dtype),
+                             sds((B, self.pages_per_row), np.int32),
+                             sds((B,), np.int32))
+                    if quant:
+                        scales = sds(pool[:-1], jnp.bfloat16)
+                        cache_aval = QuantPagedKVCache(
+                            *pages, scales, scales, sds((), np.int32))
+                    else:
+                        cache_aval = PagedKVCache(*pages)
+                self._cache = _host_zeros(cache_aval)
                 # the low-bit accounting satellites: the kv_dtype info gauge
                 # (what this engine serves — the router reads it beside the
                 # capacity numbers) and, when quantized, the HBM bytes the int8
@@ -815,61 +495,36 @@ class ServingEngine:
                     # the dtype the cache ACTUALLY carries, from its own aval
                     self._kv_dtype_label = np.dtype(cache_aval.k.dtype).name
                 monitor.record_kv_dtype(self._kv_dtype_label)
-                self._tok = jax.device_put(np.zeros((B,), np.int32))
-                self._finished = jax.device_put(np.ones((B,), bool))  # empty
-                #                                       slots are masked
-                self._steps = jax.device_put(np.zeros((B,), np.int32))
-                self._budget = jax.device_put(np.zeros((B,), np.int32))
-                self._out_buf = jax.device_put(np.zeros((B, cap), np.int32))
-                if spec is not None:
-                    # drafter lanes: per-slot token history (prompt +
-                    # emitted, the n-gram lookup corpus) and the on-device
-                    # proposed/accepted counters the poll drains into
-                    # gen.spec.*
-                    self._tok_buf = jax.device_put(
-                        np.zeros((B, self.max_len), np.int32))
-                    self._tok_len = jax.device_put(np.zeros((B,), np.int32))
-                    self._proposed = jax.device_put(np.zeros((), np.int32))
-                    self._accepted = jax.device_put(np.zeros((), np.int32))
-                    self._spec_seen = (0, 0)   # host mirror for poll deltas
-                if bd is not None:
-                    # block lanes (generation/block_diffusion): the
-                    # current block, its denoise step, its first output
-                    # index; per token the step that unmasked it; and the
-                    # on-device counters the poll drains into
-                    # gen.diffusion.* and moe.*
-                    Bl = bd.block_length
-                    self._ustep_buf = jax.device_put(
-                        np.full((B, cap), -1, np.int8))
-                    self._blk = jax.device_put(
-                        np.full((B, Bl), bd.mask_token_id, np.int32))
-                    self._blk_step = jax.device_put(np.zeros((B,), np.int32))
-                    self._out0 = jax.device_put(np.zeros((B,), np.int32))
-                    self._bd_counters = jax.device_put(
-                        np.zeros((3,), np.int32))
-                    self._moe_counters = jax.device_put(
-                        np.zeros((2,), np.int32))
-                    self._bd_seen = np.zeros((5,), np.int64)
+                self._lanes = jax.device_put(self._mode.lanes(B, cap))
                 # bytes handed to device_put; the transfer is not awaited
                 # here (the first program that reads them waits for it)
                 alloc_sp.set(bytes=sum(
-                    int(a.nbytes) for a in jax.tree_util.tree_leaves((
-                        self._cache, self._tok, self._finished, self._steps,
-                        self._budget, self._out_buf))))
+                    int(a.nbytes) for a in jax.tree_util.tree_leaves(
+                        (self._cache, self._lanes))))
+
+            self._programs = programs.program_table(
+                net, self._mode, self._cfg, state=self._state,
+                cache=self._cache, lanes=self._lanes, key=self._key,
+                buckets=buckets, max_len=self.max_len,
+                chunk=(self.prefill_chunk_tokens if self._chunk_enabled
+                       else None),
+                pages_per_row=(None if self._alloc is None
+                               else self.pages_per_row))
 
             # chunked prefill's persistent batch-1 SIDE cache: the same
             # dense row cache a bucket prefill would produce (max_len long,
             # quant sidecars included), host-built zeros like the lanes
             # above. Rebuilt from host zeros after every chunked admission
-            # or abort — the admit program DONATES it (arg 7), so the
+            # or abort — the admit program DONATES it, so the
             # buffer is gone either way, and the rebuild is also what
             # resets kv_len to 0 and zeroes the quant clip counter between
             # requests.
             self._row_cache = None
             self._row_cache_aval = None
             if self._chunk_enabled:
-                self._row_cache_aval = self._row_avals()[1]
-                self._row_cache = self._fresh_row_cache()
+                self._row_cache_aval = self._programs[
+                    ("chunk", self.prefill_chunk_tokens)].args["row_cache"]
+                self._row_cache = _host_zeros(self._row_cache_aval)
 
             self._slots: List[Optional[Request]] = [None] * B
             self._slot_used = [False] * B          # reuse detection
@@ -1038,14 +693,15 @@ class ServingEngine:
             operands=compile_cache.aval_signature(self._state))
         return sig
 
-    def _compiled(self, cache_key, build, donation=()):
-        """One warm program: ``build`` returns the LOWERED module; the
+    def _compiled(self, cache_key):
+        """One warm program of the table (``serving/programs.py``): the
         executable comes from the store on a warm relaunch (manifest
-        hit: zero traces, zero XLA compiles) or a fresh ``compile()``
-        that is then persisted."""
+        hit: zero traces, zero XLA compiles) or from the record's
+        lowering and a fresh ``compile()`` that is then persisted."""
         exe = self._exes.get(cache_key)
         if exe is None:
             from ..jit import compile_cache
+            prog = self._programs[cache_key]
             self._ensure_eval()
             # a compile after warmup means live traffic hit a shape no
             # executable was built for — exactly what the steady-state
@@ -1054,128 +710,25 @@ class ServingEngine:
                 "first" if not self._warm else "new_shape")
             label = "serving." + ".".join(str(p) for p in cache_key)
             exe = compile_cache.build_or_load(
-                self._program_signature(cache_key), build,
+                self._program_signature(cache_key), prog.lower,
                 store=self._exe_store,
-                extra=dict(kind=label, donation=donation), label=label)
+                extra=dict(kind=label, donation=prog.donation),
+                label=label)
             self._exes[cache_key] = exe
         return exe
 
     def _exe_prefill(self, bucket: int):
-        sds = jax.ShapeDtypeStruct
-        return self._compiled(("prefill", bucket),
-                              lambda: self._prefill_jit.lower(
-            self._state, sds((1, bucket), jnp.int32),
-            sds((1,), jnp.int32), sds((2,), jnp.uint32), self._cfg,
-            self.max_len))
+        return self._compiled(("prefill", bucket))
 
-    def _block_lanes(self):
-        """The block-diffusion step's lane operands, in its order; the
-        first nine are the lanes an admission installs into (the two
-        counter vectors after them are the step's alone)."""
-        return (self._cache, self._finished, self._steps, self._budget,
-                self._out_buf, self._ustep_buf, self._blk, self._blk_step,
-                self._out0, self._bd_counters, self._moe_counters)
+    @property
+    def _finished(self):
+        return self._lanes.finished
 
-    def _exe_step(self):
-        if self._bd is not None:
-            return self._compiled(
-                ("block_step",), lambda: self._step_jit.lower(
-                    self._state, *self._block_lanes(), self._bd),
-                donation=self._step_donate)
-        if self._spec is None:
-            return self._compiled(
-                ("step",), lambda: self._step_jit.lower(
-                    self._state, self._tok, self._cache, self._key,
-                    self._finished, self._steps, self._budget,
-                    self._out_buf, self._cfg),
-                donation=self._step_donate)
-        return self._compiled(
-            ("spec_step",), lambda: self._step_jit.lower(
-                self._state, self._tok, self._cache, self._key,
-                self._finished, self._steps, self._budget,
-                self._out_buf, self._tok_buf, self._tok_len,
-                self._proposed, self._accepted, self._cfg, self._spec),
-            donation=self._step_donate)
-
-    def _row_avals(self):
-        """(tok, row_cache, finished) avals of a batch-1 prefill — the
-        admit program's source operands (bucket-independent: every
-        bucket prefills into a cache of the shared max_len)."""
-        tok_a, row_cache_a, _, fin_a = jax.eval_shape(
-            lambda s, i, p, k: self._prefill_fn(s, i, p, k, self._cfg,
-                                                self.max_len),
-            self._state,
-            jax.ShapeDtypeStruct((1, self.buckets[0]), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32))
-        return tok_a, row_cache_a, fin_a
-
-    def _exe_admit(self):
-        def build():
-            tok_a, row_cache_a, fin_a = self._row_avals()
-            scalar = jnp.asarray(0, jnp.int32)
-            paged = () if self._alloc is None else (
-                jax.ShapeDtypeStruct((self.pages_per_row,), jnp.int32),
-                scalar)
-            if self._bd is not None:
-                return self._admit_jit.lower(
-                    *self._block_lanes()[:9], scalar, row_cache_a, scalar,
-                    jax.ShapeDtypeStruct((self._bd.block_length,),
-                                         jnp.int32), scalar, *paged)
-            if self._spec is None:
-                return self._admit_jit.lower(
-                    self._cache, self._tok, self._finished, self._steps,
-                    self._budget, self._out_buf, scalar, row_cache_a,
-                    tok_a, fin_a, scalar, *paged)
-            ids_row = jax.ShapeDtypeStruct((self.max_len,), jnp.int32)
-            return self._admit_jit.lower(
-                self._cache, self._tok, self._finished, self._steps,
-                self._budget, self._out_buf, scalar, row_cache_a,
-                tok_a, fin_a, scalar, *paged, self._tok_buf,
-                self._tok_len, ids_row, scalar)
-        return self._compiled(("admit",), build,
-                              donation=self._admit_donate)
-
-    def _exe_free(self):
-        return self._compiled(("free",), lambda: self._free_jit.lower(
-            self._cache, self._finished,
-            jnp.asarray(0, jnp.int32)), donation=self._free_donate)
-
-    def _fresh_row_cache(self):
-        """A zeroed chunk side cache (host-built + device_put, same
-        XLA-free contract as the lane buffers): kv_len 0, quant clips
-        0 — the state every chunked admission must start from."""
-        return jax.tree_util.tree_map(
-            lambda a: jax.device_put(np.zeros(a.shape, a.dtype)),
-            self._row_cache_aval)
-
-    def _exe_chunk(self):
-        sds = jax.ShapeDtypeStruct
-        C = self.prefill_chunk_tokens
-        return self._compiled(
-            ("chunk", C), lambda: self._chunk_jit.lower(
-                self._state, sds((1, C), jnp.int32),
-                self._row_cache_aval),
-            donation=self._chunk_donate)
-
-    def _exe_chunk_final(self):
-        sds = jax.ShapeDtypeStruct
-        C = self.prefill_chunk_tokens
-        return self._compiled(
-            ("chunk_final", C), lambda: self._chunk_final_jit.lower(
-                self._state, sds((1, C), jnp.int32),
-                sds((1,), jnp.int32), sds((2,), jnp.uint32),
-                self._row_cache_aval, self._cfg),
-            donation=self._chunk_final_donate)
-
-    def _exe_span(self):
-        sds = jax.ShapeDtypeStruct
-        return self._compiled(
-            ("install_span",), lambda: self._span_jit.lower(
-                self._cache, self._row_cache_aval,
-                sds((self.pages_per_row,), jnp.int32),
-                jnp.asarray(0, jnp.int32)),
-            donation=self._span_donate)
+    @property
+    def _steps(self):
+        """Tokens each lane has made so far ([batch] int32, on the
+        device): the lanes' own, under the name readers know."""
+        return self._lanes.steps
 
     def warmup(self):
         """Compile every program the scheduler can dispatch (one
@@ -1185,16 +738,8 @@ class ServingEngine:
         warm executables; any later compile is recorded as
         ``jit.compile{cause=new_shape}``."""
         with flight_recorder.span("setup.warmup"):
-            for b in self.buckets:
-                self._exe_prefill(b)
-            self._exe_step()
-            self._exe_admit()
-            self._exe_free()
-            if self._chunk_enabled:
-                self._exe_chunk()
-                self._exe_chunk_final()
-                if self._alloc is not None:
-                    self._exe_span()
+            for key in self._programs:
+                self._compiled(key)
         self._warm = True
         return self
 
@@ -1214,11 +759,7 @@ class ServingEngine:
             raise ValueError(
                 f"prompt of {ids.size} tokens exceeds the largest "
                 f"compiled prefill bucket {self.buckets[-1]}")
-        if self._bd is not None and ids.size < self._bd.block_length:
-            raise ValueError(
-                f"prompt of {ids.size} tokens is shorter than one block "
-                f"({self._bd.block_length}): block diffusion commits the "
-                "prompt's whole blocks before it generates")
+        self._mode.check_prompt(ids)
         params = params if params is not None else RequestParams()
         budget = self.max_new_tokens if params.max_new_tokens is None \
             else int(params.max_new_tokens)
@@ -1466,13 +1007,7 @@ class ServingEngine:
                      t_admit_ns: int):
         ids = np.full((1, bucket), self._cfg.pad_value, np.int32)
         ids[0, :req.prompt.size] = req.prompt
-        plen = np.array([req.prompt.size], np.int32)
-        if self._bd is not None:
-            # only the prompt's whole blocks are committed; what is left
-            # over opens the first generated block
-            from ..generation.block_diffusion import first_block
-            whole, first_blk, first_out0 = first_block(req.prompt, self._bd)
-            plen[0] = whole
+        plen = np.array([self._mode.prefill_len(req.prompt)], np.int32)
         exe = self._exe_prefill(bucket)
         tok, row_cache, self._key, fin = exe(
             self._state, jnp.asarray(ids), jnp.asarray(plen), self._key)
@@ -1483,53 +1018,40 @@ class ServingEngine:
         self._first_token(req, t_admit_ns, t1, bucket)
         monitor.record_generation(prefill_steps=1)
         self.stats["prefills"] += 1
-        admit = self._exe_admit()
-        paged_args, pages, plan = (), None, None
+        self._install(req, slot, row_cache, tok, fin)
+        # the blocking prefill sync above must not be attributed to
+        # per-token decode latency: restart the poll window so the next
+        # dispatch re-anchors it (same artifact class as idle gaps)
+        self._window_steps = 0
+
+    def _table_row(self, pages) -> np.ndarray:
+        """A row's page table: its pages in position order; unused
+        table slots stay 0 (the null page)."""
+        table_row = np.zeros((self.pages_per_row,), np.int32)
+        table_row[:len(pages)] = pages
+        return table_row
+
+    def _install(self, req: Request, slot: int, row_cache, tok, fin,
+                 installed: int = 0):
+        """The admit program, for an inline and a chunked admission
+        alike: the prefill's row into ``slot``'s cache row, the mode's
+        first values into its lanes; then the request is RUNNING.
+        ``installed``: positions the chunk spans have already put in
+        their pages."""
+        where, pages, plan = (), None, None
         if self._alloc is not None:
-            # the row's page table: shared prefix pages first (position
-            # order), then the freshly allocated private ones; unused
-            # table slots stay 0 (the null page). start marks the first
-            # position the install actually writes — everything below
-            # it is referenced shared content. The pending entry is
-            # popped only AFTER the install lands: an admit failure
-            # must leave it for _release_pending to roll back.
+            # shared prefix pages first, then the freshly allocated
+            # private ones. start marks the first position the install
+            # actually writes — everything below it is referenced shared
+            # content or an installed span. The pending entry is popped
+            # only AFTER the install lands: an admit failure must leave
+            # it for _release_pending to roll back.
             pages, plan = self._pending_pages[req.id]
-            table_np = np.zeros((self.pages_per_row,), np.int32)
-            table_np[:len(pages)] = pages
-            paged_args = (jnp.asarray(table_np),
-                          jnp.asarray(plan.shared_len, jnp.int32))
-        if self._bd is not None:
-            (self._cache, self._finished, self._steps, self._budget,
-             self._out_buf, self._ustep_buf, self._blk, self._blk_step,
-             self._out0) = admit(
-                # host scalars go in as they are: jnp.asarray(x, int32)
-                # dispatches a conversion program for each, and the
-                # device waits while the host queues them
-                *self._block_lanes()[:9], np.int32(slot), row_cache,
-                np.int32(req.budget), first_blk, np.int32(first_out0),
-                *paged_args)
-        elif self._spec is None:
-            (self._cache, self._tok, self._finished, self._steps,
-             self._budget, self._out_buf) = admit(
-                self._cache, self._tok, self._finished, self._steps,
-                self._budget, self._out_buf,
-                jnp.asarray(slot, jnp.int32), row_cache, tok, fin,
-                jnp.asarray(req.budget, jnp.int32), *paged_args)
-        else:
-            # the drafter's corpus row: the full-width padded prompt
-            # (the admit program appends the prefill token in-trace)
-            ids_row = np.full((self.max_len,), self._cfg.pad_value,
-                              np.int32)
-            ids_row[:req.prompt.size] = req.prompt
-            (self._cache, self._tok, self._finished, self._steps,
-             self._budget, self._out_buf, self._tok_buf,
-             self._tok_len) = admit(
-                self._cache, self._tok, self._finished, self._steps,
-                self._budget, self._out_buf,
-                jnp.asarray(slot, jnp.int32), row_cache, tok, fin,
-                jnp.asarray(req.budget, jnp.int32), *paged_args,
-                self._tok_buf, self._tok_len, jnp.asarray(ids_row),
-                jnp.asarray(req.prompt.size, jnp.int32))
+            where = (self._table_row(pages),
+                     np.int32(max(int(plan.shared_len), installed)))
+        self._cache, self._lanes = self._compiled(("admit",))(
+            self._cache, self._lanes, np.int32(slot), row_cache,
+            self._mode.first(req.prompt, req.budget, tok, fin), *where)
         if self._alloc is not None:
             # the row now references its pages; register the prompt's
             # full pages so later identical prefixes hit them
@@ -1544,10 +1066,6 @@ class ServingEngine:
         self.stats["admitted"] += 1
         monitor.record_serve_slot_occupancy(
             sum(s is not None for s in self._slots) / self.max_batch)
-        # the blocking prefill sync above must not be attributed to
-        # per-token decode latency: restart the poll window so the next
-        # dispatch re-anchors it (same artifact class as idle gaps)
-        self._window_steps = 0
 
     # ------------------------------------------------- chunked prefill
     def _begin_chunked(self, req: Request, slot: int):
@@ -1623,7 +1141,7 @@ class ServingEngine:
         C = self.prefill_chunk_tokens
         t_ns = flight_recorder.now_ns() if req.traced else 0
         ids = jnp.asarray(st["ids"][:, k * C:(k + 1) * C])
-        self._row_cache = self._exe_chunk()(
+        self._row_cache = self._compiled(("chunk", C))(
             self._state, ids, self._row_cache)
         if self._alloc is not None:
             # commit the chunk's positions into the planned pages now —
@@ -1632,18 +1150,21 @@ class ServingEngine:
             # waits for the final admit
             start = max(k * C, st["shared"])
             if (k + 1) * C > start:
-                pages = self._pending_pages[req.id][0]
-                table_np = np.zeros((self.pages_per_row,), np.int32)
-                table_np[:len(pages)] = pages
-                self._cache = self._exe_span()(
+                self._cache = self._compiled(("install_span",))(
                     self._cache, self._row_cache,
-                    jnp.asarray(table_np),
-                    jnp.asarray(start, jnp.int32))
+                    self._table_row(self._pending_pages[req.id][0]),
+                    np.int32(start))
         # the chunk must LAND before the host moves on: the sync point
         # is what bounds how long a chunk can monopolize the device
         # between decode dispatches
         _, t1 = self._sync("chunk", self._row_cache.kv_len.block_until_ready)
         st["next"] = k + 1
+        self._chunk_landed(st, k, t_ns, t1)
+
+    def _chunk_landed(self, st: dict, k: int, t_ns: int, t1: int):
+        """Chunk ``k`` (the final one too) is in the side cache: count
+        and record it."""
+        req, slot, C = st["req"], st["slot"], self.prefill_chunk_tokens
         tokens = min(C, st["plen"] - k * C)
         self.stats["prefill_chunks"] += 1
         monitor.record_prefill_chunk(tokens)
@@ -1664,74 +1185,28 @@ class ServingEngine:
         t_ns = flight_recorder.now_ns() if req.traced else 0
         ids = jnp.asarray(st["ids"][:, k * C:(k + 1) * C])
         plen = jnp.asarray(np.array([st["plen"]], np.int32))
-        tok, row_cache, self._key, fin = self._exe_chunk_final()(
+        tok, row_cache, self._key, fin = self._compiled(
+            ("chunk_final", C))(
             self._state, ids, plen, self._key, self._row_cache)
         self._row_cache = row_cache
         # TTFT measurement point — same contract as inline admission
         _, t1 = self._sync("prefill", tok.block_until_ready)
         self._first_token(req, st["t_ns"], t1, st["n"] * C)
-        tokens = st["plen"] - k * C
-        self.stats["prefill_chunks"] += 1
-        monitor.record_prefill_chunk(tokens)
+        self._chunk_landed(st, k, t_ns, t1)
         monitor.record_prefill_interleave(
             st["decode_steps"] / st["n"])
-        if flight_recorder.enabled:
-            flight_recorder.record(
-                "serve.prefill_chunk", req=req.id, slot=slot, chunk=k,
-                start=k * C, tokens=tokens, remaining=0)
-        req.span("prefill_chunk", t_ns, t1, chunk=k, slot=slot,
-                 tokens=tokens)
         monitor.record_generation(prefill_steps=1)
         self.stats["prefills"] += 1
-        admit = self._exe_admit()
-        paged_args, pages, plan = (), None, None
-        if self._alloc is not None:
-            # every span below the last chunk boundary is already
-            # installed: the admit's install_row writes only the final
-            # span (start = the later of shared prefix end and the
-            # final chunk's base)
-            pages, plan = self._pending_pages[req.id]
-            table_np = np.zeros((self.pages_per_row,), np.int32)
-            table_np[:len(pages)] = pages
-            start = max(int(plan.shared_len), k * C)
-            paged_args = (jnp.asarray(table_np),
-                          jnp.asarray(start, jnp.int32))
-        if self._spec is None:
-            (self._cache, self._tok, self._finished, self._steps,
-             self._budget, self._out_buf) = admit(
-                self._cache, self._tok, self._finished, self._steps,
-                self._budget, self._out_buf,
-                jnp.asarray(slot, jnp.int32), self._row_cache, tok, fin,
-                jnp.asarray(req.budget, jnp.int32), *paged_args)
-        else:
-            ids_row = np.full((self.max_len,), self._cfg.pad_value,
-                              np.int32)
-            ids_row[:req.prompt.size] = req.prompt
-            (self._cache, self._tok, self._finished, self._steps,
-             self._budget, self._out_buf, self._tok_buf,
-             self._tok_len) = admit(
-                self._cache, self._tok, self._finished, self._steps,
-                self._budget, self._out_buf,
-                jnp.asarray(slot, jnp.int32), self._row_cache, tok, fin,
-                jnp.asarray(req.budget, jnp.int32), *paged_args,
-                self._tok_buf, self._tok_len, jnp.asarray(ids_row),
-                jnp.asarray(req.prompt.size, jnp.int32))
-        if self._alloc is not None:
-            self._pending_pages.pop(req.id)
-            self._alloc.register(plan, pages)
-            self._row_pages[slot] = pages
-        if self._slot_used[slot]:
-            self.stats["slots_reused"] += 1
-        self._slot_used[slot] = True  # lint: lock-discipline-ok (admission runs under the caller's pump lock)
-        req.status = RequestStatus.RUNNING
-        self.stats["admitted"] += 1
+        # every span below the last chunk boundary is already
+        # installed: the admit's install_row writes only the final
+        # span (start = the later of shared prefix end and the final
+        # chunk's base)
+        self._install(req, slot, row_cache, tok, fin, installed=k * C)
         self._chunking = None
         # the admit program donated the side cache: rebuild it zeroed
         # (kv_len 0, clips 0) so the next chunked admission starts
         # clean — this rebuild IS the between-requests reset
-        self._row_cache = self._fresh_row_cache()
-        monitor.record_serve_slot_occupancy(
-            sum(s is not None for s in self._slots) / self.max_batch)
+        self._row_cache = _host_zeros(self._row_cache_aval)
 
     def _abort_chunked(self, reason: str, label: Optional[str] = None):
         """Terminal exit for a mid-prefill request (deadline, drain,
@@ -1751,34 +1226,15 @@ class ServingEngine:
         self._slots[slot] = None  # lint: lock-discipline-ok (abort runs under the caller's pump lock)
         # the side cache holds the aborted prompt's partial prefix —
         # rebuild zeroed before the next chunked admission
-        self._row_cache = self._fresh_row_cache()
+        self._row_cache = _host_zeros(self._row_cache_aval)
         self._cancel(req, reason, label=label)
         self._note_cost(req)
 
     def _dispatch_decode(self):
-        exe = self._exe_step()
+        exe = self._compiled(self._mode.key)
         with flight_recorder.span("serve.dispatch") as sp:
-            if self._bd is not None:
-                (self._cache, self._finished, self._steps, self._budget,
-                 self._out_buf, self._ustep_buf, self._blk,
-                 self._blk_step, self._out0, self._bd_counters,
-                 self._moe_counters) = exe(self._state,
-                                           *self._block_lanes())
-            elif self._spec is None:
-                (self._tok, self._cache, self._key, self._finished,
-                 self._steps, self._budget, self._out_buf) = exe(
-                    self._state, self._tok, self._cache, self._key,
-                    self._finished, self._steps, self._budget,
-                    self._out_buf)
-            else:
-                (self._tok, self._cache, self._key, self._finished,
-                 self._steps, self._budget, self._out_buf,
-                 self._tok_buf, self._tok_len, self._proposed,
-                 self._accepted) = exe(
-                    self._state, self._tok, self._cache, self._key,
-                    self._finished, self._steps, self._budget,
-                    self._out_buf, self._tok_buf, self._tok_len,
-                    self._proposed, self._accepted)
+            self._cache, self._lanes, self._key = exe(
+                self._state, self._cache, self._lanes, self._key)
         self._steps_since_poll += 1
         self._steps_unsynced += 1
         if self._chunking is not None:
@@ -1796,21 +1252,10 @@ class ServingEngine:
 
     def _read_lanes(self):
         """The poll's blocking read: the [batch] finished/step lanes and,
-        in the same window, the on-device speculation counters (two
-        int32 scalars — no extra sync cadence)."""
-        fin = np.asarray(self._finished)  # lint: host-sync-ok (scheduler poll, every poll_every steps)
-        steps = np.asarray(self._steps)  # lint: host-sync-ok (same poll read)
-        if self._bd is not None:
-            # forwards / unmasked / commits, then the experts' rows and
-            # busiest-expert rows: five int32 scalars in the same window
-            return (fin, steps,
-                    np.asarray(self._bd_counters),  # lint: host-sync-ok (same poll read)
-                    np.asarray(self._moe_counters))  # lint: host-sync-ok (same poll read)
-        if self._spec is None:
-            return fin, steps, 0, 0
-        return (fin, steps,
-                int(np.asarray(self._proposed)),  # lint: host-sync-ok (same poll read)
-                int(np.asarray(self._accepted)))  # lint: host-sync-ok (same poll read)
+        in the same window, the mode's on-device counters (a few int32
+        scalars — no extra sync cadence)."""
+        return [np.asarray(getattr(self._lanes, n))  # lint: host-sync-ok (scheduler poll, every poll_every steps)
+                for n in ("finished", "steps") + self._mode.counters]
 
     def _poll(self):
         """Scheduler poll: read the [batch] finished/step lanes (the
@@ -1821,34 +1266,9 @@ class ServingEngine:
 
     def _poll_lanes(self, sp):
         covered, self._steps_since_poll = self._steps_since_poll, 0
-        (fin, steps, prop, acc), t_ns = self._sync("poll",
+        (fin, steps, *counters), t_ns = self._sync("poll",
                                                    self._read_lanes)
-        forwards = commits = None
-        if self._bd is not None:
-            # lifetime int32 counters that may wrap: modular deltas, as
-            # the speculation counters below
-            # (_read_lanes hands the two counter vectors back in the
-            # speculation counters' places)
-            seen = np.concatenate([prop, acc]).astype(np.int64)
-            d = (seen - self._bd_seen) % (1 << 32)
-            self._bd_seen = seen
-            forwards, unmasked, commits, rows, rows_max = (
-                int(x) for x in d)
-            self.stats["diffusion_forwards"] += forwards
-            self.stats["diffusion_commits"] += commits
-            monitor.record_block_diffusion(forwards, unmasked, commits)
-            monitor.record_moe_routing(rows, rows_max)
-        elif self._spec is not None:
-            # the device counters are lifetime-monotonic int32 and WRAP
-            # on a long-lived engine; per-poll deltas are tiny, so
-            # modular subtraction recovers them exactly across the wrap
-            dp = (prop - self._spec_seen[0]) % (1 << 32)
-            da = (acc - self._spec_seen[1]) % (1 << 32)
-            if dp or da:
-                self._spec_seen = (prop, acc)
-                self.stats["spec_proposed"] += dp
-                self.stats["spec_accepted"] += da
-                monitor.record_speculative(dp, da)
+        drained = self._mode.drain(counters, self.stats)
         now = t_ns * 1e-9
         window_dt = 0.0
         if self._window_t0_ns is not None and self._window_steps:
@@ -1900,10 +1320,7 @@ class ServingEngine:
             req.n_emitted = n
             if fin[i]:
                 row, _ = self._sync("row", lambda: self._read_row(i))
-                if self._bd is not None:
-                    req.unmask_steps = row[1][:n]
-                    row = row[0]
-                self._complete(req, row[:n])
+                self._complete(req, self._mode.cut(req, row, n, False))
                 completed += 1
                 # freed in place; the next admission overwrites the row
                 self._slots[i] = None  # lint: lock-discipline-ok (poll runs under the caller's pump lock)
@@ -1920,9 +1337,7 @@ class ServingEngine:
         self.stats["polls"] += 1
         sp.set(steps=covered, emitted=emitted, admitted=admitted,
                completed=completed, evicted=evicted,
-               live=sum(s is not None for s in self._slots),
-               **({} if forwards is None
-                  else {"forwards": forwards, "commits": commits}))
+               live=sum(s is not None for s in self._slots), **drained)
         # expire queued requests that can no longer meet their deadline
         with self._qlock:
             for req in list(self._queue):
@@ -1944,14 +1359,10 @@ class ServingEngine:
             slo_mod.tick()
 
     def _read_row(self, slot: int):
-        """One lane's output row; under block diffusion ``(tokens,
-        unmask steps)``, both in position order."""
-        if self._bd is None:
-            return np.asarray(self._out_buf[slot])  # lint: host-sync-ok (one row read per completion)
-        # both rows behind ONE wait: a second blocking read is a second
-        # round trip during which the device has nothing queued
-        return jax.device_get((self._out_buf[slot],  # lint: host-sync-ok (one row read per completion)
-                               self._ustep_buf[slot]))
+        """One lane's result rows (the mode's ``row`` lanes, in position
+        order), all behind ONE wait."""
+        return jax.device_get(tuple(  # lint: host-sync-ok (one row read per completion)
+            getattr(self._lanes, n)[slot] for n in self._mode.row))
 
     def _complete(self, req: Request, toks: np.ndarray):
         eos = self._cfg.eos_token_id
@@ -1986,20 +1397,13 @@ class ServingEngine:
         if flight_recorder.enabled:
             flight_recorder.record("serve.evict", req=req.id, slot=slot,
                                    reason=reason, tokens=n_done)
-        exe = self._exe_free()
-        self._cache, self._finished = exe(
-            self._cache, self._finished, jnp.asarray(slot, jnp.int32))
+        self._cache, self._lanes = self._compiled(("free",))(
+            self._cache, self._lanes, np.int32(slot))
         if n_done:
             row, _ = self._sync("row", lambda: self._read_row(slot))
-            if self._bd is not None:
-                # tokens are unmasked out of order inside a block: the
-                # partial result is the prefix before the first position
-                # still masked
-                row, usteps = row
-                n_done = int(np.argmax(np.append(usteps, -1) < 0))
-                req.unmask_steps = usteps[:n_done]
-            req.tokens = row[:n_done].astype(np.int32)
-            req.n_emitted = n_done
+            req.tokens = self._mode.cut(req, row, n_done, True) \
+                .astype(np.int32)
+            req.n_emitted = int(req.tokens.size)
         self._slots[slot] = None  # lint: lock-discipline-ok (eviction runs under the caller's pump lock)
         self._free_slot_pages(slot)
         self._cancel(req, reason)
@@ -2388,72 +1792,35 @@ class ServingEngine:
         against ``hbm_budget`` and ``health()`` exports the headroom."""
         if self._mem_summary is not None:
             return self._mem_summary
-        from ..analysis import plan_memory
         self._ensure_eval()
-        sds = jax.ShapeDtypeStruct
-        state = tuple(sds(tuple(v.shape), v.dtype) for v in self._state)
-        key = sds((2,), jnp.uint32)
-        if self._bd is not None:
-            decode = plan_memory(
-                self._step_fn, state, *self._block_lanes(), self._bd,
-                static_argnums=(12,), donate=self._step_donate_intent,
-                name="serving.decode")
-        elif self._spec is None:
-            decode = plan_memory(
-                self._step_fn, state, self._tok, self._cache, key,
-                self._finished, self._steps, self._budget,
-                self._out_buf, self._cfg, static_argnums=(8,),
-                donate=self._step_donate_intent,
-                name="serving.decode")
-        else:
-            decode = plan_memory(
-                self._step_fn, state, self._tok, self._cache, key,
-                self._finished, self._steps, self._budget,
-                self._out_buf, self._tok_buf, self._tok_len,
-                self._proposed, self._accepted, self._cfg, self._spec,
-                static_argnums=(12, 13),
-                donate=self._step_donate_intent,
-                name="serving.decode")
-        prefill = plan_memory(
-            self._prefill_fn, state, sds((1, self.buckets[-1]),
-                                         jnp.int32),
-            sds((1,), jnp.int32), key, self._cfg, self.max_len,
-            static_argnums=(4, 5),
-            name=f"serving.prefill.{self.buckets[-1]}")
-        chunk = None
-        if self._chunk_enabled:
-            # the chunk program's transient rides on top of the SAME
-            # resident engine state as an inline admission — plus it
-            # keeps the side cache resident between chunks (an operand
-            # of the plan, so its bytes are inside chunk.peak_bytes)
-            chunk = plan_memory(
-                self._chunk_fn, state,
-                sds((1, self.prefill_chunk_tokens), jnp.int32),
-                self._row_cache_aval,
-                donate=self._chunk_donate_intent,
-                name=f"serving.prefill_chunk."
-                     f"{self.prefill_chunk_tokens}")
+        step = self._programs[self._mode.key]
+        decode = step.plan("serving.decode")
+        prefill = self._programs[("prefill", self.buckets[-1])]
+        prefill = prefill.plan("serving." + prefill.name)
+        # the chunk program's transient rides on top of the SAME
+        # resident engine state as an inline admission — plus it
+        # keeps the side cache resident between chunks (an operand
+        # of the plan, so its bytes are inside chunk.peak_bytes)
+        chunk = self._programs.get(("chunk", self.prefill_chunk_tokens))
+        if chunk is not None:
+            chunk = chunk.plan("serving." + chunk.name)
         if decode.arg_bytes is not None:
-            weights = decode.arg_bytes[0]
-            kv = decode.arg_bytes[1 if self._bd is not None else 2]
+            operands = list(step.args)
+            weights = decode.arg_bytes[operands.index("state")]
+            kv = decode.arg_bytes[operands.index("cache")]
             lanes = sum(decode.arg_bytes) - weights - kv
-            resident = sum(decode.arg_bytes)
-            predicted = max(decode.peak_bytes,
-                            resident + prefill.peak_bytes - weights)
-            if chunk is not None:
-                predicted = max(
-                    predicted, resident + chunk.peak_bytes - weights)
+            # an admission's transient shares the resident weights
+            resident = sum(decode.arg_bytes) - weights
         else:
             # exotic-pytree fail-safe (audit couldn't line leaves up
             # with positional args): no per-operand breakdown, and the
             # prefill transient can't subtract the shared weights —
             # predict CONSERVATIVELY rather than crash or under-gate
             weights = kv = lanes = None
-            predicted = max(decode.peak_bytes,
-                            decode.args_bytes + prefill.peak_bytes)
-            if chunk is not None:
-                predicted = max(predicted,
-                                decode.args_bytes + chunk.peak_bytes)
+            resident = decode.args_bytes
+        predicted = max(decode.peak_bytes, *(
+            resident + p.peak_bytes for p in (prefill, chunk)
+            if p is not None))
         self._mem_summary = {
             "weights_bytes": weights, "kv_cache_bytes": kv,
             "lanes_bytes": lanes,
@@ -2478,103 +1845,12 @@ class ServingEngine:
         and donation coverage 1.0 on the slot-decode program — the
         cache and token buffers must stay in-place across scheduler
         steps."""
-        from ..analysis import audit as _audit
         # audit must describe the EVAL program the engine serves, even
         # when called mid-fit on a shared layer
         self._ensure_eval()
         base = audit_kw.pop("name", "serving")
-        sds = jax.ShapeDtypeStruct
-        state = tuple(sds(tuple(v.shape), v.dtype) for v in self._state)
-        key = sds((2,), jnp.uint32)
-        reports: Dict = {}
-        for b in self.buckets:
-            reports[("prefill", b)] = _audit(
-                self._prefill_fn, state, sds((1, b), jnp.int32),
-                sds((1,), jnp.int32), key, self._cfg, self.max_len,
-                static_argnums=(4, 5), name=f"{base}.prefill.{b}",
-                **audit_kw)
-        # decode avals are the engine's own lanes; the row-cache aval
-        # comes from the smallest bucket's prefill report (same trace)
-        tok_a, row_cache_a, _, fin_a = \
-            reports[("prefill", self.buckets[0])].out_shape
-        scalar = sds((), jnp.int32)
-        # the paged admit carries the row's page table + install start
-        # after row_budget; its donation set is the same (the pool
-        # pytree and every lane stay in place across admissions)
-        paged_a = () if self._alloc is None else (
-            sds((self.pages_per_row,), jnp.int32), scalar)
-        if self._bd is not None:
-            reports["decode"] = _audit(
-                self._step_fn, state, *self._block_lanes(), self._bd,
-                static_argnums=(12,), donate=self._step_donate_intent,
-                name=f"{base}.decode", **audit_kw)
-            reports["admit"] = _audit(
-                self._admit_fn, *self._block_lanes()[:9], scalar,
-                row_cache_a, scalar,
-                sds((self._bd.block_length,), jnp.int32), scalar,
-                *paged_a, donate=self._admit_donate_intent,
-                name=f"{base}.admit", **audit_kw)
-        elif self._spec is None:
-            reports["decode"] = _audit(
-                self._step_fn, state, self._tok, self._cache, self._key,
-                self._finished, self._steps, self._budget, self._out_buf,
-                self._cfg, static_argnums=(8,),
-                donate=self._step_donate_intent,
-                name=f"{base}.decode", **audit_kw)
-            reports["admit"] = _audit(
-                self._admit_fn, self._cache, self._tok, self._finished,
-                self._steps, self._budget, self._out_buf, scalar,
-                row_cache_a, tok_a, fin_a, scalar, *paged_a,
-                donate=self._admit_donate_intent,
-                name=f"{base}.admit", **audit_kw)
-        else:
-            # the speculative step IS the decode program the scheduler
-            # dispatches: fused ngram draft + single-dispatch verify,
-            # every state lane (cache, token buffers, counters) donated
-            reports["decode"] = _audit(
-                self._step_fn, state, self._tok, self._cache, self._key,
-                self._finished, self._steps, self._budget, self._out_buf,
-                self._tok_buf, self._tok_len, self._proposed,
-                self._accepted, self._cfg, self._spec,
-                static_argnums=(12, 13),
-                donate=self._step_donate_intent,
-                name=f"{base}.decode", **audit_kw)
-            reports["admit"] = _audit(
-                self._admit_fn, self._cache, self._tok, self._finished,
-                self._steps, self._budget, self._out_buf, scalar,
-                row_cache_a, tok_a, fin_a, scalar, *paged_a,
-                self._tok_buf, self._tok_len,
-                sds((self.max_len,), jnp.int32), scalar,
-                donate=self._admit_donate_intent,
-                name=f"{base}.admit", **audit_kw)
-        reports["free"] = _audit(
-            self._free_fn, self._cache, self._finished, scalar,
-            donate=self._free_donate_intent, name=f"{base}.free",
-            **audit_kw)
-        if self._chunk_enabled:
-            # the chunk-prefill pair (and the paged span install) join
-            # the audited program set: the tier-1 ledger drift gate and
-            # the donation-coverage gate extend to them — the side
-            # cache must round-trip IN PLACE every chunk
-            C = self.prefill_chunk_tokens
-            rc_a = self._row_cache_aval
-            reports[("chunk", C)] = _audit(
-                self._chunk_fn, state, sds((1, C), jnp.int32), rc_a,
-                donate=self._chunk_donate_intent,
-                name=f"{base}.prefill_chunk.{C}", **audit_kw)
-            reports[("chunk_final", C)] = _audit(
-                self._chunk_final_fn, state, sds((1, C), jnp.int32),
-                sds((1,), jnp.int32), key, rc_a, self._cfg,
-                static_argnums=(5,),
-                donate=self._chunk_final_donate_intent,
-                name=f"{base}.prefill_chunk_final.{C}", **audit_kw)
-            if self._alloc is not None:
-                reports[("install_span",)] = _audit(
-                    self._span_fn, self._cache, rc_a,
-                    sds((self.pages_per_row,), jnp.int32), scalar,
-                    donate=self._span_donate_intent,
-                    name=f"{base}.install_span", **audit_kw)
-        return reports
+        return {prog.report: prog.audit(f"{base}.{prog.name}", **audit_kw)
+                for prog in self._programs.values()}
 
     def __repr__(self):
         occ = sum(s is not None for s in self._slots)

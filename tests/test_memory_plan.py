@@ -13,6 +13,8 @@ over-predicts by at most ``_SLACK``x — the gap is transient
 temporaries XLA materializes and frees between the live-array polls the
 CPU backend's ``max_memory_allocated`` fallback can see.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -430,13 +432,13 @@ class TestPredictedVsMeasured:
 
     def test_engine_decode_program_within_slack(self):
         eng = _tiny_engine()
-        args = (eng._state, eng._tok, eng._cache, eng._key,
-                eng._finished, eng._steps, eng._budget, eng._out_buf)
-        plan = analysis.plan_memory(
-            eng._step_fn, *args, eng._cfg, static_argnums=(8,),
-            name="engine.decode.measured")
+        # the step's record, with nothing donated: CPU dispatch donates
+        # nothing, so plan the same undonated program
+        step = dataclasses.replace(eng._programs[("step",)], donates=())
+        args = (eng._state, eng._cache, eng._lanes, eng._key)
+        plan = step.plan("engine.decode.measured")
         measured, _ = self._measure(
-            lambda *a: eng._step_jit(*a, eng._cfg), args, args)
+            lambda *a: step.jit(*a, eng._cfg), args, args)
         assert measured <= plan.peak_bytes <= _SLACK * measured, \
             (measured, plan.peak_bytes)
 

@@ -338,8 +338,8 @@ def test_engine_step_spans_under_budget():
     ``set()`` calls and the sampled request's decode segment."""
     eng, _ = _tiny_engine()
     try:
-        frozen = _Frozen(lambda state, *lanes: lanes)
-        eng._exe_step = lambda: frozen   # both lanes live for ever
+        # both lanes live for ever
+        eng._exes[("step",)] = _Frozen(lambda state, *carried: carried)
         _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
         polls0 = eng.stats["polls"]
         for _ in range(4 * eng.poll_every):

@@ -14,6 +14,7 @@ gen.cache.* metrics family, the tier-1 audit gate over the paged
 admit/decode/free trio with a seeded regression, and the chaos
 SIGTERM drain with shared pages live (free-list conserved).
 """
+import dataclasses
 import time
 
 import numpy as np
@@ -762,16 +763,16 @@ def test_paged_audit_gate_not_vacuous(tiny_gpt):
     eng = ServingEngine(_config(tiny_gpt, max_new=4, max_batch=1,
                                 paged=True, kv_page_size=16),
                         warmup=False)
-    orig = eng._step_fn
+    step = eng._programs[("step",)]
 
     def poisoned(*args):
-        out = orig(*args)
+        cache, lanes, key = step.fn(*args)
         leak = jax.pure_callback(
             lambda t: np.asarray(t),
-            jax.ShapeDtypeStruct((1,), jnp.int32), out[0])
-        return (out[0] + leak * 0,) + out[1:]
+            jax.ShapeDtypeStruct((1,), jnp.int32), lanes.tok)
+        return cache, lanes._replace(tok=lanes.tok + leak * 0), key
 
-    eng._step_fn = poisoned
+    eng._programs[("step",)] = dataclasses.replace(step, fn=poisoned)
     with pytest.raises(AuditError):
         eng.audit()["decode"].raise_on_error()
 

@@ -201,7 +201,7 @@ def test_failed_admission_left_the_queue_without_a_prefill(kw):
     def boom(*a):
         raise RuntimeError("injected")
     # the program fetch of either admission path, before any dispatch
-    eng._exe_prefill = eng._exe_chunk = boom
+    eng._compiled = boom
     h = eng.submit(PROMPTS[1])
     while not h.done():
         eng.step()
@@ -229,8 +229,9 @@ def test_setup_spans_and_a_compile_after_warmup():
     assert alloc.fields["bytes"] > 0
     assert not _named("jit.program") and not _named("setup.warmup")
     # warm everything but the 16 bucket
-    eng._exe_prefill(8), eng._exe_step(), eng._exe_admit()
-    eng._exe_free()
+    for key in eng._programs:
+        if key != ("prefill", 16):
+            eng._compiled(key)
     eng._warm = True
     warm = _named("jit.program")
     assert sorted(s.fields["label"] for s in warm) == [

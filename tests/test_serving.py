@@ -9,6 +9,7 @@ freeing, the serve.* SLA metrics family + MetricsCallback surfacing,
 the tier-1 audit gate on the slot-decode program, the bf16 precision
 path, thread mode, and the chaos graceful-shutdown drain.
 """
+import dataclasses
 import time
 
 import numpy as np
@@ -355,16 +356,16 @@ def test_audit_gate_not_vacuous(tiny_gpt):
     from paddle_tpu.analysis import AuditError
     eng = ServingEngine(_config(tiny_gpt, max_new=4, buckets=(16,),
                                 max_batch=1), warmup=False)
-    orig = eng._step_fn
+    step = eng._programs[("step",)]
 
     def poisoned(*args):
-        out = orig(*args)
+        cache, lanes, key = step.fn(*args)
         leak = jax.pure_callback(
             lambda t: np.asarray(t), jax.ShapeDtypeStruct((1,), jnp.int32),
-            out[0])
-        return (out[0] + leak * 0,) + out[1:]
+            lanes.tok)
+        return cache, lanes._replace(tok=lanes.tok + leak * 0), key
 
-    eng._step_fn = poisoned
+    eng._programs[("step",)] = dataclasses.replace(step, fn=poisoned)
     with pytest.raises(AuditError):
         eng.audit()["decode"].raise_on_error()
 
