@@ -128,7 +128,7 @@ def flagship_reports() -> Dict[str, object]:
     reports["predictor.prefill.16"] = bucket[("prefill", 16)]
     reports["predictor.decode.16"] = bucket[("decode", 16)]
 
-    # ---- ServingEngine program trio: dense, paged, paged-int8 (the
+    # ---- ServingEngine programs: dense, paged, paged-int8 (the
     # quant variant carries the scale-sidecar geometry through every
     # program, so a misattributed sidecar shows up as byte drift here)
     from paddle_tpu.serving import ServingEngine
@@ -145,7 +145,7 @@ def flagship_reports() -> Dict[str, object]:
         eng = ServingEngine(ecfg, warmup=False)
         rs = eng.audit()
         reports[f"{tag}.prefill.32"] = rs[("prefill", 32)]
-        for prog in ("decode", "admit", "free"):
+        for prog in ("decode", "admit", "free", "poll_view"):
             reports[f"{tag}.{prog}"] = rs[prog]
         # chunked-prefill programs (enabled on every flagship engine so
         # the ledger pins their geometry): the chunk/final pair always,

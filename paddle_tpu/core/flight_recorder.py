@@ -164,20 +164,27 @@ EVENT_DOC = {
 # tools.metrics_doc` renders it into docs/events.md.
 DECLARED_SPANS = {
     "serve.step": "one ServingEngine.step() under the pump lock "
-                  "(decode=1 if a decode step was dispatched, queued, "
-                  "live)",
+                  "(decode = decode steps it dispatched: 2 when its poll "
+                  "ran one ahead of its read, queued, live)",
     "serve.admit": "one request's admission inside serve.step: host "
-                   "prep, prefill dispatch, its sync, the admit program "
+                   "prep, prefill dispatch, the admit program's dispatch; "
+                   "the wait for the prefill's token follows the "
+                   "iteration's decode dispatch, a serve.sync under "
+                   "serve.step "
                    "(req, slot, bucket, prompt; chunks when the prefill is "
                    "chunked: the span then covers the reservation only)",
     "serve.sync": "one blocking device read (site = prefill / chunk / "
-                  "poll / row / stats; steps_queued = decode steps dispatched "
-                  "since the last sync returned: what the read waits "
-                  "behind)",
+                  "poll / row / stats; steps_queued = decode steps "
+                  "dispatched before the program it waits for and not "
+                  "seen to land by an earlier sync: what the read waits "
+                  "behind; ahead = device programs dispatched after the "
+                  "one it waits for: 0 = the device idles when the read "
+                  "returns)",
     "serve.dispatch": "the decode step's exe(...) call inside "
                       "serve.step",
     "serve.poll": "one scheduler poll inside serve.step (steps = decode "
-                  "steps it covers, emitted = tokens the lanes advanced "
+                  "steps its read covers, not the one a full engine "
+                  "dispatches ahead of the read, emitted = tokens the lanes advanced "
                   "since the last poll, admitted = lanes polled for the "
                   "first time, completed, evicted, live; under block "
                   "diffusion also forwards = lane-forwards and commits = "

@@ -75,9 +75,20 @@ block diffusion) are ``serving/programs.py``: the engine holds one
 program table and one mode object, and calls them.
 
 Host syncs are confined to the scheduler's poll cadence (every
-``poll_every`` decode steps: two [batch]-lane reads), one small sync
-per admission (the TTFT measurement point), and one row read per
-completion — the decode hot loop itself dispatches without waiting.
+``poll_every`` decode steps: one read of the [batch] lanes), one small
+sync per admission (the TTFT measurement point), and one read of the
+result rows per poll that completed a lane — the decode hot loop itself
+dispatches without waiting. **The scheduler blocks on the device only
+after it has given the device the work that follows the value it
+reads**: an iteration dispatches an admission (prefill, then the
+admit program on the prefill's outputs, device arrays still) and its
+decode step, and only then waits for the prefill's token (with several
+slots free, an earlier admission's token is waited for with its own
+admit program behind the wait, before the next prefill goes out: two
+prefill rows are alive at most); a poll of a full engine dispatches the next decode step first and reads the lanes
+as the step before it left them (the ``poll_view`` program's copies:
+the step donates the lanes themselves). ``serve.sync``'s ``ahead`` says
+how many programs were queued behind the one a read waited for.
 
 Every scheduler iteration, admission, blocking read, decode dispatch
 and poll is a flight-recorder span (``serve.step`` > ``serve.admit`` /
@@ -536,9 +547,13 @@ class ServingEngine:
             self._warm = False
             self._shutdown = False
             self._steps_since_poll = 0
-            # decode steps dispatched since the last blocking read returned:
-            # what the next read waits behind (serve.sync's steps_queued)
-            self._steps_unsynced = 0
+            # device programs dispatched so far, and the decode steps a
+            # blocking read has seen land: a read is told which program
+            # it waits for (``_mark``), and its serve.sync says how many
+            # steps lay before that one (steps_queued) and how many
+            # programs behind it (ahead)
+            self._seq = 0
+            self._steps_landed = 0
             self._window_t0_ns: Optional[int] = None
             self._window_steps = 0
             # emitted_tokens / polls: every lane's progress as the polls saw
@@ -829,25 +844,32 @@ class ServingEngine:
 
     # -------------------------------------------------------- scheduler
     def step(self):
-        """One scheduler iteration: admit queued requests into free
-        slots (short prompts inline, long ones one CHUNK per iteration
-        when chunked prefill is on), dispatch one fixed-batch decode
-        step for the running slots, advance the in-flight chunked
-        prefill, poll completions every ``poll_every`` steps. Decode
-        dispatches BEFORE the chunk's blocking sync, so in-flight
-        streams overlap the chunk's device time instead of stalling
-        behind a whole long prefill — the head-of-line fix."""
+        """One scheduler iteration: dispatch the admissions of queued
+        requests into free slots (short prompts inline, long ones one
+        CHUNK per iteration when chunked prefill is on), dispatch one
+        fixed-batch decode step for the running slots, and only then
+        wait for the last admission's first token; advance the in-flight
+        chunked prefill, poll completions every ``poll_every`` steps.
+        Every blocking read comes after the dispatch of what follows the
+        value it reads: the decode step is queued behind the prefills
+        before their tokens are waited for, and before the chunk's sync
+        (in-flight streams overlap the chunk's device time instead of
+        stalling behind a whole long prefill — the head-of-line fix); a
+        poll of a full engine dispatches one more step before it reads
+        (``_poll_lanes``)."""
         with self._pump_lock, flight_recorder.span("serve.step") as sp:
-            self._admit_ready()
+            steps0 = self.stats["decode_steps"]
+            admitted = self._admit_ready()
             live = sum(s is not None
                        and s.status is RequestStatus.RUNNING
                        for s in self._slots)
             if live:
                 self._dispatch_decode()
+            self._land(admitted)
             self._advance_chunked()
             if self._steps_since_poll >= self.poll_every:
                 self._poll()
-            sp.set(decode=int(live > 0), live=live,
+            sp.set(decode=self.stats["decode_steps"] - steps0, live=live,
                    queued=len(self._queue))
 
     def _unblock_if(self, req: Request):
@@ -917,18 +939,30 @@ class ServingEngine:
         return self._chunk_enabled and \
             req.prompt.size > self.prefill_chunk_tokens
 
-    def _admit_ready(self):
+    def _admit_ready(self) -> Optional[tuple]:
+        """Fill the free slots from the queue. An inline admission's
+        prefill and admit program are dispatched and not waited for;
+        the one before it is landed first (``_land``: its own admit
+        program is queued behind that wait, and runs while the host
+        prepares this one). So a prefill's row — a whole batch-1 cache,
+        256 MiB at 6.7B widths, alive until its admit program has run —
+        never has more than one other beside it, however many slots are
+        free. The last admission comes back unlanded: ``step`` queues
+        the decode step behind it before it waits."""
+        pending = None
         for slot, occupant in enumerate(self._slots):
             if occupant is not None:
                 continue
             req = self._pop_queue()
             if req is None:
                 break
+            self._land(pending)
+            pending = None
             try:
                 if self._needs_chunk(req):
                     self._begin_chunked(req, slot)
                 else:
-                    self._admit(req, slot)
+                    pending = self._admit(req, slot)
             except Exception as e:
                 # the request left the queue but reached no slot: it
                 # MUST still go terminal or its Future would hang
@@ -943,14 +977,62 @@ class ServingEngine:
                                   f"{type(e).__name__}: {e}",
                              label="error")
                 monitor.record_swallowed("serving.admit", e)
+        return pending
 
-    def _sync(self, site: str, read):
+    def _land(self, admitted: Optional[tuple]) -> None:
+        """Wait for the first token of an admission ``_admit`` dispatched
+        (None: nothing to wait for): a wait on a prefill's own token
+        returns when that prefill lands, whatever is queued behind it,
+        so the TTFT stamp is where it was. A prefill that failed on the
+        device surfaces HERE, its request already in its slot: the slot
+        is evicted (lane masked, row reset, pages back on the free list)
+        and the request goes terminal; the others are served."""
+        if admitted is None:
+            return
+        req, slot, bucket, t_admit_ns, tok, after = admitted
+        t0 = flight_recorder.now_ns()
+        try:
+            _, t1 = self._sync("prefill", tok.block_until_ready, after)
+        except Exception as e:
+            t1 = flight_recorder.now_ns()
+            self._evict(slot, req,
+                        f"admission error: {type(e).__name__}: {e}",
+                        label="error")
+            monitor.record_swallowed("serving.admit", e)
+        else:
+            self._first_token(req, t_admit_ns, t1, bucket)
+            # the wait is admission wall too (the dispatch was charged
+            # when its serve.admit closed)
+            self._charge_admission(req, (t1 - t0) * 1e-9, False)
+        # the wait must not be attributed to per-token decode latency:
+        # the window restarts where it returned, holding the steps
+        # dispatched behind the admission
+        self._window_steps = self.stats["decode_steps"] - after[1]
+        self._window_t0_ns = t1
+
+    def _run(self, key, *operands):
+        """Dispatch one warm program of the table. Nothing waits."""
+        out = self._compiled(key)(*operands)
+        self._seq += 1
+        return out
+
+    def _mark(self) -> tuple:
+        """The last program dispatched, for the read that will wait for
+        it: (programs dispatched, decode steps among them)."""
+        return self._seq, self.stats["decode_steps"]
+
+    def _sync(self, site: str, read, after: Optional[tuple] = None):
         """One blocking device read under a ``serve.sync`` span:
-        ``(what read() returned, the stamp at which it returned)``."""
-        with flight_recorder.span("serve.sync", site=site,
-                                  steps_queued=self._steps_unsynced) as sp:
+        ``(what read() returned, the stamp at which it returned)``.
+        ``after`` is the ``_mark`` of the program whose output ``read``
+        waits for (the last one dispatched when not given)."""
+        seq, steps = after or self._mark()
+        with flight_recorder.span(
+                "serve.sync", site=site,
+                steps_queued=max(steps - self._steps_landed, 0),
+                ahead=self._seq - seq) as sp:
             out = read()
-        self._steps_unsynced = 0
+        self._steps_landed = max(self._steps_landed, steps)
         return out, sp.end_ns or flight_recorder.now_ns()
 
     def _dequeued(self, req: Request, sp, bucket: int) -> int:
@@ -970,6 +1052,8 @@ class ServingEngine:
         measurement point."""
         req.first_token_at = t_ns * 1e-9
         monitor.record_serve_ttft(req.first_token_at - req.submitted_at)
+        monitor.record_generation(prefill_steps=1)
+        self.stats["prefills"] += 1
         if flight_recorder.enabled:
             req.stage_span("serve.prefill", t_admit_ns, t_ns,
                            bucket=bucket)
@@ -986,7 +1070,9 @@ class ServingEngine:
         req._cost_prefill_s += dt
         self._goodput.charge("compile" if retraced else "compute", dt)
 
-    def _admit(self, req: Request, slot: int):
+    def _admit(self, req: Request, slot: int) -> tuple:
+        """Dispatch one inline admission (``_admit_inner``) under its
+        ``serve.admit``; returns what ``_land`` waits for it with."""
         retraces0 = monitor.retrace_count()
         bucket = next(b for b in self.buckets if b >= req.prompt.size)
         sp = flight_recorder.span("serve.admit", req=req.id, slot=slot,
@@ -996,33 +1082,32 @@ class ServingEngine:
         try:
             with sp:
                 t0 = self._dequeued(req, sp, bucket)
-                self._admit_inner(req, slot, bucket, t0)
+                tok, after = self._admit_inner(req, slot, bucket)
         finally:
             t1 = sp.end_ns or flight_recorder.now_ns()
             self._charge_admission(
                 req, (t1 - (t0 or t1)) * 1e-9,
                 monitor.retrace_count() > retraces0)
+        return req, slot, bucket, t0, tok, after
 
-    def _admit_inner(self, req: Request, slot: int, bucket: int,
-                     t_admit_ns: int):
+    def _admit_inner(self, req: Request, slot: int, bucket: int) -> tuple:
+        """The prefill, then the admit program on its outputs: both
+        dispatched, neither waited for (the admit and the mode's
+        ``first`` take ``tok`` and ``fin`` as the device arrays they
+        are). Returns the token to wait on and the prefill's mark: the
+        TTFT measurement point is that wait's return — one small sync
+        per ADMISSION (not per decode step), made by ``_land`` once the
+        iteration's decode step is queued behind."""
         ids = np.full((1, bucket), self._cfg.pad_value, np.int32)
         ids[0, :req.prompt.size] = req.prompt
         plen = np.array([self._mode.prefill_len(req.prompt)], np.int32)
         exe = self._exe_prefill(bucket)
         tok, row_cache, self._key, fin = exe(
             self._state, jnp.asarray(ids), jnp.asarray(plen), self._key)
-        # TTFT measurement point: the request's first token exists once
-        # the prefill lands — one small sync per ADMISSION (not per
-        # decode step)
-        _, t1 = self._sync("prefill", tok.block_until_ready)
-        self._first_token(req, t_admit_ns, t1, bucket)
-        monitor.record_generation(prefill_steps=1)
-        self.stats["prefills"] += 1
+        self._seq += 1
+        after = self._mark()
         self._install(req, slot, row_cache, tok, fin)
-        # the blocking prefill sync above must not be attributed to
-        # per-token decode latency: restart the poll window so the next
-        # dispatch re-anchors it (same artifact class as idle gaps)
-        self._window_steps = 0
+        return tok, after
 
     def _table_row(self, pages) -> np.ndarray:
         """A row's page table: its pages in position order; unused
@@ -1049,8 +1134,8 @@ class ServingEngine:
             pages, plan = self._pending_pages[req.id]
             where = (self._table_row(pages),
                      np.int32(max(int(plan.shared_len), installed)))
-        self._cache, self._lanes = self._compiled(("admit",))(
-            self._cache, self._lanes, np.int32(slot), row_cache,
+        self._cache, self._lanes = self._run(
+            ("admit",), self._cache, self._lanes, np.int32(slot), row_cache,
             self._mode.first(req.prompt, req.budget, tok, fin), *where)
         if self._alloc is not None:
             # the row now references its pages; register the prompt's
@@ -1141,8 +1226,8 @@ class ServingEngine:
         C = self.prefill_chunk_tokens
         t_ns = flight_recorder.now_ns() if req.traced else 0
         ids = jnp.asarray(st["ids"][:, k * C:(k + 1) * C])
-        self._row_cache = self._compiled(("chunk", C))(
-            self._state, ids, self._row_cache)
+        self._row_cache = self._run(
+            ("chunk", C), self._state, ids, self._row_cache)
         if self._alloc is not None:
             # commit the chunk's positions into the planned pages now —
             # only the span at/past the shared prefix (and past already
@@ -1150,8 +1235,8 @@ class ServingEngine:
             # waits for the final admit
             start = max(k * C, st["shared"])
             if (k + 1) * C > start:
-                self._cache = self._compiled(("install_span",))(
-                    self._cache, self._row_cache,
+                self._cache = self._run(
+                    ("install_span",), self._cache, self._row_cache,
                     self._table_row(self._pending_pages[req.id][0]),
                     np.int32(start))
         # the chunk must LAND before the host moves on: the sync point
@@ -1185,8 +1270,8 @@ class ServingEngine:
         t_ns = flight_recorder.now_ns() if req.traced else 0
         ids = jnp.asarray(st["ids"][:, k * C:(k + 1) * C])
         plen = jnp.asarray(np.array([st["plen"]], np.int32))
-        tok, row_cache, self._key, fin = self._compiled(
-            ("chunk_final", C))(
+        tok, row_cache, self._key, fin = self._run(
+            ("chunk_final", C),
             self._state, ids, plen, self._key, self._row_cache)
         self._row_cache = row_cache
         # TTFT measurement point — same contract as inline admission
@@ -1195,8 +1280,6 @@ class ServingEngine:
         self._chunk_landed(st, k, t_ns, t1)
         monitor.record_prefill_interleave(
             st["decode_steps"] / st["n"])
-        monitor.record_generation(prefill_steps=1)
-        self.stats["prefills"] += 1
         # every span below the last chunk boundary is already
         # installed: the admit's install_row writes only the final
         # span (start = the later of shared prefix end and the final
@@ -1231,12 +1314,11 @@ class ServingEngine:
         self._note_cost(req)
 
     def _dispatch_decode(self):
-        exe = self._compiled(self._mode.key)
         with flight_recorder.span("serve.dispatch") as sp:
-            self._cache, self._lanes, self._key = exe(
+            self._cache, self._lanes, self._key = self._run(
+                self._mode.key,
                 self._state, self._cache, self._lanes, self._key)
         self._steps_since_poll += 1
-        self._steps_unsynced += 1
         if self._chunking is not None:
             # decode steps interleaved into THIS chunked admission —
             # the serve.prefill.interleave_ratio numerator
@@ -1250,12 +1332,18 @@ class ServingEngine:
         self.stats["decode_steps"] += 1
         monitor.record_generation(decode_steps=1)
 
-    def _read_lanes(self):
-        """The poll's blocking read: the [batch] finished/step lanes and,
-        in the same window, the mode's on-device counters (a few int32
-        scalars — no extra sync cadence)."""
-        return [np.asarray(getattr(self._lanes, n))  # lint: host-sync-ok (scheduler poll, every poll_every steps)
-                for n in ("finished", "steps") + self._mode.counters]
+    def _poll_ahead(self) -> bool:
+        """Whether a poll dispatches the next decode step before its
+        read: when a request that arrives during the read could not be
+        admitted at its return anyway — no slot is free, or the queue's
+        head waits for pages. The chain behind the read (completions,
+        the generator, the next admission's planning and dispatch) then
+        runs while that step does, and a lane the poll frees sits out
+        exactly that one step, masked. With a slot free and nothing
+        blocked the read has nothing behind it, so an arrival is
+        prefilled the moment it returns and its TTFT pays no step."""
+        return (self._alloc is not None and self._page_blocked) \
+            or all(s is not None for s in self._slots)
 
     def _poll(self):
         """Scheduler poll: read the [batch] finished/step lanes (the
@@ -1266,19 +1354,48 @@ class ServingEngine:
 
     def _poll_lanes(self, sp):
         covered, self._steps_since_poll = self._steps_since_poll, 0
-        (fin, steps, *counters), t_ns = self._sync("poll",
-                                                   self._read_lanes)
-        drained = self._mode.drain(counters, self.stats)
+        t_window, n_window = self._window_t0_ns, self._window_steps
+        self._window_steps = 0   # next dispatch re-anchors the window
+        # the [batch] finished/step lanes, the mode's on-device counters,
+        # the cache's kv_len and a quantized cache's counter (a few
+        # int32, in the same read), and the result rows for the lanes
+        # found finished
+        view = programs.poll_view(self._mode, self._cache, self._lanes)
+        if self._poll_ahead():
+            # AT MOST one step ahead of a poll's read. The step donates
+            # the lanes, so the read is of copies made in between
+            view = self._run(("poll_view",), view)
+            after = self._mark()
+            self._dispatch_decode()
+        else:
+            after = self._mark()
+        seen, t_ns = self._sync(
+            "poll", lambda: jax.device_get(view._replace(rows=())), after)  # lint: host-sync-ok (scheduler poll, every poll_every steps)
+        fin, steps = seen.finished, seen.steps
+        if self._window_steps:
+            # the step in flight opens the next window where this read
+            # returned: windows neither overlap nor leave a gap
+            self._window_t0_ns = t_ns
+        rows = None
+
+        def row(i):
+            """Lane ``i``'s result rows: every lane's, behind ONE wait
+            a poll, and never behind the step in flight."""
+            nonlocal rows
+            if rows is None:
+                rows, _ = self._sync(
+                    "row", lambda: jax.device_get(view.rows), after)  # lint: host-sync-ok (one row read per completing poll)
+            return tuple(r[i].copy() for r in rows)
+
+        drained = self._mode.drain(seen.counters, self.stats)
         now = t_ns * 1e-9
         window_dt = 0.0
-        if self._window_t0_ns is not None and self._window_steps:
-            window_dt = (t_ns - self._window_t0_ns) * 1e-9
-            monitor.record_serve_token_latency(
-                window_dt / self._window_steps)
+        if t_window is not None and n_window:
+            window_dt = (t_ns - t_window) * 1e-9
+            monitor.record_serve_token_latency(window_dt / n_window)
             # the dispatch window (host dispatches + the device wait
             # the lane reads above just paid) is goodput compute
             self._goodput.charge("compute", window_dt)
-        self._window_steps = 0   # next dispatch re-anchors the window
         if window_dt > 0.0:
             # cost attribution: every live request owns an equal share
             # of the window the ledger just booked as compute (shares
@@ -1319,14 +1436,15 @@ class ServingEngine:
             emitted += n - req.n_emitted
             req.n_emitted = n
             if fin[i]:
-                row, _ = self._sync("row", lambda: self._read_row(i))
-                self._complete(req, self._mode.cut(req, row, n, False))
+                self._complete(req, self._mode.cut(req, row(i), n, False))
                 completed += 1
                 # freed in place; the next admission overwrites the row
                 self._slots[i] = None  # lint: lock-discipline-ok (poll runs under the caller's pump lock)
                 self._free_slot_pages(i)
             elif req.deadline is not None and now > req.deadline:
-                self._evict(i, req, "deadline", n)
+                # the rows are read BEFORE the free program: it donates
+                # the lanes a poll that did not run ahead reads them from
+                self._evict(i, req, "deadline", n, row(i))
                 evicted += 1
             elif req.traced:
                 # rolling decode segment: one span per poll window, so
@@ -1349,9 +1467,12 @@ class ServingEngine:
         monitor.record_serve_slot_occupancy(
             sum(s is not None for s in self._slots) / self.max_batch)
         if monitor.enabled:
-            monitor.record_cache_occupancy(self._cache.occupancy())
+            # the cache's own occupancy() reads kv_len off the device:
+            # behind the step in flight, were it called here
+            monitor.record_cache_occupancy(
+                float(seen.kv_len.max()) / self._cache.max_len)  # lint: host-sync-ok (host array)
             self._drain_page_stats()
-            self._drain_quant_stats()
+            self._drain_quant_stats(seen.clips)
             self._goodput.flush()
             # SLO watchtower: sample the time-series ring + evaluate
             # burn rates at most once per ring period (fast path is a
@@ -1360,8 +1481,9 @@ class ServingEngine:
 
     def _read_row(self, slot: int):
         """One lane's result rows (the mode's ``row`` lanes, in position
-        order), all behind ONE wait."""
-        return jax.device_get(tuple(  # lint: host-sync-ok (one row read per completion)
+        order), all behind ONE wait, from the lanes as they stand: for
+        an eviction outside a poll (a poll reads its view's)."""
+        return jax.device_get(tuple(  # lint: host-sync-ok (one row read per eviction at the drain's cutoff)
             getattr(self._lanes, n)[slot] for n in self._mode.row))
 
     def _complete(self, req: Request, toks: np.ndarray):
@@ -1391,22 +1513,25 @@ class ServingEngine:
         monitor.record_serve_cancellation(label or reason)
 
     def _evict(self, slot: int, req: Request, reason: str,
-               n_done: int = 0):
+               n_done: int = 0, row=None, label: Optional[str] = None):
         """Cancel an in-flight request: mask its lane + reset its cache
-        row via the free program, keep whatever it produced."""
+        row via the free program, keep whatever it produced (``row``:
+        its result rows as the poll that evicts it read them; ``label``
+        as ``_cancel``'s)."""
         if flight_recorder.enabled:
             flight_recorder.record("serve.evict", req=req.id, slot=slot,
                                    reason=reason, tokens=n_done)
-        self._cache, self._lanes = self._compiled(("free",))(
-            self._cache, self._lanes, np.int32(slot))
+        self._cache, self._lanes = self._run(
+            ("free",), self._cache, self._lanes, np.int32(slot))
         if n_done:
-            row, _ = self._sync("row", lambda: self._read_row(slot))
+            if row is None:
+                row, _ = self._sync("row", lambda: self._read_row(slot))
             req.tokens = self._mode.cut(req, row, n_done, True) \
                 .astype(np.int32)
             req.n_emitted = int(req.tokens.size)
         self._slots[slot] = None  # lint: lock-discipline-ok (eviction runs under the caller's pump lock)
         self._free_slot_pages(slot)
-        self._cancel(req, reason)
+        self._cancel(req, reason, label=label)
         self._note_cost(req)
 
     def _note_cost(self, req: Request):
@@ -1465,16 +1590,18 @@ class ServingEngine:
             cow_copies=delta["cow_copies"])
         monitor.record_page_occupancy(self._alloc.page_occupancy())
 
-    def _drain_quant_stats(self):
+    def _drain_quant_stats(self, clips=None):
         """Drain the quantized cache's in-device saturation counter
-        into ``gen.cache.quant.scale_clips`` (one int32 scalar read at
-        the poll cadence, beside the existing lane reads; the lifetime
-        counter is int32 and may wrap — modular delta, same treatment
-        as the speculation counters)."""
+        into ``gen.cache.quant.scale_clips`` (one int32 scalar, which a
+        poll reads with its lanes and hands in; read here at the
+        drain's end; the lifetime counter is int32 and may wrap —
+        modular delta, same treatment as the speculation counters)."""
         if getattr(self._cache, "clips", None) is None:
             return
-        clips, _ = self._sync(
-            "stats", lambda: int(np.asarray(self._cache.clips)))  # lint: host-sync-ok (scheduler poll, tiny scalar)
+        if clips is None:
+            clips, _ = self._sync(
+                "stats", lambda: np.asarray(self._cache.clips))  # lint: host-sync-ok (drain's end, tiny scalar)
+        clips = int(clips)
         d = (clips - self._clips_seen) % (1 << 32)
         if d:
             self._clips_seen = clips

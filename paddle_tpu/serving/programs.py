@@ -42,7 +42,7 @@ from ..generation.sampling import sample
 from ..generation.speculative import apply_verify_window, ngram_propose
 
 __all__ = ["Program", "Network", "Decode", "Speculative", "BlockDiffusion",
-           "program_table"]
+           "poll_view", "program_table"]
 
 _sds = jax.ShapeDtypeStruct
 _SCALAR = _sds((), jnp.int32)
@@ -253,6 +253,14 @@ def admit_fn(mode, cache, lanes, slot, row_cache, first, *where):
 def free_fn(cache, lanes, slot):
     return (cache.reset_rows(slot),
             lanes._replace(finished=lanes.finished.at[slot].set(True)))
+
+
+def poll_view_fn(view):
+    # what a poll reads, COPIED out of the lanes (and the quantized
+    # cache's counter): the step dispatched behind this program donates
+    # the buffers these came from, and the host reads the copies while
+    # that step runs. A plain return would hand the inputs back.
+    return jax.tree_util.tree_map(jnp.copy, view)
 
 
 def chunk_fn(net, state_vals, ids, row_cache):
@@ -517,11 +525,30 @@ class BlockDiffusion(_StepMode):
 
 # ------------------------------------------------------------ the table
 
+#: what one poll reads: the mode's ``counters`` and ``row`` lanes as tuples,
+#: the cache's ``kv_len`` (the occupancy gauge) and a quantized cache's
+#: saturation counter (None where there is none)
+PollView = collections.namedtuple(
+    "PollView", ("finished", "steps", "counters", "kv_len", "clips", "rows"))
+
+
+def poll_view(mode, cache, lanes) -> PollView:
+    """The live buffers a poll reads when nothing runs behind its read;
+    the ``poll_view`` program's copies of them when a step does: nothing
+    a poll reads may wait for that step."""
+    return PollView(lanes.finished, lanes.steps,
+                    tuple(getattr(lanes, n) for n in mode.counters),
+                    cache.kv_len, getattr(cache, "clips", None),
+                    tuple(getattr(lanes, n) for n in mode.row))
+
+
 def program_table(net: Network, mode, cfg, *, state, cache, lanes, key,
                   buckets, max_len: int, chunk=None,
                   pages_per_row=None) -> Dict[tuple, Program]:
     """Every program the engine can dispatch, by its key, in warm-up
-    order: a prefill per bucket, the mode's step, admit and free, and
+    order: a prefill per bucket, the mode's step, admit and free, the
+    poll's view (the one program that donates nothing and is not part of
+    the cache's round trip: a copy of a few small lanes), and
     with ``chunk`` (tokens a prefill chunk) the chunk pair and, over a
     page pool (``pages_per_row``), the span install. ``state`` is held as
     given (never donated; its placement is the lowering's); the donated
@@ -570,6 +597,9 @@ def program_table(net: Network, mode, cfg, *, state, cache, lanes, key,
         Program(("free",), free_fn,
                 dict(cache=cache, lanes=lanes, slot=_SCALAR),
                 donates=("cache", "lanes"), name="free", report="free"),
+        Program(("poll_view",), poll_view_fn,
+                dict(view=poll_view(mode, cache, lanes)),
+                name="poll_view", report="poll_view"),
     ]
     if chunk is not None:
         # chunk programs: the side cache is the ONLY donated operand —

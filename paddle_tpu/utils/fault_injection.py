@@ -45,9 +45,11 @@ Injectable faults:
                                   ``suspend_worker``).
 - ``fail_admission(engine, n)`` — inject ``n`` consecutive admission
                                   failures into a ServingEngine
-                                  (pre-prefill, so the failed request
-                                  is re-routable; drives the router's
-                                  circuit breaker).
+                                  (pre-prefill by default, so the
+                                  failed request is re-routable: drives
+                                  the router's circuit breaker; or at
+                                  the admit program, or at the deferred
+                                  wait for the prefill's token).
 """
 from __future__ import annotations
 
@@ -414,44 +416,73 @@ class wedge_replica:
 
 class fail_admission:
     """Inject ``n`` consecutive admission failures into a
-    ServingEngine: the next ``n`` requests popped for admission raise
-    at the prefill-executable fetch — BEFORE any prefill dispatch or KV
-    write, so the failed admission is idempotent and a router may
-    re-route the request to another replica. The engine's own handling
-    cancels each doomed handle with an ``admission error: ...`` detail
-    (its Future never hangs); ``triggered`` counts faults actually
+    ServingEngine, at one of three points of an admission (``at``):
+
+    - ``"fetch"`` (default): the next ``n`` requests popped for
+      admission raise at the prefill-executable fetch — BEFORE any
+      prefill dispatch or KV write, so the failed admission is
+      idempotent and a router may re-route the request to another
+      replica;
+    - ``"admit"``: the prefill is dispatched, then the fetch of the
+      admit program raises — the request has left the queue and holds
+      committed pages, but reached no slot;
+    - ``"wait"``: the prefill and the admit program run, and the
+      DEFERRED wait for the prefill's token raises, as a prefill that
+      failed on the device would — the request already sits in its slot
+      and the iteration's decode step is queued behind it.
+
+    The engine's own handling cancels each doomed handle with an
+    ``admission error: ...`` detail (its Future never hangs), returns
+    its pages and frees its slot; ``triggered`` counts faults actually
     fired. Composes with ``KillAfter``/``StoreFaults``::
 
         with fail_admission(engine, n=3):
             ...   # the next 3 admissions on this engine fail
     """
 
-    def __init__(self, engine, n: int = 1):
+    def __init__(self, engine, n: int = 1, at: str = "fetch"):
         if n < 1:
             raise ValueError("fail_admission fires on n >= 1 admissions")
+        if at not in ("fetch", "admit", "wait"):
+            raise ValueError(f"fail_admission at {at!r}: one of 'fetch', "
+                             "'admit', 'wait'")
         self.engine = engine
         self.n = int(n)
+        self.at = at
         self.triggered = 0
         self._orig = None
 
+    def _fire(self):
+        self.triggered += 1
+        raise RuntimeError(
+            f"fail_admission: injected admission failure "
+            f"{self.triggered}/{self.n}")
+
     def __enter__(self) -> "fail_admission":
-        orig = self.engine._exe_prefill
+        # the engine method each point goes through, and when it fires
+        name, fires = {
+            "fetch": ("_exe_prefill", lambda bucket: True),
+            "admit": ("_compiled", lambda key: key == ("admit",)),
+            "wait": ("_sync", lambda site, *_: site == "prefill"),
+        }[self.at]
+        orig = getattr(self.engine, name)
 
-        def flaky(bucket):
-            if self.triggered < self.n:
-                self.triggered += 1
-                raise RuntimeError(
-                    f"fail_admission: injected admission failure "
-                    f"{self.triggered}/{self.n}")
-            return orig(bucket)
+        def flaky(*args):
+            if self.triggered < self.n and fires(*args):
+                if self.at != "wait":
+                    self._fire()
+                # raise inside the read, under its span, where a device
+                # error surfaces
+                args = (args[0], self._fire) + args[2:]
+            return orig(*args)
 
-        self._orig = orig
-        self.engine._exe_prefill = flaky
+        self._orig = (name, orig)
+        setattr(self.engine, name, flaky)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._orig is not None:
-            self.engine._exe_prefill = self._orig
+            setattr(self.engine, *self._orig)
             self._orig = None
         return False
 

@@ -64,7 +64,8 @@ def spec_path(tmp_path_factory):
 
 
 def test_nine_new_readers_are_declared():
-    assert len(SPAN_METRICS) == 9
+    # ISSUE 26's nine and ISSUE 31's sync_covered_share.serve
+    assert len(SPAN_METRICS) == 9 + 1
     for m in SPAN_METRICS:
         assert os.path.exists(
             os.path.join(BENCH, "metrics", m["name"] + ".py")), m["name"]
@@ -136,8 +137,12 @@ def _run(t_proc_ns, t_open_ns, t_close_ns):
         program_median_ms=lambda which: None)
 
 
-def _traffic():
-    """A set-up and a window's worth of spans, by hand."""
+def _traffic(ahead=(1, 0)):
+    """A set-up and a window's worth of spans, by hand. ``ahead``: what
+    the poll's and the row's ``serve.sync`` carry of it (None: the field
+    is not there, as on a program before ISSUE 31)."""
+    def field(value):
+        return {} if value is None else {"ahead": value}
     with fr.span("setup.engine_init"):
         with fr.span("jit.program", label="serving.step") as sp:
             sp.set(source="compile", lower_s=0.0, bytes=0)
@@ -152,8 +157,13 @@ def _traffic():
             with fr.span("serve.dispatch"):
                 pass
             with fr.span("serve.poll") as poll:
-                with fr.span("serve.sync", site="poll", steps_queued=1):
+                with fr.span("serve.sync", site="poll", steps_queued=1,
+                             **field(ahead[0])):
                     pass
+                if i % 2:
+                    with fr.span("serve.sync", site="row", steps_queued=0,
+                                 **field(ahead[1])):
+                        pass
                 poll.set(steps=1, emitted=2, admitted=1)
             step.set(decode=1)
         with fr.span("train.step"):
@@ -179,6 +189,27 @@ def test_reader_reads_a_whole_window(name, recorder, bench_modules):
     assert value is not None and value >= 0
     if name == "poll_lane_occupancy.serve":
         assert value == pytest.approx(100.0 * (2 - 1) / (1 * 2))
+    if name == "sync_covered_share.serve":
+        # 8 poll reads with a step behind them, 4 row reads with none
+        assert value == pytest.approx(100.0 * 8 / 12)
+
+
+@pytest.mark.parametrize("ahead,want", [((1, 1), 100.0), ((0, 0), 0.0),
+                                        ((None, None), None),
+                                        ((2, None), 100.0 * 8 / 12)])
+def test_sync_covered_share_reads_ahead(ahead, want, recorder,
+                                        bench_modules, capsys):
+    """Every site counts; a read with nothing behind it is uncovered
+    whatever it says or leaves unsaid; a program whose ``serve.sync``
+    carries no ``ahead`` at all (the parent, with this file laid over
+    it) gives nothing and does not raise."""
+    t_proc = fr.now_ns()
+    t_open = _traffic(ahead)
+    got = _reader("sync_covered_share.serve").read(
+        _run(t_proc, t_open, fr.now_ns()))
+    assert got == (want if want is None else pytest.approx(want))
+    err = capsys.readouterr().err
+    assert ("by site: poll" in err) == (want is not None)
 
 
 @pytest.mark.parametrize("name", NAMES)

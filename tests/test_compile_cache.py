@@ -269,7 +269,7 @@ def test_warm_restart_gate_serving(tiny_gpt, tmp_path):
     from paddle_tpu.core import monitor
     from paddle_tpu.serving import ServingEngine
     root = str(tmp_path / "exe")
-    n_programs = 2 + 3   # one prefill per bucket + decode/admit/free
+    n_programs = 2 + 4   # a prefill per bucket + decode/admit/free/poll_view
 
     cold_store = ExecutableStore(root)
     cold = ServingEngine(_serve_cfg(tiny_gpt), poll_every=2,

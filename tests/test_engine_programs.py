@@ -66,14 +66,14 @@ def test_every_program_is_written_once(mode, cache):
         table = eng._programs
         _, step_key, step_name = MODES[mode]
         want = {("prefill", 16), ("prefill", 32), step_key, ("admit",),
-                ("free",)}
+                ("free",), ("poll_view",)}
         if cache == "chunked":
             want |= {("chunk", 16), ("chunk_final", 16), ("install_span",)}
         # one table: what warmup() compiled and what audit() reports
         assert set(table) == want == set(eng._exes)
         reports = eng.audit()
         assert set(reports) == {p.report for p in table.values()}
-        assert {"decode", "admit", "free"} <= set(reports)
+        assert {"decode", "admit", "free", "poll_view"} <= set(reports)
         for rep in reports.values():
             rep.raise_on_error()
         # the cache and every lane stay in place across steps and

@@ -746,7 +746,7 @@ def test_paged_audit_gate(tiny_gpt):
                                 kv_page_size=16), warmup=False)
     reports = eng.audit()
     assert set(reports) == {("prefill", 16), ("prefill", 32), "decode",
-                            "admit", "free"}
+                            "admit", "free", "poll_view"}
     for rep in reports.values():
         rep.raise_on_error()
     assert not reports["decode"].by_check("host_sync")

@@ -88,13 +88,16 @@ def test_every_child_lies_inside_its_parent(drained):
     for s in children:
         p = by_id[s.parent]
         assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns, (s, p)
-    # the tree the table in ISSUE 26 draws
+    # the tree the table in ISSUE 26 draws; since ISSUE 31 the wait for
+    # a prefill's token follows the iteration's decode dispatch, outside
+    # its serve.admit
     tree = {(s.name, by_id[s.parent].name) for s in children}
     assert {("serve.admit", "serve.step"),
             ("serve.dispatch", "serve.step"),
             ("serve.poll", "serve.step"),
-            ("serve.sync", "serve.admit"),
+            ("serve.sync", "serve.step"),
             ("serve.sync", "serve.poll")} <= tree
+    assert ("serve.sync", "serve.admit") not in tree
     assert all(s.parent is None for s in spans if s.name == "serve.step")
     # request spans carry their trace id, iteration spans carry none
     for s in spans:
@@ -120,11 +123,14 @@ def test_queue_wait_plus_prefill_is_time_to_first_token(drained):
         # admitted_at / first_token_at ARE the spans' stamps
         assert abs(h.admitted_at * 1e9 - qw.end_ns) < 1e3
         assert abs(h.first_token_at * 1e9 - pf.end_ns) < 1e3
-        # the prefill's sync closes the prefill span, inside its admit
-        syncs = [s for s in spans if s.name == "serve.sync"
-                 and s.fields["site"] == "prefill"
-                 and s.end_ns == pf.end_ns]
-        assert len(syncs) == 1
+        # the prefill's sync closes the prefill span, after its admit
+        (sync,) = [s for s in spans if s.name == "serve.sync"
+                   and s.fields["site"] == "prefill"
+                   and s.end_ns == pf.end_ns]
+        (admit,) = [s for s in spans if s.name == "serve.admit"
+                    and s.fields["req"] == h.id]
+        if "chunks" not in admit.fields:
+            assert admit.end_ns <= sync.start_ns
     admits = [s for s in spans if s.name == "serve.admit"]
     assert sorted(s.fields["req"] for s in admits) == \
         sorted(h.id for h in handles)
@@ -151,16 +157,26 @@ def test_poll_emitted_adds_up_to_what_the_requests_emitted(drained):
         == dispatched == eng.stats["decode_steps"]
     decoded = want - len(handles)
     assert 0 < decoded <= dispatched * eng.max_batch
-    # what a sync waits behind: decode steps dispatched since the last
+    # what a sync waits behind: the decode steps dispatched before the
+    # program it waits for that no earlier sync saw land. A poll's read
+    # never waits behind more than the steps the poll covers, and the
+    # step a full engine dispatches ahead of the read is not among them
+    by_id = {s.id: s for s in spans}
     poll_syncs = [s for s in spans if s.name == "serve.sync"
                   and s.fields["site"] == "poll"]
     assert len(poll_syncs) == len(polls)
-    assert all(0 <= s.fields["steps_queued"] <= eng.poll_every
-               for s in poll_syncs)
+    for s in poll_syncs:
+        assert 0 <= s.fields["steps_queued"] \
+            <= by_id[s.parent].fields["steps"] <= eng.poll_every
+    # one read of the result rows a poll that completed a lane, behind
+    # the same program as the poll's own read: nothing more to wait for
     rows = [s for s in spans if s.name == "serve.sync"
             and s.fields["site"] == "row"]
-    assert len(rows) == len(handles)
+    assert [by_id[s.parent].id for s in rows] == \
+        [p.id for p in polls if p.fields["completed"]]
     assert all(s.fields["steps_queued"] == 0 for s in rows)
+    assert sum(s.fields["steps_queued"] for s in spans
+               if s.name == "serve.sync") == dispatched
 
 
 def test_progress_has_a_public_reader():
@@ -235,8 +251,8 @@ def test_setup_spans_and_a_compile_after_warmup():
     eng._warm = True
     warm = _named("jit.program")
     assert sorted(s.fields["label"] for s in warm) == [
-        "serving.admit", "serving.free", "serving.prefill.8",
-        "serving.step"]
+        "serving.admit", "serving.free", "serving.poll_view",
+        "serving.prefill.8", "serving.step"]
     assert all(s.fields["source"] in ("store", "persistent_cache",
                                       "compile")
                and s.fields["lower_s"] >= 0 for s in warm)
@@ -262,7 +278,7 @@ def test_warmup_is_one_span_over_its_programs():
     (warm,) = _named("setup.warmup")
     assert warm.parent == init.id
     progs = _named("jit.program")
-    assert len(progs) == len(eng._exes) == 5
+    assert len(progs) == len(eng._exes) == 6
     assert all(p.parent == warm.id for p in progs)
     eng.shutdown()
 

@@ -341,7 +341,7 @@ def test_serving_audit_gate(tiny_gpt):
                                 max_batch=2), warmup=False)
     reports = eng.audit()
     assert set(reports) == {("prefill", 16), ("prefill", 32), "decode",
-                            "admit", "free"}
+                            "admit", "free", "poll_view"}
     for rep in reports.values():
         rep.raise_on_error()
     assert not reports["decode"].by_check("host_sync")

@@ -621,7 +621,7 @@ def test_engine_speculative_audit_gate(tiny_gpt):
     eng = ServingEngine(_spec_config(tiny_gpt), warmup=False)
     reports = eng.audit()
     assert set(reports) == {("prefill", 16), ("prefill", 32), "decode",
-                            "admit", "free"}
+                            "admit", "free", "poll_view"}
     for rep in reports.values():
         rep.raise_on_error()
     assert not reports["decode"].by_check("host_sync")
