@@ -171,8 +171,11 @@ DECLARED_SPANS = {
                    "the wait for the prefill's token follows the "
                    "iteration's decode dispatch, a serve.sync under "
                    "serve.step "
-                   "(req, slot, bucket, prompt; chunks when the prefill is "
-                   "chunked: the span then covers the reservation only)",
+                   "(req, slot, bucket, prompt; state_bytes = bytes of "
+                   "per-lane state the admit program installs beside the "
+                   "KV row, 0 for a model that keeps none; chunks when the "
+                   "prefill is chunked: the span then covers the "
+                   "reservation only)",
     "serve.sync": "one blocking device read (site = prefill / chunk / "
                   "poll / row / stats; steps_queued = decode steps "
                   "dispatched before the program it waits for and not "
@@ -188,7 +191,9 @@ DECLARED_SPANS = {
                   "since the last poll, admitted = lanes polled for the "
                   "first time, completed, evicted, live; under block "
                   "diffusion also forwards = lane-forwards and commits = "
-                  "blocks committed since the last poll)",
+                  "blocks committed since the last poll; in every step "
+                  "mode moe_rows = rows the dropless expert layers "
+                  "computed since the last poll, where there are any)",
     "serve.queue_wait": "every request: submit -> popped from the queue, "
                         "which is its admitted_at whether or not the "
                         "prefill then succeeds (trace id; req, bucket; "
@@ -201,7 +206,10 @@ DECLARED_SPANS = {
                    "setup.engine_init",
     "setup.cache_alloc": "host-built KV cache and lane buffers and "
                          "their device_put inside setup.engine_init "
-                         "(bytes; the transfer is not awaited)",
+                         "(bytes = cache and lanes; kv_bytes = the KV half "
+                         "of the cache, pages or rows, tables and lengths; "
+                         "state_bytes = per-lane state a hybrid cache holds "
+                         "beside it; the transfer is not awaited)",
     "setup.warmup": "ServingEngine.warmup(): every program the "
                     "scheduler can dispatch",
     "jit.program": "one AOT program built or loaded by jit.compile_cache"

@@ -55,6 +55,9 @@ Metric name scheme (what the summary views group by):
     moe.rows / moe.expert_rows_max   dropless expert layers: (token,
                                 expert) rows computed, and the busiest
                                 expert's rows, summed over layers and steps
+                                of every step mode's forwards (decode,
+                                speculative, block diffusion; drained at
+                                each poll)
     serve.requests{status=...}  terminal request outcomes (completed/
                                 cancelled/rejected) — QPS = rate of this
     serve.queue_depth           gauge: requests waiting for a slot
@@ -285,7 +288,8 @@ METRIC_DOC = {
                               "next block"),
     "moe.rows": ("counter", (),
                  "(token, expert) rows the dropless expert layers "
-                 "computed, summed over layers and steps"),
+                 "computed, summed over layers and the steps of "
+                 "whichever step mode serves (drained at each poll)"),
     "moe.expert_rows_max": ("counter", (),
                             "rows of the busiest expert, summed over "
                             "layers and steps (x experts / moe.rows = "

@@ -173,16 +173,25 @@ class MoEMLP(Layer):
         return y.reshape(shape), aux
 
 
+ROUTER_KINDS = ("softmax", "sigmoid")
+
+
 def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
-                 norm_topk_prob: bool = True):
+                 norm_topk_prob: bool = True, router: str = "softmax",
+                 select_bias=None, scaling: float = 1.0):
     """Pure-jax body of :class:`DroplessMoE` on raw arrays.
 
     x [T, H]; router_w [H, E]; w_gate_up [E, H, 2F] (gate then up);
     w_down [E, F, H]. Returns (y [T, H], rows [E] int32: how many
     (token, expert) rows each expert computed).
 
-    Router: softmax over ALL experts in float32, the ``top_k`` largest,
-    renormalised over the chosen (``norm_topk_prob``). Experts: the
+    Router, ``"softmax"``: softmax over ALL experts in float32, the
+    ``top_k`` largest, renormalised over the chosen (``norm_topk_prob``).
+    ``"sigmoid"``: a sigmoid score per expert; the ``top_k`` largest of
+    ``score + select_bias`` ([E], a bias that takes part in the SELECTION
+    only) are chosen, their weights are the scores WITHOUT the bias,
+    divided by (their sum + 1e-6) under ``norm_topk_prob``, times
+    ``scaling``. Experts: the
     T * top_k (token, expert) rows sorted by expert, one grouped product
     for gate+up, SiLU(gate) * up, one for down, then each token's k rows
     weighted and summed in float32. Nothing is dropped; an expert no
@@ -196,9 +205,19 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
         logits = jnp.matmul(x.astype(jnp.float32),
                             router_w.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-        if norm_topk_prob:
-            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        if router == "softmax":
+            top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            if norm_topk_prob:
+                top = top / jnp.sum(top, axis=-1, keepdims=True)
+        else:
+            score = jax.nn.sigmoid(logits)
+            biased = score if select_bias is None \
+                else score + select_bias.astype(jnp.float32)
+            _, idx = jax.lax.top_k(biased, top_k)
+            top = jnp.take_along_axis(score, idx, axis=-1)
+            if norm_topk_prob:
+                top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-6)
+            top = top * scaling
     with jax.named_scope("moe_experts"):
         flat = idx.reshape(-1)                          # [T*k] expert ids
         order = jnp.argsort(flat, stable=True)          # rows by expert
@@ -218,6 +237,9 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
 class DroplessMoE(Layer):
     """Dropless sparse-expert FFN: ``num_experts`` SiLU-gated experts of
     width ``d_expert``, ``top_k`` a token, no shared expert, no drops.
+    ``router``: ``"softmax"``, or ``"sigmoid"`` with an optional
+    per-expert ``select_bias`` parameter and a ``scaling`` factor
+    (:func:`dropless_moe` states both).
 
     Holds the experts stacked: ``gate_up`` [E, d_model, 2 * d_expert]
     (gate then up, side by side so one grouped product feeds both) and
@@ -228,18 +250,32 @@ class DroplessMoE(Layer):
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int, norm_topk_prob: bool = True,
                  std: float = 0.02, down_std: Optional[float] = None,
-                 dtype=None):
+                 dtype=None, router: str = "softmax",
+                 select_bias: bool = False, scaling: float = 1.0):
         # dtype: the stacked experts are nearly all of a sparse model;
         # built in float32 first, a model served in bfloat16 on one chip
         # would not fit beside its own cast
         super().__init__()
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"top_k {top_k} outside [1, {num_experts}]")
+        if router not in ROUTER_KINDS:
+            raise ValueError(f"unknown router kind {router!r}: one of "
+                             f"{ROUTER_KINDS}")
+        if select_bias and router != "sigmoid":
+            raise ValueError("a selection bias belongs to the sigmoid "
+                             "router: softmax weights ARE what is ranked")
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = bool(norm_topk_prob)
+        self.router_kind, self.scaling = router, float(scaling)
         self.router = self.create_parameter(
             (d_model, num_experts), default_initializer=I.Normal(0.0, std))
         self.router.spec = P()
+        # ranks the experts and weighs nothing (the sigmoid kind only)
+        self.select_bias = None
+        if select_bias:
+            self.select_bias = self.create_parameter(
+                (num_experts,), default_initializer=I.Constant(0.0))
+            self.select_bias.spec = P()
         self.gate_up = self.create_parameter(
             (num_experts, d_model, 2 * d_expert), dtype=dtype,
             default_initializer=I.Normal(0.0, std))
@@ -252,12 +288,14 @@ class DroplessMoE(Layer):
 
     def forward(self, x):
         shape = x.shape
+        bias = () if self.select_bias is None else (self.select_bias,)
         y, rows = _dispatch(
             "dropless_moe",
-            lambda x_, r, gu, dn: dropless_moe(
+            lambda x_, r, gu, dn, *b: dropless_moe(
                 x_.reshape(-1, shape[-1]), r, gu, dn, self.top_k,
-                self.norm_topk_prob),
-            (x, self.router, self.gate_up, self.down), {})
+                self.norm_topk_prob, self.router_kind, *b,
+                scaling=self.scaling),
+            (x, self.router, self.gate_up, self.down) + bias, {})
         self.rows = rows
         return y.reshape(shape)
 

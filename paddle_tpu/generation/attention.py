@@ -125,10 +125,15 @@ def _cached_attention(q, k, v, cache, layer_idx, decode, causal, attn_mask,
                 **(dict(k_scale=sc[0], v_scale=sc[1]) if sc else {})),
             (q, cache.k[layer_idx], cache.v[layer_idx], mask_len)
             + scales, {}, differentiable=False)
-    elif block is not None:
+    elif block is not None or (causal and attn_mask is None
+                               and k.shape[2] != q.shape[2]):
+        # grouped kv heads under the plain causal mask take the same
+        # path: a block of 1 is causal, and it repeats the kv heads (or
+        # hands them to the flash kernel, which does)
         out = dispatch(
             "block_causal_attention",
-            lambda q_, k_, v_: block_causal_attention(q_, k_, v_, block),
+            lambda q_, k_, v_: block_causal_attention(q_, k_, v_,
+                                                      block or 1),
             (q, k, v), {}, differentiable=False)
     else:
         out = F.scaled_dot_product_attention(

@@ -278,6 +278,22 @@ class KVCache:
             kv_len = jnp.broadcast_to(kv_len, (self.batch,))
         return KVCache(self.k, self.v, kv_len)
 
+    def paged(self, n_pages: int, page_size: int, pages_per_row: int):
+        """The avals of the page pool that serves these dense rows
+        (``self`` may itself be avals: only shapes and dtypes are read):
+        layers, heads, head_dim and dtype stay, the ``batch`` rows of
+        ``max_len`` become ``n_pages`` pages of ``page_size`` and a
+        ``[batch, pages_per_row]`` table. The serving engine builds its
+        cache from what the model's prefill returns through this, so a
+        cache kind states its own paged form."""
+        from .paged_cache import PagedKVCache, pool_head_dim
+        sds = jax.ShapeDtypeStruct
+        L, B, _, H, D = self.k.shape
+        pool = (L, n_pages, H, page_size, pool_head_dim(D))
+        return PagedKVCache(sds(pool, self.k.dtype), sds(pool, self.v.dtype),
+                            sds((B, pages_per_row), jnp.int32),
+                            sds((B,), jnp.int32))
+
     # --------------------------------------------------------- telemetry
     def occupancy(self) -> float:
         """Host-side fraction of the cache in use (max over rows) — the
@@ -398,6 +414,18 @@ class QuantKVCache(KVCache):
             kv_len = jnp.broadcast_to(kv_len, (self.batch,))
         return QuantKVCache(self.k, self.v, kv_len, self.k_scale,
                             self.v_scale, self.clips)
+
+    def paged(self, n_pages: int, page_size: int, pages_per_row: int):
+        """Value pages + their bf16 scale pages (the scales live IN the
+        page, so prefix sharing / COW / reclaim carry them for free) +
+        the saturation counter."""
+        from .paged_cache import QuantPagedKVCache
+        sds = jax.ShapeDtypeStruct
+        wide = KVCache.paged(self, n_pages, page_size, pages_per_row)
+        scales = sds(wide.k.shape[:-1], jnp.bfloat16)
+        return QuantPagedKVCache(wide.k, wide.v, wide.page_table,
+                                 wide.kv_len, scales, scales,
+                                 sds((), jnp.int32))
 
     def __repr__(self):
         return (f"QuantKVCache(layers={self.num_layers}, "

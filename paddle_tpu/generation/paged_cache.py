@@ -79,6 +79,29 @@ __all__ = ["PagedKVCache", "QuantPagedKVCache", "PageAllocator",
            "AdmissionPlan"]
 
 
+def pool_head_dim(head_dim: int) -> int:
+    """The pools' minor dimension for heads of ``head_dim``: heads of 64
+    are stored in 128 lanes, the upper half zero. The TPU tiles an
+    array's two minor dimensions in (sublanes, 128 lanes): it lays a
+    ``[.., 128, 64]`` array out with the 64 SECOND-minor (no lane is
+    wasted that way), the paged decode kernel wants ``head_dim`` minor
+    (in 128-lane tiles: it reads 128 lanes a row whatever they hold),
+    and the copy between the two layouts is the whole pool, K and V, in
+    every attention layer of every step (1.6 GB of scratch and 8.6 ms a
+    step at 3 layers of 8 heads: PERF.md section 6, PR 32). Padded, the
+    array the kernel reads is the array the program holds. Other widths
+    below a lane tile never reach that kernel and stay as they are."""
+    return 128 if head_dim == 64 else head_dim
+
+
+def _to_pool_width(new, buf):
+    """``new`` ([..., head_dim]) zero-padded to the pool's lanes."""
+    short = buf.shape[-1] - new.shape[-1]
+    if short == 0:
+        return new
+    return jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, short),))
+
+
 def _scatter_tokens(buf, layer: int, page, off, new):
     """Write ``new`` ([batch, s, heads, ...]) into ``buf`` ([layers,
     n_pages, heads, page_size, ...]) at ``(layer, page[i], :, off[i])``
@@ -86,6 +109,8 @@ def _scatter_tokens(buf, layer: int, page, off, new):
     indices, not spanned by the window (see the module docstring)."""
     heads = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :]
     flat = new.reshape((-1,) + new.shape[2:]).astype(buf.dtype)
+    if buf.ndim == 5:       # values (a scale sidecar has no head_dim)
+        flat = _to_pool_width(flat, buf)
     return buf.at[layer, page[:, None], heads, off[:, None]].set(flat)
 
 
@@ -101,6 +126,8 @@ def _install_pages(buf, rows, page, valid):
     6.7B widths and took 68 ms on the v5e: PERF.md section 6, PR 27.)"""
     n, ps = page.shape[0], buf.shape[3]
     rows = rows[:, 0]
+    if buf.ndim == 5:       # values (a scale sidecar has no head_dim)
+        rows = _to_pool_width(rows, buf)
     new = jnp.pad(rows, ((0, 0), (0, n * ps - rows.shape[1]))
                   + ((0, 0),) * (rows.ndim - 2))
     new = new.reshape((rows.shape[0], n, ps) + rows.shape[2:])
@@ -179,7 +206,8 @@ class PagedKVCache:
                page_size: int, pages_per_row: int, num_heads: int,
                head_dim: int, dtype=jnp.float32,
                cache_dtype=None) -> "PagedKVCache":
-        shape = (num_layers, n_pages, num_heads, page_size, head_dim)
+        shape = (num_layers, n_pages, num_heads, page_size,
+                 pool_head_dim(head_dim))
         if validate_cache_dtype(cache_dtype) is not None:
             sshape = shape[:-1]
             return QuantPagedKVCache(

@@ -1590,13 +1590,20 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
     wc = {} if window_causal else {"window_causal": False}
     use_pallas = (jax.default_backend() == "tpu"
                   and ps % 128 == 0 and d in (64, 128, 256))
+    # a pool wider than the heads (``paged_cache.pool_head_dim``: heads
+    # of 64 stored in 128 lanes, the rest zero): the kernel runs at the
+    # pool's width on a query padded with zeros, which adds nothing to a
+    # score, and the lanes of the result past ``d`` are V's zeros
+    wide = key_pool.shape[-1] - d
     if use_pallas:
+        if wide:
+            query = jnp.pad(query, ((0, 0),) * 3 + ((0, wide),))
         # the query heads of a kv head ride the rows of its tile in
         # both windows: the causal one tells them apart by row % sq
         return _unfold_group(_paged_decode_pallas(
             _fold_group(query, hk), key_pool, value_pool, page_table,
             kv_len, float(scale), layer, sq, k_scale=k_scale,
-            v_scale=v_scale, **wc), sq)
+            v_scale=v_scale, **wc), sq)[..., :d]
     if window_causal:
         qt = jnp.swapaxes(query, 1, 2).reshape(b * hq, sq, d)
     else:
@@ -1616,7 +1623,9 @@ def flash_attention_decode_paged(query, key_pool, value_pool,
 
     def rows(pool):  # [b, slots, hk, ps, ...] -> [b * hk, t, ...]
         g = jnp.swapaxes(pool[layer, page_table], 1, 2)
-        return g.reshape((b * hk, t) + pool.shape[4:])
+        if wide and pool.ndim == 5:     # the heads' own lanes alone
+            g = g[..., :d]
+        return g.reshape((b * hk, t) + g.shape[4:])
 
     return unflatten(_decode_xla(
         qt, rows(key_pool), rows(value_pool), jnp.repeat(kv_len, hk),

@@ -455,28 +455,35 @@ class ServingEngine:
                     net, s, i, p, k, self._cfg, self.max_len),
                 self._state, sds((B, buckets[0]), jnp.int32),
                 sds((B,), jnp.int32), self._key)[1]
+            # a model whose cache holds per-lane state beside its KV
+            # (generation/hybrid_cache.py): what cannot carry a state yet
+            # is refused here, with the reason, not served wrong
+            if getattr(cache_aval, "state", None) is not None:
+                refusal = (
+                    (self._spec is not None,
+                     "speculative decoding rolls a lane's cache back to "
+                     "the accepted length after every verify window"),
+                    (self._chunk_enabled,
+                     "chunked prefill hands a side cache from chunk to "
+                     "chunk and installs it in spans"))
+                for on, why in refusal:
+                    if on:
+                        raise ValueError(
+                            f"{why}; the model's cache holds per-lane "
+                            "state of a fixed width beside its KV "
+                            f"({cache_aval!r}), which can only move "
+                            "forward: serve it by plain decode with "
+                            "inline prefill")
             with flight_recorder.span("setup.cache_alloc") as alloc_sp:
                 quant = getattr(cache_aval, "k_scale", None) is not None
                 if self._alloc is not None:
-                    # paged pool: layers/heads/head_dim/dtype from the dense
-                    # prefill aval, rows replaced by the page pool + tables;
-                    # int8: value pages + their bf16 scale pages (the scales
-                    # live IN the page, so prefix sharing / COW / reclaim
-                    # carry them for free) + the saturation counter
-                    from ..generation.paged_cache import (PagedKVCache,
-                                                          QuantPagedKVCache)
-                    L, _, _, H, D = cache_aval.k.shape
-                    pool = (L, self._alloc.n_pages, H, self.page_size, D)
-                    pages = (sds(pool, cache_aval.k.dtype),
-                             sds(pool, cache_aval.v.dtype),
-                             sds((B, self.pages_per_row), np.int32),
-                             sds((B,), np.int32))
-                    if quant:
-                        scales = sds(pool[:-1], jnp.bfloat16)
-                        cache_aval = QuantPagedKVCache(
-                            *pages, scales, scales, sds((), np.int32))
-                    else:
-                        cache_aval = PagedKVCache(*pages)
+                    # paged pool: the cache kind the model's prefill
+                    # returned states its own paged form (layers / heads /
+                    # head_dim / dtype kept, rows replaced by the page pool
+                    # + tables; whatever else it holds a lane kept as is)
+                    cache_aval = cache_aval.paged(
+                        self._alloc.n_pages, self.page_size,
+                        self.pages_per_row)
                 self._cache = _host_zeros(cache_aval)
                 # the low-bit accounting satellites: the kv_dtype info gauge
                 # (what this engine serves — the router reads it beside the
@@ -509,9 +516,16 @@ class ServingEngine:
                 self._lanes = jax.device_put(self._mode.lanes(B, cap))
                 # bytes handed to device_put; the transfer is not awaited
                 # here (the first program that reads them waits for it)
-                alloc_sp.set(bytes=sum(
-                    int(a.nbytes) for a in jax.tree_util.tree_leaves(
-                        (self._cache, self._lanes))))
+                def nbytes(tree):
+                    return sum(int(a.nbytes)
+                               for a in jax.tree_util.tree_leaves(tree))
+                state_bytes = getattr(self._cache, "state_bytes", 0)
+                # what one admission installs of it: the slot's row
+                self._state_row_bytes = state_bytes // B
+                alloc_sp.set(
+                    bytes=nbytes((self._cache, self._lanes)),
+                    kv_bytes=nbytes(self._cache) - state_bytes,
+                    state_bytes=state_bytes)
 
             self._programs = programs.program_table(
                 net, self._mode, self._cfg, state=self._state,
@@ -1077,7 +1091,8 @@ class ServingEngine:
         bucket = next(b for b in self.buckets if b >= req.prompt.size)
         sp = flight_recorder.span("serve.admit", req=req.id, slot=slot,
                                   bucket=bucket,
-                                  prompt=int(req.prompt.size))
+                                  prompt=int(req.prompt.size),
+                                  state_bytes=self._state_row_bytes)
         t0 = 0
         try:
             with sp:

@@ -173,8 +173,19 @@ def prefill_fn(net, state_vals, ids, plen, key, cfg, cache_len):
     return _first_token(logits, cache, key, cfg)
 
 
+def _count_routing(net, moe_counters):
+    """``moe_counters`` plus this forward's routing through the layer's
+    dropless expert layers: rows computed and the busiest expert's,
+    summed over layers (unchanged where the layer has none)."""
+    from ..distributed.parallel.moe import routing_stats
+    moe = routing_stats(net.layer)
+    if moe is None:
+        return moe_counters
+    return moe_counters + jnp.stack(moe).astype(jnp.int32)
+
+
 def step_fn(net, state_vals, cache, lanes, key, cfg):
-    tok, finished, steps, budget, out_buf = lanes
+    tok, finished, steps, budget, out_buf, moe_counters = lanes
     logits, cache = net(state_vals, tok[:, None], cache=cache)
     logits = logits[:, -1].astype(jnp.float32)
     k0, k1 = jax.random.split(key)
@@ -193,12 +204,13 @@ def step_fn(net, state_vals, cache, lanes, key, cfg):
     # the ring nor walks the position table out of range while
     # it waits for its next admission
     cache = cache.with_kv_len(jnp.where(finished, 0, cache.kv_len))
-    return cache, DecodeLanes(nxt, finished, steps, budget, out_buf), k1
+    return cache, DecodeLanes(nxt, finished, steps, budget, out_buf,
+                              _count_routing(net, moe_counters)), k1
 
 
 def spec_step_fn(net, state_vals, cache, lanes, key, cfg, spec):
-    (tok, finished, steps, budget, out_buf, tok_buf, tok_len, proposed,
-     accepted) = lanes
+    (tok, finished, steps, budget, out_buf, moe_counters, tok_buf, tok_len,
+     proposed, accepted) = lanes
     draft = ngram_propose(tok_buf, tok_len, k=spec.k, n=spec.ngram)
     window = jnp.concatenate([tok[:, None], draft], axis=1)
     logits, cache = net(state_vals, window, cache=cache)
@@ -212,12 +224,12 @@ def spec_step_fn(net, state_vals, cache, lanes, key, cfg, spec):
         logits, draft, k0, cfg, spec, tok, cache, finished, steps, budget,
         out_buf, tok_buf, tok_len, proposed, accepted,
         pin_finished_kv=True)
-    return cache, SpecLanes(tok, finished, steps, budget, out_buf, tok_buf,
+    return cache, SpecLanes(tok, finished, steps, budget, out_buf,
+                            _count_routing(net, moe_counters), tok_buf,
                             tok_len, proposed, accepted), k1
 
 
 def block_step_fn(net, state_vals, cache, lanes, key, bd):
-    from ..distributed.parallel.moe import routing_stats
     (finished, steps, budget, out_buf, ustep_buf, blk, blk_step, out0,
      counters, moe_counters) = lanes
     kv0 = cache.kv_len
@@ -227,15 +239,10 @@ def block_step_fn(net, state_vals, cache, lanes, key, bd):
      counters) = apply_block_step(
         logits, bd, cache, kv0, finished, steps, budget, out_buf,
         ustep_buf, blk, blk_step, out0, counters)
-    # a dropless expert layer's routing of this forward: rows
-    # computed and the busiest expert's, summed over layers
-    moe = routing_stats(net.layer)
-    if moe is not None:
-        moe_counters = moe_counters + jnp.stack(moe).astype(jnp.int32)
     # block diffusion draws nothing: the key goes through as it came
     return cache, BlockLanes(finished, steps, budget, out_buf, ustep_buf,
                              blk, blk_step, out0, counters,
-                             moe_counters), key
+                             _count_routing(net, moe_counters)), key
 
 
 def admit_fn(mode, cache, lanes, slot, row_cache, first, *where):
@@ -297,8 +304,12 @@ def install_span_fn(cache, row_cache, table_row, start):
 
 # ---------------------------------------------------------- step modes
 
+# ``moe_counters``: the experts' rows and busiest-expert rows of every
+# forward so far, two int32 the poll drains into moe.* (they stay 0
+# where the layer has no dropless expert layer)
 DecodeLanes = collections.namedtuple(
-    "DecodeLanes", ("tok", "finished", "steps", "budget", "out_buf"))
+    "DecodeLanes", ("tok", "finished", "steps", "budget", "out_buf",
+                    "moe_counters"))
 # drafter lanes: per-slot token history (prompt + emitted, the n-gram
 # lookup corpus) and the on-device proposed/accepted counters the poll
 # drains into gen.spec.*
@@ -371,11 +382,21 @@ class _StepMode:
         ``partial``: the lane was evicted before it finished."""
         return row[0][:n]
 
+    @staticmethod
+    def _book_routing(rows: int, rows_max: int) -> dict:
+        """The drained ``moe_counters`` into moe.*; what a ``serve.poll``
+        shows of them (nothing where no expert layer ran)."""
+        if not rows:
+            return {}
+        monitor.record_moe_routing(rows, rows_max)
+        return {"moe_rows": rows}
+
 
 class Decode(_StepMode):
     """Plain decode: every live lane takes one sampled token a step."""
     key = ("step",)
     step_fn = staticmethod(step_fn)
+    counters = ("moe_counters",)
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -383,7 +404,11 @@ class Decode(_StepMode):
 
     def lanes(self, batch, cap):
         return DecodeLanes(_zeros(batch), np.ones((batch,), bool),
-                           _zeros(batch), _zeros(batch), _zeros(batch, cap))
+                           _zeros(batch), _zeros(batch), _zeros(batch, cap),
+                           _zeros(2))
+
+    def _book(self, stats, rows, rows_max):
+        return self._book_routing(rows, rows_max)
 
     def first(self, prompt, budget, tok, fin):
         return {"tok": tok, "fin": fin, "budget": np.int32(budget)}
@@ -407,7 +432,7 @@ class Speculative(Decode):
     forward; every live lane advances 1..k+1 tokens a step."""
     key = ("spec_step",)
     step_fn = staticmethod(spec_step_fn)
-    counters = ("proposed", "accepted")
+    counters = Decode.counters + ("proposed", "accepted")
 
     def __init__(self, cfg, spec, max_len: int):
         super().__init__(cfg)
@@ -437,12 +462,12 @@ class Speculative(Decode):
         return lanes._replace(tok_buf=lanes.tok_buf.at[slot].set(row),
                               tok_len=lanes.tok_len.at[slot].set(plen + 1))
 
-    def _book(self, stats, proposed, accepted):
+    def _book(self, stats, rows, rows_max, proposed, accepted):
         if proposed or accepted:
             stats["spec_proposed"] += proposed
             stats["spec_accepted"] += accepted
             monitor.record_speculative(proposed, accepted)
-        return {}
+        return self._book_routing(rows, rows_max)
 
 
 class BlockDiffusion(_StepMode):
@@ -509,8 +534,8 @@ class BlockDiffusion(_StepMode):
         stats["diffusion_forwards"] += forwards
         stats["diffusion_commits"] += commits
         monitor.record_block_diffusion(forwards, unmasked, commits)
-        monitor.record_moe_routing(rows, rows_max)
-        return {"forwards": forwards, "commits": commits}
+        return dict(self._book_routing(rows, rows_max),
+                    forwards=forwards, commits=commits)
 
     def cut(self, req, row, n, partial):
         toks, usteps = row

@@ -15,6 +15,7 @@ admit/decode/free trio with a seeded regression, and the chaos
 SIGTERM drain with shared pages live (free-list conserved).
 """
 import dataclasses
+import importlib
 import time
 
 import numpy as np
@@ -813,3 +814,51 @@ def test_sigterm_mid_serve_with_shared_pages_conserves(tiny_gpt):
     assert eng._alloc.used_pages() == 0
     with pytest.raises(RuntimeError, match="shut down"):
         eng.submit(traffic[0])
+
+
+@pytest.mark.parametrize("cache_dtype", [None, "int8"])
+def test_heads_of_64_live_in_128_lanes_and_serve_the_dense_result(
+        cache_dtype):
+    """``pool_head_dim``: a pool of heads of 64 is 128 lanes wide, the
+    upper half zero (on the TPU the kernel then reads the pool where it
+    lies), and install, token write and decode give bit for bit what
+    the dense cache gives."""
+    from paddle_tpu.generation.paged_cache import pool_head_dim
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    assert [pool_head_dim(d) for d in (16, 64, 128, 256)] \
+        == [16, 128, 128, 256]
+    L, B, H, D, ps, P = 2, 3, 2, 64, 8, 4
+    rng = np.random.default_rng(0)
+    paged = PagedKVCache.create(L, B, 1 + B * P, ps, P, H, D,
+                                cache_dtype=cache_dtype)
+    assert paged.k.shape == (L, 1 + B * P, H, ps, 128)
+    dense = []
+    for r, n in enumerate((5, 17, 9)):
+        src = KVCache.create(L, 1, ps * P, H, D, cache_dtype=cache_dtype)
+        k = jnp.asarray(rng.normal(size=(1, n, H, D)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(1, n, H, D)), jnp.float32)
+        for layer in range(L):
+            src = src.update(layer, k + layer, v - layer, 0)
+        src = src.with_kv_len(n)
+        table = jnp.asarray(1 + r * P + np.arange(P), jnp.int32)
+        paged = paged.install_row(src, r, table, 0)
+        dense.append(src)
+    kn = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, 1, 4, D)), jnp.float32)
+    paged = paged.update(1, kn, vn, paged.kv_len)
+    sc = dict(k_scale=paged.k_scale, v_scale=paged.v_scale) \
+        if cache_dtype else {}
+    out = fa.flash_attention_decode_paged(
+        q, paged.k, paged.v, paged.page_table, paged.kv_len + 1, 1, **sc)
+    assert out.shape == (B, 1, 4, D)
+    for r, src in enumerate(dense):
+        row = src.update(1, kn[r:r + 1], vn[r:r + 1], src.kv_len)
+        sc = dict(k_scale=row.k_scale[1], v_scale=row.v_scale[1]) \
+            if cache_dtype else {}
+        want = fa.flash_attention_decode(q[r:r + 1], row.k[1], row.v[1],
+                                         row.kv_len + 1, **sc)
+        np.testing.assert_array_equal(np.array(out[r:r + 1]),
+                                      np.array(want))
+    assert not np.array(paged.k[..., D:]).any()
+    assert not np.array(paged.v[..., D:]).any()

@@ -273,6 +273,67 @@ def test_grouped_expert_products(one_chip, as_on_tpu):
     assert text.count("ragged-dot") >= 2 and KERNEL in text
 
 
+# ---- a hybrid MoE at the published widths of its cell: 32 query heads
+# over 8 kv heads of 64 in 3 attention layers, 32 experts of 1792 top-4
+# behind a sigmoid router with a selection bias, 128 lanes
+
+def test_paged_decode_d64_grouped_at_the_cells_shapes(one_chip, as_on_tpu):
+    """The paged decode kernel's first use at heads of 64 WITH grouped kv
+    heads under the causal window: 4 query heads a kv head. The pool
+    holds heads of 64 in 128 lanes (``pool_head_dim``), so the kernel
+    reads it where it lies: with 64 lanes the TPU lays the pool out
+    page-minor and the program copies all 0.4 GB of K and of V into the
+    kernel's layout, every layer, every step (1.6 GB of scratch)."""
+    from paddle_tpu.generation.paged_cache import pool_head_dim
+    sds = lambda shape, dt=BF16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    assert pool_head_dim(64) == 128 and pool_head_dim(128) == 128
+    pool = sds((3, 1024, 8, PAGE, pool_head_dim(64)))
+
+    def fn(q, k, v, table, kv_len):
+        return fa.flash_attention_decode_paged(q, k, v, table, kv_len, 2)
+
+    compiled = jax.jit(fn).lower(
+        sds((128, 1, 32, 64)), pool, pool, sds((128, 16), jnp.int32),
+        sds((128,), jnp.int32)).compile()
+    assert "flash_decode_paged" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("seq", [512, 1024])
+def test_causal_prefill_kernel_with_grouped_kv_heads(one_chip, as_on_tpu,
+                                                     seq):
+    """The causal prefill of grouped kv heads goes through the
+    block-causal path at a block of 1: the flash kernel at the buckets
+    that reach its gate."""
+    from paddle_tpu.generation.attention import block_causal_attention
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, BF16,  # noqa: E731
+                                             sharding=one_chip)
+
+    def fn(q, k, v):
+        return block_causal_attention(q, k, v, 1)
+
+    text = compiled_text(fn, sds((1, seq, 32, 64)), sds((1, seq, 8, 64)),
+                         sds((1, seq, 8, 64)))
+    assert KERNEL in text and "flash_fwd" in text
+
+
+def test_grouped_expert_products_behind_a_sigmoid_router(one_chip,
+                                                         as_on_tpu):
+    from paddle_tpu.distributed.parallel.moe import dropless_moe
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, BF16,  # noqa: E731
+                                             sharding=one_chip)
+
+    def fn(x, router, gate_up, down, bias):
+        return dropless_moe(x, router, gate_up, down, 4, True, "sigmoid",
+                            bias)
+
+    text = compiled_text(fn, sds((128, 2048)), sds((2048, 32)),
+                         sds((32, 2048, 3584)), sds((32, 1792, 2048)),
+                         sds((32,)))
+    assert text.count("ragged-dot") >= 2 and KERNEL in text
+
+
 # ---- the page pool is read and written where it lies (gpt3-6.7b widths)
 #
 # An 8-layer stacked pool of 32 heads of 128. One layer of it is 0.27 GB

@@ -88,6 +88,12 @@ device trace being taken. The sampled per-request segments
 (`req<id>.decode`, `req<id>.prefill_chunk`; 1 request in
 `trace_sample`) carry dynamic names and are not listed.
 
+On the DEVICE plane of such a trace the model's regions are named by
+`jax.named_scope`: `embed`, a block's mixer under its own name (`attn`
+for an attention layer, `short_conv` for a gated short convolution;
+`block_attn` inside `attn` under block diffusion), `mlp` (inside it
+`moe_router` and `moe_experts` of a dropless expert layer), `lm_head`.
+
 | Span | Opened in, covers (fields) |
 |---|---|
 """
