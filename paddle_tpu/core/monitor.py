@@ -58,6 +58,10 @@ Metric name scheme (what the summary views group by):
                                 of every step mode's forwards (decode,
                                 speculative, block diffusion; drained at
                                 each poll)
+    moe.grouped_kernel_layers / moe.ragged_dot_layers   gauges: dropless
+                                expert layers traced onto the repo's
+                                grouped-product kernel / onto XLA's
+                                ragged_dot, over every program built
     serve.requests{status=...}  terminal request outcomes (completed/
                                 cancelled/rejected) — QPS = rate of this
     serve.queue_depth           gauge: requests waiting for a slot
@@ -121,6 +125,7 @@ DECLARED_METRICS = frozenset({
     "gen.spec.proposed", "gen.spec.accepted", "gen.spec.accept_rate",
     "gen.diffusion.forwards", "gen.diffusion.unmasked",
     "gen.diffusion.commits", "moe.rows", "moe.expert_rows_max",
+    "moe.grouped_kernel_layers", "moe.ragged_dot_layers",
     "serve.requests", "serve.queue_depth", "serve.ttft",
     "serve.token_latency", "serve.slot_occupancy", "serve.cancellations",
     "serve.prefill.chunks", "serve.prefill.chunk_tokens",
@@ -294,6 +299,15 @@ METRIC_DOC = {
                             "rows of the busiest expert, summed over "
                             "layers and steps (x experts / moe.rows = "
                             "load imbalance)"),
+    "moe.grouped_kernel_layers": ("gauge", (),
+                                  "dropless expert layers traced onto "
+                                  "kernels/grouped_matmul.py, summed over "
+                                  "every program built (a TPU, shapes on "
+                                  "the tiles, experts not sharded)"),
+    "moe.ragged_dot_layers": ("gauge", (),
+                              "dropless expert layers traced onto XLA's "
+                              "ragged_dot instead (the CPU, an 'ep' axis, "
+                              "widths off the lane tile)"),
     "serve.requests": ("counter", ("status",),
                        "requests reaching a terminal status: completed "
                        "| cancelled | rejected (QPS = rate of this)"),
@@ -757,6 +771,18 @@ def record_moe_routing(rows: int, rows_max: int):
         metrics.counter("moe.rows").inc(int(rows))
     if rows_max:
         metrics.counter("moe.expert_rows_max").inc(int(rows_max))
+
+
+def record_moe_path(kernel: bool):
+    """One dropless expert layer was traced: onto the repo's grouped-
+    product kernel, or onto XLA's ``ragged_dot`` (trace time, so once a
+    layer and program built, not once a step)."""
+    if not enabled:
+        return
+    if kernel:
+        metrics.gauge("moe.grouped_kernel_layers").add(1)
+    else:
+        metrics.gauge("moe.ragged_dot_layers").add(1)
 
 
 def record_cache_occupancy(frac: float):

@@ -21,8 +21,9 @@ under jit: read it in the SAME trace, e.g. inside the loss closure —
 `DroplessMoE` is the expert layer of present-day sparse models (many
 narrow gated experts, several a token): no capacity and no dropped token.
 Tokens are sorted by expert and each projection is ONE grouped product
-over the experts' stacked weights (`jax.lax.ragged_dot`), so the cost is
-the rows routed, not `[tokens, E, capacity]`.
+over the experts' stacked weights (`kernels/grouped_matmul.py` on a TPU,
+`jax.lax.ragged_dot` elsewhere: `grouped_product`), so the cost is the
+rows routed, not `[tokens, E, capacity]`.
 """
 from __future__ import annotations
 
@@ -32,9 +33,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ...core import monitor as _monitor
 from ...core.tensor import Tensor, dispatch as _dispatch
+from ...kernels import grouped_matmul as _gmm
 from ...nn import initializer as I
 from ...nn.layer import Layer
+from .. import topology
 from .mp_layers import sharded_constraint
 
 
@@ -176,6 +180,25 @@ class MoEMLP(Layer):
 ROUTER_KINDS = ("softmax", "sigmoid")
 
 
+def grouped_product(xs, w_gate_up, w_down, backend=None, mesh=None):
+    """The grouped product an expert layer runs on its sorted rows ``xs``
+    [M, H], chosen by what the code can see: the repo's kernel
+    (``kernels/grouped_matmul.py``) on a TPU where rows and weights are
+    bfloat16, both products' shapes fit it and the expert dimension is
+    not sharded (no mesh, or an 'ep' axis of one); XLA's ``ragged_dot``
+    everywhere else (the CPU, expert parallelism, float32 training,
+    widths off the lane tile)."""
+    backend = jax.default_backend() if backend is None else backend
+    mesh = topology.get_mesh() if mesh is None else mesh
+    m, h = xs.shape
+    f2 = w_gate_up.shape[2]
+    bf16 = all(a.dtype == jnp.bfloat16 for a in (xs, w_gate_up, w_down))
+    fits = _gmm.supports(m, h, f2) and _gmm.supports(m, f2 // 2, h)
+    whole = mesh is None or dict(mesh.shape).get("ep", 1) == 1
+    return _gmm.grouped_matmul \
+        if backend == "tpu" and bf16 and fits and whole else _gmm.ragged_dot
+
+
 def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
                  norm_topk_prob: bool = True, router: str = "softmax",
                  select_bias=None, scaling: float = 1.0):
@@ -193,8 +216,8 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
     divided by (their sum + 1e-6) under ``norm_topk_prob``, times
     ``scaling``. Experts: the
     T * top_k (token, expert) rows sorted by expert, one grouped product
-    for gate+up, SiLU(gate) * up, one for down, then each token's k rows
-    weighted and summed in float32. Nothing is dropped; an expert no
+    (:func:`grouped_product`) for gate+up, SiLU(gate) * up, one for down,
+    then each token's k rows weighted and summed in float32. Nothing is dropped; an expert no
     token chose is an empty group."""
     t, h = x.shape
     e, f = w_down.shape[0], w_down.shape[1]
@@ -223,11 +246,11 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
         order = jnp.argsort(flat, stable=True)          # rows by expert
         rows = jnp.zeros((e,), jnp.int32).at[flat].add(1)
         xs = x[order // top_k]                          # [T*k, H]
-        gu = jax.lax.ragged_dot(xs, w_gate_up, rows,
-                                preferred_element_type=jnp.float32)
+        product = grouped_product(xs, w_gate_up, w_down)
+        _monitor.record_moe_path(kernel=product is _gmm.grouped_matmul)
+        gu = product(xs, w_gate_up, rows)
         z = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(x.dtype)
-        ys = jax.lax.ragged_dot(z, w_down, rows,
-                                preferred_element_type=jnp.float32)
+        ys = product(z, w_down, rows)
         # back to (token, k) order, weight, sum the k rows of a token
         ys = ys[jnp.argsort(order)].reshape(t, top_k, h)
         y = jnp.einsum("tkh,tk->th", ys, top)

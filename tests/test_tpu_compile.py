@@ -29,6 +29,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 import paddle_tpu.nn.functional as F
 from paddle_tpu.distributed import topology
 
+from paddle_tpu.kernels import grouped_matmul as gm
+
 # the module, not the function of the same name that kernels/ exports
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
@@ -70,6 +72,7 @@ def as_on_tpu(monkeypatch):
     """Steer the code onto its TPU branches: real kernels instead of
     interpret mode, and the Pallas path instead of the XLA reference."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
@@ -269,8 +272,10 @@ def test_grouped_expert_products(one_chip, as_on_tpu):
 
     text = compiled_text(fn, sds((512, 2048)), sds((2048, 128)),
                          sds((128, 2048, 1536)), sds((128, 768, 2048)))
-    # XLA's own grouped-product kernel, once for gate+up and once for down
-    assert text.count("ragged-dot") >= 2 and KERNEL in text
+    # the repo's grouped-product kernel, once for gate+up and once for
+    # down, and XLA's own op nowhere
+    assert len(set(GROUPED.findall(text))) == 2 and KERNEL in text
+    assert "ragged-dot" not in text
 
 
 # ---- a hybrid MoE at the published widths of its cell: 32 query heads
@@ -331,7 +336,125 @@ def test_grouped_expert_products_behind_a_sigmoid_router(one_chip,
     text = compiled_text(fn, sds((128, 2048)), sds((2048, 32)),
                          sds((32, 2048, 3584)), sds((32, 1792, 2048)),
                          sds((32,)))
-    assert text.count("ragged-dot") >= 2 and KERNEL in text
+    assert len(set(GROUPED.findall(text))) == 2 and KERNEL in text
+    assert "ragged-dot" not in text
+
+
+# ---- the experts' grouped-product kernel (kernels/grouped_matmul.py) at
+# both MoE cells' real shapes: the (token, expert) rows of the decode
+# step (sdar 128 lanes x 4 positions x 8 experts, lfm2 128 x 4) and of the
+# four prefill buckets (128-1024 tokens x 8 or x 4), both products
+
+# name -> (experts, K, N, the rows the cell's programs hand it)
+EXPERT_PRODUCTS = {
+    "sdar_gate_up": (128, 2048, 1536, (1024, 2048, 4096, 8192)),
+    "sdar_down": (128, 768, 2048, (1024, 2048, 4096, 8192)),
+    "lfm2_gate_up": (32, 2048, 3584, (512, 1024, 2048, 4096)),
+    "lfm2_down": (32, 1792, 2048, (512, 1024, 2048, 4096)),
+}
+# the kernel's instances in a compiled text: grouped_matmul, .1, .2 ...
+GROUPED = re.compile(r"%(grouped_matmul[.\d]*) = ")
+# the kernel's blocks live in VMEM; what it leaves in HBM beside its
+# result is the work list (a few hundred int32) and XLA's bookkeeping
+KERNEL_TEMP_BYTES = 1 << 20
+
+
+@pytest.mark.parametrize("product,rows", [
+    pytest.param(name, rows, id=f"{name}-{rows}")
+    for name, (_, _, _, all_rows) in EXPERT_PRODUCTS.items()
+    for rows in all_rows])
+def test_grouped_matmul_at_the_cells_shapes(one_chip, as_on_tpu, product,
+                                            rows):
+    e, k, n, _ = EXPERT_PRODUCTS[product]
+    sds = lambda shape, dt=BF16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    assert gm.supports(rows, k, n)
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        sds((rows, k)), sds((e, k, n)), sds((e,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert KERNEL in text and GROUPED.search(text)
+    assert "ragged-dot" not in text
+    assert f"f32[{rows},{n}]" in text       # float32 off the accumulator
+    assert compiled.memory_analysis().temp_size_in_bytes < KERNEL_TEMP_BYTES
+
+
+def _sdar_two_layers():
+    from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+    model = SDARForCausalLM(SDARConfig(
+        dtype="bfloat16", vocab_size=151936, hidden_size=2048,
+        num_hidden_layers=2, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, moe_intermediate_size=768, num_experts=128,
+        num_experts_per_tok=8, rope_theta=1e6))
+    return model, "block_step", dict(block_diffusion=dict(
+        block_length=4, denoising_steps=2,
+        remasking="low_confidence_static", confidence_threshold=0.9,
+        mask_token_id=151669))
+
+
+def _lfm2_two_layers():
+    from paddle_tpu.models.lfm2 import LFM2Config, LFM2ForCausalLM
+    # one layer of each mixer kind, both with experts (the cell's two
+    # leading layers are the dense ones)
+    model = LFM2ForCausalLM(LFM2Config(
+        dtype="bfloat16", num_hidden_layers=2,
+        layer_types=("conv", "full_attention"), num_dense_layers=0))
+    return model, "step", {}
+
+
+@pytest.mark.parametrize("build", [_sdar_two_layers, _lfm2_two_layers],
+                         ids=["sdar", "lfm2"])
+def test_step_of_a_two_layer_model_holds_the_kernel(one_chip, as_on_tpu,
+                                                    monkeypatch, build):
+    """The engine's own ``step`` program of a two-layer model at each MoE
+    cell's widths, 128 lanes over a paged cache, as the cell builds it:
+    both expert layers run the kernel twice and XLA's op is gone; the
+    gauges say which path each traced layer took."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core import metrics, monitor
+    from paddle_tpu.inference import Config
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.serving import ServingEngine
+    # weights are never read here: zero pages the OS hands out lazily
+    # (drawing 1.2 B normals on the CPU takes most of a minute)
+    monkeypatch.setattr(
+        initializer.Normal, "__call__",
+        lambda self, shape, dtype=None: jnp.asarray(np.zeros(
+            tuple(shape), jnp.dtype(dtype or "float32"))))
+    model, step, mode = build()
+    model.eval()
+    conf = (Config().from_layer(
+        model, [paddle.to_tensor(np.zeros((1, 128), np.int32))])
+        .enable_tpu("bfloat16")
+        .enable_generation(max_new_tokens=512, prefill_buckets=(128,),
+                           max_batch=128, do_sample=False, **mode)
+        .enable_serving(paged=True, kv_page_size=128, kv_pages=256,
+                        cache_max_len=2048))
+    monitor.enable()
+    try:
+        engine = ServingEngine(conf, warmup=False)
+        program = engine._programs[(step,)]
+        static = program._argnums(program.static)
+        avals = [
+            op if i in static else jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), op)
+            for i, op in enumerate(program.operands())]
+        before = {k: metrics.gauge(k).value for k in (
+            "moe.grouped_kernel_layers", "moe.ragged_dot_layers")}
+        text = jax.jit(program.fn, static_argnums=static,
+                       donate_argnums=program.donation_intent) \
+            .lower(*avals).compile().as_text()
+        after = {k: metrics.gauge(k).value for k in before}
+    finally:
+        monitor.disable()
+        engine.shutdown()
+    assert len(set(GROUPED.findall(text))) == 4     # 2 layers x 2 products
+    assert "ragged-dot" not in text
+    assert after["moe.grouped_kernel_layers"] \
+        == before["moe.grouped_kernel_layers"] + 2
+    assert after["moe.ragged_dot_layers"] == before["moe.ragged_dot_layers"]
 
 
 # ---- the page pool is read and written where it lies (gpt3-6.7b widths)
