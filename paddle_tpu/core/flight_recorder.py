@@ -172,8 +172,9 @@ DECLARED_SPANS = {
                    "iteration's decode dispatch, a serve.sync under "
                    "serve.step "
                    "(req, slot, bucket, prompt; state_bytes = bytes of "
-                   "per-lane state the admit program installs beside the "
-                   "KV row, 0 for a model that keeps none; chunks when the "
+                   "per-lane state, over every state array, that the "
+                   "admit program installs beside the KV row, 0 for a "
+                   "model that keeps none; chunks when the "
                    "prefill is chunked: the span then covers the "
                    "reservation only)",
     "serve.sync": "one blocking device read (site = prefill / chunk / "
@@ -208,8 +209,9 @@ DECLARED_SPANS = {
                          "their device_put inside setup.engine_init "
                          "(bytes = cache and lanes; kv_bytes = the KV half "
                          "of the cache, pages or rows, tables and lengths; "
-                         "state_bytes = per-lane state a hybrid cache holds "
-                         "beside it; the transfer is not awaited)",
+                         "state_bytes = the per-lane states a hybrid cache "
+                         "holds beside it, summed over its state arrays; "
+                         "the transfer is not awaited)",
     "setup.warmup": "ServingEngine.warmup(): every program the "
                     "scheduler can dispatch",
     "jit.program": "one AOT program built or loaded by jit.compile_cache"

@@ -58,10 +58,18 @@ Metric name scheme (what the summary views group by):
                                 of every step mode's forwards (decode,
                                 speculative, block diffusion; drained at
                                 each poll)
+    moe.rows_elsewhere          rows a layer that holds a share of its
+                                experts sent to experts held elsewhere
+                                (never computed here; drained with
+                                moe.rows)
     moe.grouped_kernel_layers / moe.ragged_dot_layers   gauges: dropless
                                 expert layers traced onto the repo's
                                 grouped-product kernel / onto XLA's
                                 ragged_dot, over every program built
+    ssm.kernel_layers / ssm.fallback_layers   gauges: state-space
+                                mixers' one-step updates traced onto
+                                kernels/ssm_update.py / onto XLA's
+                                fusion, over every program built
     serve.requests{status=...}  terminal request outcomes (completed/
                                 cancelled/rejected) — QPS = rate of this
     serve.queue_depth           gauge: requests waiting for a slot
@@ -125,7 +133,9 @@ DECLARED_METRICS = frozenset({
     "gen.spec.proposed", "gen.spec.accepted", "gen.spec.accept_rate",
     "gen.diffusion.forwards", "gen.diffusion.unmasked",
     "gen.diffusion.commits", "moe.rows", "moe.expert_rows_max",
+    "moe.rows_elsewhere",
     "moe.grouped_kernel_layers", "moe.ragged_dot_layers",
+    "ssm.kernel_layers", "ssm.fallback_layers",
     "serve.requests", "serve.queue_depth", "serve.ttft",
     "serve.token_latency", "serve.slot_occupancy", "serve.cancellations",
     "serve.prefill.chunks", "serve.prefill.chunk_tokens",
@@ -299,6 +309,12 @@ METRIC_DOC = {
                             "rows of the busiest expert, summed over "
                             "layers and steps (x experts / moe.rows = "
                             "load imbalance)"),
+    "moe.rows_elsewhere": ("counter", (),
+                           "(token, expert) rows a layer that holds a "
+                           "share of the router's experts sent to "
+                           "experts held elsewhere: sorted past its last "
+                           "group, never multiplied (moe.rows / (moe.rows "
+                           "+ this) = the share's part of the routing)"),
     "moe.grouped_kernel_layers": ("gauge", (),
                                   "dropless expert layers traced onto "
                                   "kernels/grouped_matmul.py, summed over "
@@ -308,6 +324,16 @@ METRIC_DOC = {
                               "dropless expert layers traced onto XLA's "
                               "ragged_dot instead (the CPU, an 'ep' axis, "
                               "widths off the lane tile)"),
+    "ssm.kernel_layers": ("gauge", (),
+                          "state-space mixers whose one-step decode "
+                          "update was traced onto kernels/ssm_update.py "
+                          "(a TPU, a float32 state on the tiles), summed "
+                          "over every program built"),
+    "ssm.fallback_layers": ("gauge", (),
+                            "state-space mixers whose one-step update "
+                            "was traced onto XLA's fusion of the same "
+                            "arithmetic instead (the CPU, another state "
+                            "type)"),
     "serve.requests": ("counter", ("status",),
                        "requests reaching a terminal status: completed "
                        "| cancelled | rejected (QPS = rate of this)"),
@@ -761,16 +787,19 @@ def record_block_diffusion(forwards: int, unmasked: int, commits: int):
         metrics.counter("gen.diffusion.commits").inc(int(commits))
 
 
-def record_moe_routing(rows: int, rows_max: int):
+def record_moe_routing(rows: int, rows_max: int, elsewhere: int = 0):
     """Dropless expert layers' routing since the last record: rows
-    computed and the busiest expert's rows, both summed over layers and
-    steps (the engine drains the device counters at each poll)."""
+    computed, the busiest expert's rows and the rows sent to experts
+    held elsewhere, all summed over layers and steps (the engine drains
+    the device counters at each poll)."""
     if not enabled:
         return
     if rows:
         metrics.counter("moe.rows").inc(int(rows))
     if rows_max:
         metrics.counter("moe.expert_rows_max").inc(int(rows_max))
+    if elsewhere:
+        metrics.counter("moe.rows_elsewhere").inc(int(elsewhere))
 
 
 def record_moe_path(kernel: bool):
@@ -783,6 +812,18 @@ def record_moe_path(kernel: bool):
         metrics.gauge("moe.grouped_kernel_layers").add(1)
     else:
         metrics.gauge("moe.ragged_dot_layers").add(1)
+
+
+def record_ssm_path(kernel: bool):
+    """One state-space mixer's one-step update was traced: onto the
+    repo's kernel, or onto XLA's fusion (trace time, as
+    :func:`record_moe_path`)."""
+    if not enabled:
+        return
+    if kernel:
+        metrics.gauge("ssm.kernel_layers").add(1)
+    else:
+        metrics.gauge("ssm.fallback_layers").add(1)
 
 
 def record_cache_occupancy(frac: float):

@@ -180,20 +180,22 @@ class MoEMLP(Layer):
 ROUTER_KINDS = ("softmax", "sigmoid")
 
 
-def grouped_product(xs, w_gate_up, w_down, backend=None, mesh=None):
+def grouped_product(xs, w_up, w_down, backend=None, mesh=None):
     """The grouped product an expert layer runs on its sorted rows ``xs``
     [M, H], chosen by what the code can see: the repo's kernel
     (``kernels/grouped_matmul.py``) on a TPU where rows and weights are
     bfloat16, both products' shapes fit it and the expert dimension is
     not sharded (no mesh, or an 'ep' axis of one); XLA's ``ragged_dot``
     everywhere else (the CPU, expert parallelism, float32 training,
-    widths off the lane tile)."""
+    widths off the lane tile). ``w_up`` [E, H, F'] is the first product's
+    weights (gate and up side by side, or up alone), ``w_down``
+    [E, F, H] the second's."""
     backend = jax.default_backend() if backend is None else backend
     mesh = topology.get_mesh() if mesh is None else mesh
     m, h = xs.shape
-    f2 = w_gate_up.shape[2]
-    bf16 = all(a.dtype == jnp.bfloat16 for a in (xs, w_gate_up, w_down))
-    fits = _gmm.supports(m, h, f2) and _gmm.supports(m, f2 // 2, h)
+    bf16 = all(a.dtype == jnp.bfloat16 for a in (xs, w_up, w_down))
+    fits = _gmm.supports(m, h, w_up.shape[2]) \
+        and _gmm.supports(m, w_down.shape[1], h)
     whole = mesh is None or dict(mesh.shape).get("ep", 1) == 1
     return _gmm.grouped_matmul \
         if backend == "tpu" and bf16 and fits and whole else _gmm.ragged_dot
@@ -201,24 +203,34 @@ def grouped_product(xs, w_gate_up, w_down, backend=None, mesh=None):
 
 def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
                  norm_topk_prob: bool = True, router: str = "softmax",
-                 select_bias=None, scaling: float = 1.0):
+                 select_bias=None, scaling: float = 1.0, *,
+                 gated: bool = True, held=None, norm_eps: float = 1e-6):
     """Pure-jax body of :class:`DroplessMoE` on raw arrays.
 
-    x [T, H]; router_w [H, E]; w_gate_up [E, H, 2F] (gate then up);
-    w_down [E, F, H]. Returns (y [T, H], rows [E] int32: how many
-    (token, expert) rows each expert computed).
+    x [T, H]; router_w [H, E]; w_gate_up [E', H, 2F] (gate then up;
+    ``gated=False``: up alone, [E', H, F]); w_down [E', F, H]. ``E'`` is
+    ``E``, or under ``held = (first, count)`` the ``count`` experts from
+    ``first`` on that this chip holds of the router's ``E``. Returns
+    (y [T, H], rows [E'] int32: how many (token, expert) rows each
+    expert computed; under ``held`` one more entry LAST: the rows sent to
+    experts held elsewhere).
 
     Router, ``"softmax"``: softmax over ALL experts in float32, the
     ``top_k`` largest, renormalised over the chosen (``norm_topk_prob``).
     ``"sigmoid"``: a sigmoid score per expert; the ``top_k`` largest of
     ``score + select_bias`` ([E], a bias that takes part in the SELECTION
     only) are chosen, their weights are the scores WITHOUT the bias,
-    divided by (their sum + 1e-6) under ``norm_topk_prob``, times
+    divided by (their sum + ``norm_eps``) under ``norm_topk_prob``, times
     ``scaling``. Experts: the
     T * top_k (token, expert) rows sorted by expert, one grouped product
-    (:func:`grouped_product`) for gate+up, SiLU(gate) * up, one for down,
+    (:func:`grouped_product`) for gate+up, SiLU(gate) * up (ungated:
+    relu(up)^2), one for down,
     then each token's k rows weighted and summed in float32. Nothing is dropped; an expert no
-    token chose is an empty group."""
+    token chose is an empty group. Under ``held`` the router, the top-k
+    and the normalisation still go over all ``E``; a row sent to an
+    expert outside the share sorts past the last held group, is never
+    multiplied and adds nothing: ``y`` is this share's PART of the
+    layer's result (the parts of all the shares add up to it)."""
     t, h = x.shape
     e, f = w_down.shape[0], w_down.shape[1]
     with jax.named_scope("moe_router"):
@@ -239,18 +251,29 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
             _, idx = jax.lax.top_k(biased, top_k)
             top = jnp.take_along_axis(score, idx, axis=-1)
             if norm_topk_prob:
-                top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-6)
+                top = top / (jnp.sum(top, axis=-1, keepdims=True)
+                             + norm_eps)
             top = top * scaling
     with jax.named_scope("moe_experts"):
         flat = idx.reshape(-1)                          # [T*k] expert ids
+        if held is not None:
+            # this share's own numbering; everything else is one group
+            # more, past the last held one
+            first, count = held
+            flat = jnp.where((flat >= first) & (flat < first + count),
+                             flat - first, e)
         order = jnp.argsort(flat, stable=True)          # rows by expert
-        rows = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        rows = jnp.zeros((e + (held is not None),), jnp.int32) \
+            .at[flat].add(1)
         xs = x[order // top_k]                          # [T*k, H]
         product = grouped_product(xs, w_gate_up, w_down)
         _monitor.record_moe_path(kernel=product is _gmm.grouped_matmul)
-        gu = product(xs, w_gate_up, rows)
-        z = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(x.dtype)
-        ys = product(z, w_down, rows)
+        gu = product(xs, w_gate_up, rows[:e])
+        if gated:
+            z = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(x.dtype)
+        else:
+            z = jnp.square(jax.nn.relu(gu)).astype(x.dtype)
+        ys = product(z, w_down, rows[:e])
         # back to (token, k) order, weight, sum the k rows of a token
         ys = ys[jnp.argsort(order)].reshape(t, top_k, h)
         y = jnp.einsum("tkh,tk->th", ys, top)
@@ -258,23 +281,36 @@ def dropless_moe(x, router_w, w_gate_up, w_down, top_k: int,
 
 
 class DroplessMoE(Layer):
-    """Dropless sparse-expert FFN: ``num_experts`` SiLU-gated experts of
-    width ``d_expert``, ``top_k`` a token, no shared expert, no drops.
+    """Dropless sparse-expert FFN: ``num_experts`` experts of width
+    ``d_expert``, SiLU-gated or (``gated=False``) ungated relu^2,
+    ``top_k`` a token, no drops. A shared expert beside them is the
+    model's, not this layer's.
     ``router``: ``"softmax"``, or ``"sigmoid"`` with an optional
-    per-expert ``select_bias`` parameter and a ``scaling`` factor
-    (:func:`dropless_moe` states both).
+    per-expert ``select_bias`` parameter, a ``scaling`` factor and the
+    normalisation's ``norm_eps`` (:func:`dropless_moe` states them).
+    ``held = (first, count)``: this layer HOLDS ``count`` of the
+    ``num_experts`` the router ranks, from ``first`` on (the chip's share
+    under expert parallelism), and gives their part of the result.
 
-    Holds the experts stacked: ``gate_up`` [E, d_model, 2 * d_expert]
-    (gate then up, side by side so one grouped product feeds both) and
-    ``down`` [E, d_expert, d_model], sharded over 'ep'. After a forward,
-    ``rows`` holds that call's per-expert row counts (a traced value
-    under jit: read it in the SAME trace — :func:`routing_stats`)."""
+    Holds the experts stacked: ``gate_up`` [E', d_model, 2 * d_expert]
+    (gate then up, side by side so one grouped product feeds both;
+    ungated: ``up`` [E', d_model, d_expert]) and ``down`` [E', d_expert,
+    d_model], sharded over 'ep'. ``pad_to``: the experts' width as it is
+    STORED, in whole multiples of it (columns of zeros in ``up``, rows of
+    zeros in ``down``; exact for an ungated expert, ``relu(0)^2 = 0``):
+    what lets a width that is not whole lane tiles take the grouped
+    kernel. After a forward, ``rows`` holds that call's per-expert row
+    counts and ``rows_elsewhere`` the rows it sent to experts it does not
+    hold (traced values under jit: read them in the SAME trace —
+    :func:`routing_stats`)."""
 
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int, norm_topk_prob: bool = True,
                  std: float = 0.02, down_std: Optional[float] = None,
                  dtype=None, router: str = "softmax",
-                 select_bias: bool = False, scaling: float = 1.0):
+                 select_bias: bool = False, scaling: float = 1.0,
+                 gated: bool = True, held=None, norm_eps: float = 1e-6,
+                 pad_to: int = 1):
         # dtype: the stacked experts are nearly all of a sparse model;
         # built in float32 first, a model served in bfloat16 on one chip
         # would not fit beside its own cast
@@ -287,9 +323,19 @@ class DroplessMoE(Layer):
         if select_bias and router != "sigmoid":
             raise ValueError("a selection bias belongs to the sigmoid "
                              "router: softmax weights ARE what is ranked")
+        if held is not None and not (
+                0 <= held[0] and held[1] >= 1
+                and held[0] + held[1] <= num_experts):
+            raise ValueError(f"held {held} outside the router's "
+                             f"{num_experts} experts")
+        if pad_to > 1 and gated:
+            raise ValueError("padding splits gate from up: the ungated "
+                             "kind alone is stored padded")
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = bool(norm_topk_prob)
         self.router_kind, self.scaling = router, float(scaling)
+        self.gated, self.norm_eps = bool(gated), float(norm_eps)
+        self.held = None if held is None else (int(held[0]), int(held[1]))
         self.router = self.create_parameter(
             (d_model, num_experts), default_initializer=I.Normal(0.0, std))
         self.router.spec = P()
@@ -299,15 +345,26 @@ class DroplessMoE(Layer):
             self.select_bias = self.create_parameter(
                 (num_experts,), default_initializer=I.Constant(0.0))
             self.select_bias.spec = P()
-        self.gate_up = self.create_parameter(
-            (num_experts, d_model, 2 * d_expert), dtype=dtype,
+        here = num_experts if held is None else self.held[1]
+        stored = -(-d_expert // pad_to) * pad_to
+        up = self.create_parameter(
+            (here, d_model, (2 if gated else 1) * stored), dtype=dtype,
             default_initializer=I.Normal(0.0, std))
-        self.gate_up.spec = P("ep", None, None)
+        up.spec = P("ep", None, None)
         self.down = self.create_parameter(
-            (num_experts, d_expert, d_model), dtype=dtype,
+            (here, stored, d_model), dtype=dtype,
             default_initializer=I.Normal(0.0, down_std or std))
         self.down.spec = P("ep", None, None)
-        self.rows = None
+        if gated:
+            self.gate_up = up
+        else:
+            self.up = up
+        if stored != d_expert:
+            keep = (jnp.arange(stored) < d_expert)
+            up.set_value(up._data * keep.astype(up._data.dtype))
+            self.down.set_value(
+                self.down._data * keep[:, None].astype(up._data.dtype))
+        self.rows = self.rows_elsewhere = None
 
     def forward(self, x):
         shape = x.shape
@@ -317,26 +374,33 @@ class DroplessMoE(Layer):
             lambda x_, r, gu, dn, *b: dropless_moe(
                 x_.reshape(-1, shape[-1]), r, gu, dn, self.top_k,
                 self.norm_topk_prob, self.router_kind, *b,
-                scaling=self.scaling),
-            (x, self.router, self.gate_up, self.down) + bias, {})
-        self.rows = rows
+                scaling=self.scaling, gated=self.gated, held=self.held,
+                norm_eps=self.norm_eps),
+            (x, self.router, self.gate_up if self.gated else self.up,
+             self.down) + bias, {})
+        if self.held is None:
+            self.rows, self.rows_elsewhere = rows, None
+        else:
+            self.rows, self.rows_elsewhere = rows[:-1], rows[-1]
         return y.reshape(shape)
 
 
 def routing_stats(model: Layer):
-    """(rows, rows_max) summed over every :class:`DroplessMoE` sublayer's
-    last forward: the (token, expert) rows computed and the busiest
-    expert's rows, int32 scalars (traced under jit: call in the same
-    trace as the forward, like :func:`aux_loss`). None when the model has
-    no such layer."""
+    """(rows, rows_max, rows_elsewhere) summed over every
+    :class:`DroplessMoE` sublayer's last forward: the (token, expert)
+    rows computed, the busiest expert's rows and the rows sent to experts
+    held elsewhere (0 where every layer holds all its experts), int32
+    scalars (traced under jit: call in the same trace as the forward,
+    like :func:`aux_loss`). None when the model has no such layer."""
     total = None
     for layer in model.sublayers(include_self=True):
         if isinstance(layer, DroplessMoE) and layer.rows is not None:
-            r = layer.rows._data if isinstance(layer.rows, Tensor) \
-                else layer.rows
-            one = (jnp.sum(r), jnp.max(r))
+            r, away = (jnp.asarray(0, jnp.int32) if v is None
+                       else v._data if isinstance(v, Tensor) else v
+                       for v in (layer.rows, layer.rows_elsewhere))
+            one = (jnp.sum(r), jnp.max(r), away)
             total = one if total is None \
-                else (total[0] + one[0], total[1] + one[1])
+                else tuple(a + b for a, b in zip(total, one))
     return total
 
 

@@ -175,8 +175,9 @@ def prefill_fn(net, state_vals, ids, plen, key, cfg, cache_len):
 
 def _count_routing(net, moe_counters):
     """``moe_counters`` plus this forward's routing through the layer's
-    dropless expert layers: rows computed and the busiest expert's,
-    summed over layers (unchanged where the layer has none)."""
+    dropless expert layers: rows computed, the busiest expert's and the
+    rows sent to experts held elsewhere, summed over layers (unchanged
+    where the layer has none)."""
     from ..distributed.parallel.moe import routing_stats
     moe = routing_stats(net.layer)
     if moe is None:
@@ -304,9 +305,10 @@ def install_span_fn(cache, row_cache, table_row, start):
 
 # ---------------------------------------------------------- step modes
 
-# ``moe_counters``: the experts' rows and busiest-expert rows of every
-# forward so far, two int32 the poll drains into moe.* (they stay 0
-# where the layer has no dropless expert layer)
+# ``moe_counters``: the experts' rows, busiest-expert rows and rows sent
+# to experts held elsewhere of every forward so far, three int32 the poll
+# drains into moe.* (they stay 0 where the layer has no dropless expert
+# layer)
 DecodeLanes = collections.namedtuple(
     "DecodeLanes", ("tok", "finished", "steps", "budget", "out_buf",
                     "moe_counters"))
@@ -383,12 +385,12 @@ class _StepMode:
         return row[0][:n]
 
     @staticmethod
-    def _book_routing(rows: int, rows_max: int) -> dict:
+    def _book_routing(rows: int, rows_max: int, elsewhere: int) -> dict:
         """The drained ``moe_counters`` into moe.*; what a ``serve.poll``
         shows of them (nothing where no expert layer ran)."""
-        if not rows:
+        if not rows and not elsewhere:
             return {}
-        monitor.record_moe_routing(rows, rows_max)
+        monitor.record_moe_routing(rows, rows_max, elsewhere)
         return {"moe_rows": rows}
 
 
@@ -405,10 +407,10 @@ class Decode(_StepMode):
     def lanes(self, batch, cap):
         return DecodeLanes(_zeros(batch), np.ones((batch,), bool),
                            _zeros(batch), _zeros(batch), _zeros(batch, cap),
-                           _zeros(2))
+                           _zeros(3))
 
-    def _book(self, stats, rows, rows_max):
-        return self._book_routing(rows, rows_max)
+    def _book(self, stats, *routing):
+        return self._book_routing(*routing)
 
     def first(self, prompt, budget, tok, fin):
         return {"tok": tok, "fin": fin, "budget": np.int32(budget)}
@@ -462,12 +464,12 @@ class Speculative(Decode):
         return lanes._replace(tok_buf=lanes.tok_buf.at[slot].set(row),
                               tok_len=lanes.tok_len.at[slot].set(plen + 1))
 
-    def _book(self, stats, rows, rows_max, proposed, accepted):
+    def _book(self, stats, rows, rows_max, elsewhere, proposed, accepted):
         if proposed or accepted:
             stats["spec_proposed"] += proposed
             stats["spec_accepted"] += accepted
             monitor.record_speculative(proposed, accepted)
-        return self._book_routing(rows, rows_max)
+        return self._book_routing(rows, rows_max, elsewhere)
 
 
 class BlockDiffusion(_StepMode):
@@ -477,8 +479,8 @@ class BlockDiffusion(_StepMode):
     the budget: nothing is sampled."""
     key = ("block_step",)
     step_fn = staticmethod(block_step_fn)
-    # forwards / unmasked / commits, then the experts' rows and
-    # busiest-expert rows: five int32 scalars in the poll's window
+    # forwards / unmasked / commits, then the experts' three routing
+    # counts: six int32 scalars in the poll's window
     counters = ("counters", "moe_counters")
     # both rows behind ONE wait: a second blocking read is a second
     # round trip during which the device has nothing queued
@@ -496,7 +498,7 @@ class BlockDiffusion(_StepMode):
             _zeros(batch, cap), np.full((batch, cap), -1, np.int8),
             np.full((batch, self.bd.block_length), self.bd.mask_token_id,
                     np.int32),
-            _zeros(batch), _zeros(batch), _zeros(3), _zeros(2))
+            _zeros(batch), _zeros(batch), _zeros(3), _zeros(3))
 
     def check_prompt(self, ids):
         if ids.size < self.bd.block_length:
@@ -530,11 +532,11 @@ class BlockDiffusion(_StepMode):
             blk_step=lanes.blk_step.at[slot].set(0),
             out0=lanes.out0.at[slot].set(first["out0"]))
 
-    def _book(self, stats, forwards, unmasked, commits, rows, rows_max):
+    def _book(self, stats, forwards, unmasked, commits, *routing):
         stats["diffusion_forwards"] += forwards
         stats["diffusion_commits"] += commits
         monitor.record_block_diffusion(forwards, unmasked, commits)
-        return dict(self._book_routing(rows, rows_max),
+        return dict(self._book_routing(*routing),
                     forwards=forwards, commits=commits)
 
     def cut(self, req, row, n, partial):

@@ -251,8 +251,9 @@ def test_dropless_layer_reports_its_rows():
     layer = DroplessMoE(16, 8, 6, 2)
     y = layer(paddle.to_tensor(np.ones((2, 5, 16), np.float32)))
     assert y.shape == [2, 5, 16]
-    rows, busiest = routing_stats(layer)
+    rows, busiest, elsewhere = routing_stats(layer)
     assert int(rows) == 20 and int(busiest) == 10   # equal rows: one route
+    assert int(elsewhere) == 0          # the layer holds all its experts
 
 
 # ------------------------------------------------------------ the masks

@@ -120,6 +120,21 @@ def test_tiles_at_the_cells_shapes(rows, k, n, tn):
     assert 2 << 20 <= k * tn * 2 <= 8 << 20     # a copy of 2-8 MB a visit
 
 
+# the 1856-wide ungated experts of a 2688-wide model are stored in 1920
+# columns (15 lane tiles; ``DroplessMoE(pad_to=128)``): up 2688 x 1920 in
+# three blocks of 640, down 1920 x 2688 in three of 896, 3.4 MB a visit;
+# at the step's 1,536 rows and the four prefill buckets' 768-6,144
+@pytest.mark.parametrize("rows", [1536, 768, 3072, 6144])
+@pytest.mark.parametrize("k,n,tn", [(2688, 1920, 640), (1920, 2688, 896)])
+def test_tiles_at_the_padded_1856_wide_experts(rows, k, n, tn):
+    assert gm.supports(rows, k, n)
+    assert gm.row_tile(rows) == 128 and gm.col_tile(k, n) == tn
+    assert 2 << 20 <= k * tn * 2 <= 8 << 20
+    # the published width itself is whole sublane tiles, not whole lanes
+    assert not gm.supports(rows, 2688, 1856)
+    assert not gm.supports(rows, 1856, 2688)
+
+
 @pytest.mark.parametrize("rows,k,n", [
     (200, 128, 128),        # 200 rows are no whole tiles of 128
     (72, 128, 128),         # 72 rows are no whole bf16 sublane tiles
@@ -257,6 +272,37 @@ def test_dropless_moe_through_the_kernel(as_on_tpu, tokens, experts,
     np.testing.assert_allclose(np.array(got, np.float32),
                                np.array(want, np.float32), rtol=tol,
                                atol=tol)
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)], ids=["whole", "held"])
+def test_ungated_experts_stored_padded_take_the_kernel(as_on_tpu, held):
+    """A width off the lane tile (the 1856 of 2688 x 1856, here 72 of
+    128 x 72) falls back to ``ragged_dot``; stored padded to whole tiles
+    it takes the kernel, and padding with zeros changes nothing. Rows of
+    experts held elsewhere are the kernel's zero tail."""
+    dtype, e, h, f, fs = jnp.bfloat16, 8, 128, 72, 128
+    here = e if held is None else held[1]
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(ks[0], (64, h), jnp.float32).astype(dtype)
+    wr = jax.random.normal(ks[1], (h, e), jnp.float32) * 0.3
+    up = (jax.random.normal(ks[2], (here, h, f)) * 0.1).astype(dtype)
+    down = (jax.random.normal(ks[3], (here, f, h)) * 0.1).astype(dtype)
+    kw = dict(gated=False, held=held, norm_eps=1e-20)
+    run = lambda u, d: jax.jit(lambda *a: dropless_moe(  # noqa: E731
+        *a, 2, True, "sigmoid", None, 2.5, **kw))(x, wr, u, d)
+    assert moe.grouped_product(x[:1].repeat(128, 0), up, down) \
+        is gm.ragged_dot
+    want, rows_w = run(up, down)
+    up_s = jnp.pad(up, ((0, 0), (0, 0), (0, fs - f)))
+    down_s = jnp.pad(down, ((0, 0), (0, fs - f), (0, 0)))
+    assert moe.grouped_product(x[:1].repeat(128, 0), up_s, down_s) \
+        is gm.grouped_matmul
+    got, rows = run(up_s, down_s)
+    np.testing.assert_array_equal(np.array(rows), np.array(rows_w))
+    assert int(np.array(rows).sum()) == 128
+    np.testing.assert_allclose(np.array(got, np.float32),
+                               np.array(want, np.float32), rtol=2e-2,
+                               atol=2e-2)
 
 
 def test_layer_trains_through_the_kernel(as_on_tpu):

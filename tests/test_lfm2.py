@@ -160,7 +160,7 @@ def test_prefill_then_decode_logits_through_the_cache(params):
                           prompt_len=paddle.to_tensor(plen),
                           cache_max_len=32)
     assert isinstance(cache, HybridCache)
-    assert cache.kv.k.shape[0] == 2 and cache.state.shape == (4, 2, 2, 64)
+    assert cache.kv.k.shape[0] == 2 and [s.shape for s in cache.state] == [(4, 2, 2, 64)]
     want = [np.array(ref.logits_at(params, jnp.asarray(s), jnp.arange(20),
                                    cfg)) for s in seqs]
     got = np.array(logits._data)
@@ -486,12 +486,12 @@ def _dense_row(kv_len, layers=2, heads=2, d=4, max_len=16, seed=0):
     shape = (layers, 1, max_len, heads, d)
     kv = KVCache(jax.random.normal(ks[0], shape), jax.random.normal(
         ks[1], shape), jnp.asarray([kv_len], jnp.int32))
-    return HybridCache(kv, jax.random.normal(ks[2], (3, 1, 2, 8)))
+    return HybridCache(kv, (jax.random.normal(ks[2], (3, 1, 2, 8)),))
 
 
 def _pool(batch=3):
     kv = PagedKVCache.create(2, batch, 9, 4, 4, 2, 4)
-    return HybridCache.create(kv, 3, (2, 8), jnp.float32)
+    return HybridCache.create(kv, 3, (((2, 8), None),), jnp.float32)
 
 
 def test_hybrid_cache_is_a_pytree_that_delegates_the_kv_protocol():
@@ -508,10 +508,11 @@ def test_hybrid_cache_is_a_pytree_that_delegates_the_kv_protocol():
     assert getattr(dense, "page_table", None) is None
     # the paged form keeps the state a row a lane
     avals = jax.eval_shape(lambda: HybridCache.create(
-        KVCache.create(2, 3, 16, 2, 4), 3, (2, 8), jnp.float32))
+        KVCache.create(2, 3, 16, 2, 4), 3, (((2, 8), None),),
+        jnp.float32))
     paged = avals.paged(9, 4, 4)
     assert paged.kv.k.shape == (2, 9, 2, 4, 4)
-    assert paged.state.shape == (3, 3, 2, 8)
+    assert [s.shape for s in paged.state] == [(3, 3, 2, 8)]
 
 
 def test_install_row_and_reset_rows_move_state_with_the_kv_row():
@@ -520,14 +521,14 @@ def test_install_row_and_reset_rows_move_state_with_the_kv_row():
     other rows keep theirs."""
     cache = _pool()
     left = jnp.full((3, 3, 2, 8), 7.0)
-    cache = HybridCache(cache.kv, left)
+    cache = HybridCache(cache.kv, (left,))
     src = _dense_row(6)
     table = jnp.asarray([3, 5, 0, 0], jnp.int32)
     out = cache.install_row(src, 1, table, 0)
-    np.testing.assert_array_equal(np.array(out.state[:, 1]),
-                                  np.array(src.state[:, 0]))
-    np.testing.assert_array_equal(np.array(out.state[:, 0]), 7.0)
-    np.testing.assert_array_equal(np.array(out.state[:, 2]), 7.0)
+    np.testing.assert_array_equal(np.array(out.state[0][:, 1]),
+                                  np.array(src.state[0][:, 0]))
+    np.testing.assert_array_equal(np.array(out.state[0][:, 0]), 7.0)
+    np.testing.assert_array_equal(np.array(out.state[0][:, 2]), 7.0)
     assert list(np.array(out.kv_len)) == [0, 6, 0]
     assert list(np.array(out.page_table[1])) == [3, 5, 0, 0]
     # K of position 5 sits in page 5, offset 1
@@ -537,8 +538,8 @@ def test_install_row_and_reset_rows_move_state_with_the_kv_row():
         freed = out.reset_rows(rows)
         assert list(np.array(freed.kv_len)) == [0, 0, 0]
         assert not np.array(freed.page_table[1]).any()
-        assert not np.array(freed.state[:, 1]).any()
-        np.testing.assert_array_equal(np.array(freed.state[:, 0]), 7.0)
+        assert not np.array(freed.state[0][:, 1]).any()
+        np.testing.assert_array_equal(np.array(freed.state[0][:, 0]), 7.0)
 
 
 def test_update_with_kv_len_and_with_state_touch_their_own_half():
@@ -546,20 +547,20 @@ def test_update_with_kv_len_and_with_state_touch_their_own_half():
                                 jnp.asarray([2, 4, 0, 0], jnp.int32), 0)
     k_new = jnp.ones((3, 1, 2, 4))
     out = cache.update(1, k_new, 2 * k_new, cache.kv_len)
-    np.testing.assert_array_equal(np.array(out.state),
-                                  np.array(cache.state))
+    np.testing.assert_array_equal(np.array(out.state[0]),
+                                  np.array(cache.state[0]))
     # row 0 writes position 6: page 4, offset 2; idle rows: the null page
     np.testing.assert_array_equal(np.array(out.k[1, 4, :, 2]), 1.0)
     np.testing.assert_array_equal(np.array(out.v[1, 4, :, 2]), 2.0)
     grown = out.with_kv_len(out.kv_len + 1)
     assert list(np.array(grown.kv_len)) == [7, 1, 1]
-    np.testing.assert_array_equal(np.array(grown.state),
-                                  np.array(cache.state))
+    np.testing.assert_array_equal(np.array(grown.state[0]),
+                                  np.array(cache.state[0]))
     new = jnp.full((3, 2, 8), 5.0)
-    stated = grown.with_state(2, new)
-    np.testing.assert_array_equal(np.array(stated.state[2]), 5.0)
-    np.testing.assert_array_equal(np.array(stated.state[:2]),
-                                  np.array(cache.state[:2]))
+    stated = grown.with_state(2, (new,))
+    np.testing.assert_array_equal(np.array(stated.state[0][2]), 5.0)
+    np.testing.assert_array_equal(np.array(stated.state[0][:2]),
+                                  np.array(cache.state[0][:2]))
     np.testing.assert_array_equal(np.array(stated.k), np.array(grown.k))
     assert list(np.array(stated.positions(2)[0])) == [7, 8]
 

@@ -541,3 +541,107 @@ def test_paged_install_writes_the_pool_in_place(one_chip, program, quant):
             lambda c, src, row, start: c.install_span(src, row, start),
             cache, src, table_row, at)
     assert not pool_sized_relayouts(text, cache.k.size // POOL_LAYERS)
+
+
+# ---- a state-space MoE hybrid's programs at its cell's shapes: the
+# engine's own ``step``, ``prefill`` and ``admit`` of one block of each
+# kind (Mamba-2, experts, attention) at Nemotron-3-Nano's widths, 256
+# lanes over a paged cache and a float32 SSM state of 0.54 GB a layer
+
+SSM = re.compile(r"%(ssm_update[.\d]*) = ")
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(topo):
+    """{key: (compiled text, memory analysis)} of a three-block engine's
+    programs, with the gauges' changes, built once for the module."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core import metrics, monitor
+    from paddle_tpu.inference import Config
+    from paddle_tpu.kernels import ssm_update as su
+    from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                              NemotronHForCausalLM)
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.serving import ServingEngine
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    names = ("ssm.kernel_layers", "ssm.fallback_layers",
+             "moe.grouped_kernel_layers", "moe.ragged_dot_layers")
+    with pytest.MonkeyPatch.context() as mp:
+        # weights are never read here: zeros, not 1.3 B normals
+        mp.setattr(initializer.Normal, "__call__",
+                   lambda self, shape, dtype=None: jnp.asarray(np.zeros(
+                       tuple(shape), jnp.dtype(dtype or "float32"))))
+        for mod in (fa, gm, su):
+            mp.setattr(mod, "_interpret", lambda: False)
+        model = NemotronHForCausalLM(NemotronHConfig(
+            dtype="bfloat16", vocab_size=65536, num_hidden_layers=3,
+            hybrid_override_pattern="ME*", n_routed_experts=64,
+            router_experts=128))
+        model.eval()
+        conf = (Config().from_layer(
+            model, [paddle.to_tensor(np.zeros((1, 128), np.int32))])
+            .enable_tpu("bfloat16")
+            .enable_generation(max_new_tokens=512,
+                               prefill_buckets=(128, 1024), max_batch=256,
+                               do_sample=False)
+            .enable_serving(paged=True, kv_page_size=128, kv_pages=2048,
+                            cache_max_len=2048))
+        engine = ServingEngine(conf, warmup=False)
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        monitor.enable()
+        out = {}
+        try:
+            before = {k: metrics.gauge(k).value for k in names}
+            for key in (("step",), ("prefill", 128), ("prefill", 1024),
+                        ("admit",)):
+                program = engine._programs[key]
+                static = program._argnums(program.static)
+                avals = [
+                    op if i in static else jax.tree_util.tree_map(
+                        lambda a: jax.ShapeDtypeStruct(
+                            a.shape, a.dtype, sharding=one_chip), op)
+                    for i, op in enumerate(program.operands())]
+                compiled = jax.jit(
+                    program.fn, static_argnums=static,
+                    donate_argnums=program.donation_intent) \
+                    .lower(*avals).compile()
+                out[key] = (compiled.as_text(), compiled.memory_analysis())
+            out["gauges"] = {k: metrics.gauge(k).value - before[k]
+                             for k in names}
+        finally:
+            monitor.disable()
+            engine.shutdown()
+    return out
+
+
+@pytest.mark.parametrize("key,grouped,ssm", [
+    (("step",), 2, 1), (("prefill", 128), 2, 0),
+    (("prefill", 1024), 2, 0), (("admit",), 0, 0)],
+    ids=["step", "prefill-128", "prefill-1024", "admit"])
+def test_state_space_moe_programs_hold_their_kernels(nemotron_programs, key,
+                                                     grouped, ssm):
+    """``step``: the one-step state update and both grouped products are
+    custom calls, XLA's ``ragged-dot`` is gone (1856 columns stored as
+    1920), and the stacked float32 state (0.54 GB) is updated where it
+    lies: aliased, no state-sized scratch. ``prefill``: the chunked scan
+    is plain XLA, the experts take the kernel. ``admit`` writes the
+    slot's rows of both states in place."""
+    text, mem = nemotron_programs[key]
+    assert len(set(GROUPED.findall(text))) == grouped
+    assert len(set(SSM.findall(text))) == ssm
+    assert "ragged-dot" not in text
+    state_bytes = 256 * 64 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < state_bytes // 4
+    if key in (("step",), ("admit",)):
+        assert mem.alias_size_in_bytes >= state_bytes
+
+
+def test_state_space_moe_gauges_say_which_path(nemotron_programs):
+    g = nemotron_programs["gauges"]
+    assert g["ssm.kernel_layers"] == 1 and g["ssm.fallback_layers"] == 0
+    # one expert block traced in the step and in both prefill buckets
+    # (and wherever a program's operands are a prefill's shapes)
+    assert g["moe.grouped_kernel_layers"] >= 3
+    assert g["moe.ragged_dot_layers"] == 0

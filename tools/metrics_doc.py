@@ -90,9 +90,13 @@ device trace being taken. The sampled per-request segments
 
 On the DEVICE plane of such a trace the model's regions are named by
 `jax.named_scope`: `embed`, a block's mixer under its own name (`attn`
-for an attention layer, `short_conv` for a gated short convolution;
+for an attention layer, `short_conv` for a gated short convolution,
+`ssm` for a state-space mixer, inside it `ssm_conv`, `ssm_scan` (a
+prefill's chunked scan) or `ssm_update` (a decode step's one-step
+update; the kernel's trace name too) and `ssm_norm`;
 `block_attn` inside `attn` under block diffusion), `mlp` (inside it
-`moe_router` and `moe_experts` of a dropless expert layer), `lm_head`.
+`moe_router` and `moe_experts` of a dropless expert layer, `moe_shared`
+of a shared expert beside one), `lm_head`.
 
 | Span | Opened in, covers (fields) |
 |---|---|
