@@ -70,6 +70,11 @@ Metric name scheme (what the summary views group by):
                                 mixers' one-step updates traced onto
                                 kernels/ssm_update.py / onto XLA's
                                 fusion, over every program built
+    kv.write_kernel_layers / kv.write_scatter_layers   gauges: paged
+                                caches' per-layer writes of the new
+                                positions' K and V traced onto
+                                kernels/paged_write.py / onto XLA's row
+                                scatters, over every program built
     serve.requests{status=...}  terminal request outcomes (completed/
                                 cancelled/rejected) — QPS = rate of this
     serve.queue_depth           gauge: requests waiting for a slot
@@ -136,6 +141,7 @@ DECLARED_METRICS = frozenset({
     "moe.rows_elsewhere",
     "moe.grouped_kernel_layers", "moe.ragged_dot_layers",
     "ssm.kernel_layers", "ssm.fallback_layers",
+    "kv.write_kernel_layers", "kv.write_scatter_layers",
     "serve.requests", "serve.queue_depth", "serve.ttft",
     "serve.token_latency", "serve.slot_occupancy", "serve.cancellations",
     "serve.prefill.chunks", "serve.prefill.chunk_tokens",
@@ -334,6 +340,17 @@ METRIC_DOC = {
                             "was traced onto XLA's fusion of the same "
                             "arithmetic instead (the CPU, another state "
                             "type)"),
+    "kv.write_kernel_layers": ("gauge", (),
+                               "per-layer writes of the new positions' K "
+                               "and V into a page pool traced onto "
+                               "kernels/paged_write.py (a TPU, bfloat16 or "
+                               "float32 values, 4 rows a lane or more), "
+                               "summed over every program built"),
+    "kv.write_scatter_layers": ("gauge", (),
+                                "such writes traced onto XLA's row "
+                                "scatters instead (the CPU, the int8 pool, "
+                                "few rows a lane, a window longer than a "
+                                "sublane tile)"),
     "serve.requests": ("counter", ("status",),
                        "requests reaching a terminal status: completed "
                        "| cancelled | rejected (QPS = rate of this)"),
@@ -824,6 +841,18 @@ def record_ssm_path(kernel: bool):
         metrics.gauge("ssm.kernel_layers").add(1)
     else:
         metrics.gauge("ssm.fallback_layers").add(1)
+
+
+def record_kv_write_path(kernel: bool):
+    """One layer's write of the new positions' K and V into a page pool
+    was traced: onto the repo's kernel, or onto XLA's row scatters (trace
+    time, as :func:`record_moe_path`)."""
+    if not enabled:
+        return
+    if kernel:
+        metrics.gauge("kv.write_kernel_layers").add(1)
+    else:
+        metrics.gauge("kv.write_scatter_layers").add(1)
 
 
 def record_cache_occupancy(frac: float):

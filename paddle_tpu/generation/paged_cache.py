@@ -30,12 +30,23 @@ mechanism its GQA head mapping already uses):
   head_dim`` contiguous elements per layer, so everything that works
   on page ids (prefix sharing, copy-on-write, reclaim) is untouched by
   the order inside a page.
-- **Writes enumerate layer and head in the scatter's indices**, leaving
-  ``head_dim`` as the only window dimension. A scatter whose window
-  spans ``heads`` and ``head_dim`` (``pool.at[l, page, :, off]``) makes
-  XLA's layout assignment flip the whole stacked pool around every
-  write: two pool-sized copies per token. ``tests/test_tpu_compile.py``
-  holds the compiled programs to no pool-sized temporary.
+- **Two paths write a step's new rows, chosen by shape** (``update``).
+  On a TPU, a bfloat16 or float32 pool whose lanes each write 4 rows or
+  more (``kv_heads`` x positions) takes ``kernels/paged_write.py``: K
+  and V of a layer in ONE kernel, the stacked pools aliased to its
+  outputs, a lane's sublane tile read, merged and written back a grid
+  step, idle lanes skipped (62 us a layer where the scatters took 288
+  at 64 lanes x 32 heads: PERF.md section 5, PR 35).
+  ``paged_write.supports`` reads backend, dtype and shapes, nothing
+  else. Everything else (the CPU, the int8 pool and its scale sidecars,
+  2 rows a lane, a window longer than a tile) takes ``_scatter_tokens``,
+  one XLA scatter for K and one for V, about 70-95 ns a row. Its
+  **indices enumerate layer and head**, leaving ``head_dim`` as the
+  only window dimension: a scatter whose window spans ``heads`` and
+  ``head_dim`` (``pool.at[l, page, :, off]``) makes XLA's layout
+  assignment flip the whole stacked pool around every write, two
+  pool-sized copies per token. ``tests/test_tpu_compile.py`` holds the
+  compiled programs of both paths to no pool-sized temporary.
 - **Page 0 is the reserved null page**: masked install positions,
   out-of-table positions, and idle engine lanes (``kv_len == 0``, the
   finished-slot contract) all route their writes there. Nothing ever
@@ -73,6 +84,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import monitor as _monitor
+from ..kernels import paged_write as _paged_write
 from .kv_cache import KVCache, _raw, quantize_kv, validate_cache_dtype
 
 __all__ = ["PagedKVCache", "QuantPagedKVCache", "PageAllocator",
@@ -269,10 +282,19 @@ class PagedKVCache:
         dense cache: the model advances it once per forward)."""
         k_new, v_new = _raw(k_new), _raw(v_new)
         page, off = self._token_dest(pos, *k_new.shape[:2])
-        return PagedKVCache(
-            _scatter_tokens(self.k, layer, page, off, k_new),
-            _scatter_tokens(self.v, layer, page, off, v_new),
-            self.page_table, self.kv_len)
+        kernel = _paged_write.supports(self.k.shape, self.k.dtype,
+                                       k_new.shape[1])
+        _monitor.record_kv_write_path(kernel=kernel)
+        if kernel:
+            with jax.named_scope("paged_kv_write"):
+                k, v = _paged_write.paged_kv_write(
+                    self.k, self.v, layer, page, off,
+                    _to_pool_width(k_new, self.k),
+                    _to_pool_width(v_new, self.v))
+        else:
+            k = _scatter_tokens(self.k, layer, page, off, k_new)
+            v = _scatter_tokens(self.v, layer, page, off, v_new)
+        return PagedKVCache(k, v, self.page_table, self.kv_len)
 
     def install_row(self, src: KVCache, slot, table_row,
                     start) -> "PagedKVCache":
@@ -396,6 +418,7 @@ class QuantPagedKVCache(PagedKVCache):
         routing for idle/out-of-table positions as the wide pool."""
         k_new, v_new = _raw(k_new), _raw(v_new)
         page, off = self._token_dest(pos, *k_new.shape[:2])
+        _monitor.record_kv_write_path(kernel=False)
         kq, ks, kc = quantize_kv(k_new)
         vq, vs, vc = quantize_kv(v_new)
 

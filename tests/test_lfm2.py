@@ -188,15 +188,17 @@ def served(params):
         _, make = build(cfg, params)
         engine = make()
         monitor.enable()
+        recorder_was = flight_recorder.is_enabled()
+        since = flight_recorder.now_ns()      # this fixture's spans alone
         flight_recorder.enable()
         try:
             before = {k: fam.counter(k)
                       for k in ("moe.rows", "moe.expert_rows_max")}
             reqs = serve(engine)
             moe = {k: fam.counter(k) - v for k, v in before.items()}
-            spans = flight_recorder.spans_between(0, 2 ** 62)
+            spans = flight_recorder.spans_between(since, 2 ** 62)
         finally:
-            flight_recorder.disable()
+            flight_recorder.configure(on=recorder_was)
             monitor.disable()
     yield cfg, engine, reqs, moe, spans
     engine.shutdown()
@@ -284,12 +286,14 @@ def test_spans_carry_the_state_bytes(served):
 def test_cache_alloc_span_splits_kv_and_state(params):
     cfg = tiny_cfg()
     _, make = build(cfg, params)
+    recorder_was = flight_recorder.is_enabled()
+    since = flight_recorder.now_ns()      # this fixture's spans alone
     flight_recorder.enable()
     try:
         engine = make()
-        spans = flight_recorder.spans_between(0, 2 ** 62)
+        spans = flight_recorder.spans_between(since, 2 ** 62)
     finally:
-        flight_recorder.disable()
+        flight_recorder.configure(on=recorder_was)
     engine.shutdown()
     sp = [s for s in spans if s.name == "setup.cache_alloc"][-1].fields
     assert sp["state_bytes"] == 4 * 4 * 2 * 64 * 4

@@ -230,6 +230,8 @@ def served(params):
              "ssm.kernel_layers", "ssm.fallback_layers")
     with jax.default_matmul_precision("highest"):
         monitor.enable()
+        recorder_was = flight_recorder.is_enabled()
+        since = flight_recorder.now_ns()      # this fixture's spans alone
         flight_recorder.enable()
         try:
             before = {k: fam.counter(k) for k in names}
@@ -237,9 +239,9 @@ def served(params):
             engine = make()
             reqs = serve(engine)
             seen = {k: fam.counter(k) - v for k, v in before.items()}
-            spans = flight_recorder.spans_between(0, 2 ** 62)
+            spans = flight_recorder.spans_between(since, 2 ** 62)
         finally:
-            flight_recorder.disable()
+            flight_recorder.configure(on=recorder_was)
             monitor.disable()
     yield cfg, engine, reqs, seen, spans
     engine.shutdown()

@@ -30,6 +30,7 @@ import paddle_tpu.nn.functional as F
 from paddle_tpu.distributed import topology
 
 from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import paged_write as pw
 
 # the module, not the function of the same name that kernels/ exports
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
@@ -73,6 +74,7 @@ def as_on_tpu(monkeypatch):
     interpret mode, and the Pallas path instead of the XLA reference."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(pw, "_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
@@ -491,27 +493,87 @@ def compiled_donating(fn, *avals):
         .as_text()
 
 
+WRITE = re.compile(r"%(paged_kv_write[.\d]*) = ")
+DECODE = re.compile(r"%(flash_decode_paged[.\d]*) = ")
+WRITE_GAUGES = ("kv.write_kernel_layers", "kv.write_scatter_layers")
+
+
+def update_then_decode(cache, q, k, v):
+    """Per-layer ``update`` then the paged decode, over every layer of
+    the cache's stacked pool."""
+    out = 0
+    for layer in range(cache.num_layers):
+        cache = cache.update(layer, k, v, cache.kv_len)
+        out += fa.flash_attention_decode_paged(
+            q, cache.k, cache.v, cache.page_table,
+            cache.kv_len + k.shape[1], layer, **paged_scales(cache))
+    return cache, out
+
+
+def compiled_with_write_gauges(fn, *avals):
+    """(compiled text, how far each of the two gauges rose while ``fn``
+    was traced)."""
+    from paddle_tpu.core import metrics, monitor
+    monitor.enable()
+    try:
+        before = {k: metrics.gauge(k).value for k in WRITE_GAUGES}
+        text = compiled_donating(fn, *avals)
+        return text, {k: metrics.gauge(k).value - before[k]
+                      for k in WRITE_GAUGES}
+    finally:
+        monitor.disable()
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("s", [1, 4], ids=["s1", "s4"])
 def test_paged_step_reads_the_pool_in_place(one_chip, as_on_tpu, s, quant):
     """Per-layer ``update`` (decode, and a speculative window of 4) then
-    the paged decode, over all 8 layers of a donated pool."""
+    the paged decode, over all 8 layers of a donated pool: the bf16
+    pool's rows go through the write kernel, the int8 pool's (values and
+    scale sidecars) through the scatter, and neither copies the pool."""
     cache, sds = pool_avals(one_chip, POOL_D, POOL_HEADS, quant,
                             POOL_LAYERS, POOL_BATCH)
     new = sds((POOL_BATCH, s, POOL_HEADS, POOL_D), BF16)
-
-    def fn(cache, q, k, v):
-        out = 0
-        for layer in range(POOL_LAYERS):
-            cache = cache.update(layer, k, v, cache.kv_len)
-            out += fa.flash_attention_decode_paged(
-                q, cache.k, cache.v, cache.page_table, cache.kv_len + s,
-                layer, **paged_scales(cache))
-        return cache, out
-
-    text = compiled_donating(fn, cache, new, new, new)
-    assert text.count(KERNEL) >= POOL_LAYERS
+    text, took = compiled_with_write_gauges(update_then_decode, cache, new,
+                                            new, new)
+    assert len(set(DECODE.findall(text))) == POOL_LAYERS
+    writes = 0 if quant else POOL_LAYERS
+    assert len(set(WRITE.findall(text))) == writes
+    assert took == {"kv.write_kernel_layers": writes,
+                    "kv.write_scatter_layers": POOL_LAYERS - writes}
     assert not pool_sized_relayouts(text, cache.k.size // POOL_LAYERS)
+
+
+@pytest.mark.parametrize("lanes,s,hq,hk,pages,layers,writes", [
+    pytest.param(64, 1, 32, 32, 512, 8, 8, id="gpt3l8"),
+    pytest.param(128, 4, 32, 4, 1024, 6, 6, id="sdar-l6"),
+    pytest.param(128, 1, 32, 8, 1024, 3, 3, id="lfm2-l14"),
+    pytest.param(256, 1, 32, 2, 2048, 2, 0, id="nemotron3n-l13"),
+])
+def test_paged_step_at_the_cells_shapes(one_chip, as_on_tpu, lanes, s, hq, hk,
+                                        pages, layers, writes):
+    """``update`` + paged decode at the serve cells' own shapes (bf16, 16
+    table slots a lane): which cells' new rows go through the write
+    kernel (``paged_write.supports``: the rows a lane), and every
+    cell's program writes K and V into the donated pools where they lie
+    (aliased, no scratch of a layer's size)."""
+    from paddle_tpu.generation.paged_cache import PagedKVCache
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    pool = sds((layers, pages, hk, PAGE, 128), BF16)
+    cache = PagedKVCache(pool, pool, sds((lanes, 16), jnp.int32),
+                         sds((lanes,), jnp.int32))
+    new = sds((lanes, s, hk, 128), BF16)
+    compiled = jax.jit(update_then_decode, donate_argnums=(0,)).lower(
+        cache, sds((lanes, s, hq, 128), BF16), new, new).compile()
+    text = compiled.as_text()
+    assert len(set(DECODE.findall(text))) == layers
+    assert len(set(WRITE.findall(text))) == writes
+    assert not pool_sized_relayouts(text, cache.k.size // layers)
+    layer_bytes = 2 * cache.k.size // layers
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes // 4
+    assert mem.alias_size_in_bytes >= 2 * layers * layer_bytes
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -573,7 +635,7 @@ def nemotron_programs(topo):
         mp.setattr(initializer.Normal, "__call__",
                    lambda self, shape, dtype=None: jnp.asarray(np.zeros(
                        tuple(shape), jnp.dtype(dtype or "float32"))))
-        for mod in (fa, gm, su):
+        for mod in (fa, gm, su, pw):
             mp.setattr(mod, "_interpret", lambda: False)
         model = NemotronHForCausalLM(NemotronHConfig(
             dtype="bfloat16", vocab_size=65536, num_hidden_layers=3,

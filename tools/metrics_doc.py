@@ -94,7 +94,9 @@ for an attention layer, `short_conv` for a gated short convolution,
 `ssm` for a state-space mixer, inside it `ssm_conv`, `ssm_scan` (a
 prefill's chunked scan) or `ssm_update` (a decode step's one-step
 update; the kernel's trace name too) and `ssm_norm`;
-`block_attn` inside `attn` under block diffusion), `mlp` (inside it
+`block_attn` inside `attn` under block diffusion; `paged_kv_write`
+where a paged cache's new rows go through `kernels/paged_write.py`, the
+kernel's trace name too), `mlp` (inside it
 `moe_router` and `moe_experts` of a dropless expert layer, `moe_shared`
 of a shared expert beside one), `lm_head`.
 
