@@ -37,6 +37,20 @@ reader (the Profiler's Perfetto export and the benchmark's per-layer
 metrics go through it); ``dropped_since()`` tells a whole window from
 a cut one.
 
+A scheduler iteration that runs long says what it waited for. Every
+span boundary (open or close) is stamped per thread; a boundary that
+comes more than ``STALL_NS`` after the last one, with a ``serve.step``
+open on its thread, leaves a record, and the watcher (one daemon thread,
+started with the first ``serve.step``, awake every 50 ms) turns it into
+one ``serve.stall`` event: the silent stretch, the span it lay in, the
+garbage collector's part of it, and what every thread's stack showed
+while it lasted (``sys._current_frames()`` sampled by the watcher, which
+it can do only while something lets the interpreter go: a stall under
+the interpreter lock has no samples, and that is a finding too). Then
+one line through the logger and an ``auto_dump``. ``gc.callbacks`` put
+every collection on the same clock: a long or full one is a ``host.gc``
+span, and ``gc_ns()`` is the running total ``serve.step`` closes with.
+
 One clock: ``now_ns()`` is ``time.monotonic_ns()``, the clock serving
 requests and the benchmark's window are stamped with (on Linux CPython
 the same CLOCK_MONOTONIC reading as ``perf_counter_ns``, which the
@@ -50,21 +64,26 @@ prints its path to stderr, so the artifact is findable post-mortem).
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
+import linecache
 import os
+import re
 import sys
 import threading
 import time
+import weakref
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = [
     "DECLARED_EVENTS", "DECLARED_SPANS", "EVENT_DOC", "FlightRecorder",
     "Span", "auto_dump", "capacity", "clear", "clock_offset_ns",
     "configure", "disable", "dropped_since", "dump", "dump_dict",
-    "enable", "enabled", "events", "identity", "is_enabled", "now_ns",
-    "record", "record_span", "set_clock_offset_ns", "span",
-    "spans_between", "tail",
+    "STALL_NS", "enable", "enabled", "events", "gc_ns", "identity",
+    "is_enabled", "now_ns", "record", "record_span",
+    "set_clock_offset_ns", "span", "spans_between", "tail",
+    "thread_stacks",
 ]
 
 # The declared event-name families. Every point event recorded through
@@ -83,7 +102,7 @@ DECLARED_EVENTS = frozenset({
     "fit.crash",
     "serve.submit", "serve.evict", "serve.finish",
     "serve.prefill_chunk",
-    "serve.preempted", "serve.crash",
+    "serve.preempted", "serve.crash", "serve.stall",
     "serve.drain_begin", "serve.drain_end",
     "serve.router.reroute", "serve.router.breaker_open",
     "serve.router.breaker_probe", "serve.router.breaker_close",
@@ -120,6 +139,23 @@ EVENT_DOC = {
                            "remaining)",
     "serve.preempted": "preemption observed mid-serve (in_flight)",
     "serve.crash": "uncaught exception in serve_forever (error)",
+    "serve.stall": "no span boundary for over 250 ms on a thread with a "
+                   "serve.step open, stamped when the boundary came (ms = "
+                   "the silent stretch; span = the innermost span open "
+                   "through it, with its site / program / steps_queued / "
+                   "ahead; thread; gc_ms = collections inside it; "
+                   "samples = times the watcher saw every thread's stack "
+                   "while it lasted, 0 when the interpreter lock was "
+                   "held throughout; top = that thread's most frequent "
+                   "innermost frame and stack = the frames beneath it; "
+                   "others = threads seen running, not waiting, in half "
+                   "the samples or more; late_ms = the watcher's own "
+                   "longest oversleep while it lasted: near ms when "
+                   "whatever held the thread held the watcher too; "
+                   "cpu_ms / thread_cpu_ms = CPU time the process / the "
+                   "thread itself used from the watcher's last look "
+                   "before it to its report: near 0 = blocked, not "
+                   "computing)",
     "serve.drain_begin": "graceful drain started (queued, in_flight)",
     "serve.drain_end": "graceful drain finished",
     "serve.router.reroute": "the router re-routed a request to the "
@@ -165,9 +201,19 @@ EVENT_DOC = {
 DECLARED_SPANS = {
     "serve.step": "one ServingEngine.step() under the pump lock "
                   "(decode = decode steps it dispatched: 2 when its poll "
-                  "ran one ahead of its read, queued, live)",
+                  "ran one ahead of its read, queued, live; gc_ms = the "
+                  "garbage collections that ended inside it, on any "
+                  "thread: 0.0 when there was none)",
+    "serve.plan": "the head request's page plan and its commit in "
+                  "_pop_queue, under serve.step: the prompt's prefix "
+                  "hashed against the registry, its pages taken from the "
+                  "pool (req, pages, shared = prompt positions found "
+                  "there; blocked=1 when the pool was too full and the "
+                  "request stays queued); not opened while the pool is "
+                  "as it was at the last failure",
     "serve.admit": "one request's admission inside serve.step: host "
-                   "prep, prefill dispatch, the admit program's dispatch; "
+                   "prep (its self time), the prefill's and the admit "
+                   "program's serve.dispatch; "
                    "the wait for the prefill's token follows the "
                    "iteration's decode dispatch, a serve.sync under "
                    "serve.step "
@@ -184,8 +230,12 @@ DECLARED_SPANS = {
                   "behind; ahead = device programs dispatched after the "
                   "one it waits for: 0 = the device idles when the read "
                   "returns)",
-    "serve.dispatch": "the decode step's exe(...) call inside "
-                      "serve.step",
+    "serve.dispatch": "one program's exe(...) call inside serve.step, "
+                      "host side only, nothing waits (program = step / "
+                      "prefill / admit / poll_view / free / chunk / "
+                      "chunk_final / install_span: the program table's "
+                      "key; a decode step is program=step in every step "
+                      "mode)",
     "serve.poll": "one scheduler poll inside serve.step (steps = decode "
                   "steps its read covers, not the one a full engine "
                   "dispatches ahead of the read, emitted = tokens the lanes advanced "
@@ -195,6 +245,15 @@ DECLARED_SPANS = {
                   "blocks committed since the last poll; in every step "
                   "mode moe_rows = rows the dropless expert layers "
                   "computed since the last poll, where there are any)",
+    "serve.telemetry": "what a poll does for the monitor and not for "
+                       "the scheduler, inside serve.poll after the lanes "
+                       "are handled: token latency, per-request cost "
+                       "attribution, goodput charge and flush, cache and "
+                       "page occupancy, quantization clips, slo.tick()",
+    "host.gc": "one garbage collection of generation 2, or of any "
+               "generation that took over 1 ms, from gc.callbacks: child "
+               "of whatever span was open on the thread it ran on (gen, "
+               "collected, thread)",
     "serve.queue_wait": "every request: submit -> popped from the queue, "
                         "which is its admitted_at whether or not the "
                         "prefill then succeeds (trace id; req, bucket; "
@@ -222,13 +281,20 @@ DECLARED_SPANS = {
                   "(compiled=1 if this call built a program)",
 }
 
-# set-up plus a 51 s window plus its 60 s grace of the busiest benchmark
-# cell: ~9.5 scheduler iterations/s x 2.5 spans (step, dispatch, a poll
-# and its sync every 4th) + 1.2 requests/s x 7 (submit, finish events;
-# admit, sync, queue_wait, prefill spans; sampled decode segments) +
-# compiles ~ 60 events/s x 180 s ~ 11k, with a factor of 4 to spare and
-# rounded up to a power of two
-DEFAULT_CAPACITY = 65536
+# One whole run of a benchmark cell (set-up, the 51 s window, its grace),
+# four times over: the readers of set-up need the whole process in the
+# ring, and a window the ring cut reads None in every span metric at
+# once. Counted on the chip at PR 36, with the iteration's finer spans:
+# nemotron3n-l13-offline 37,382 events a run (256 lanes whose sampled
+# requests leave a decode segment a poll: 14,649 of them),
+# sdar-l6-offline 32,375-35,134 (34 runs), gpt3l8-offline 30,328,
+# lfm2-l14-offline 28,266, gpt3l8-chat 24,079-27,035 (13 runs: 7,600
+# iterations of 2 spans and a poll of 5 every 4th);
+# four times the busiest rounds up to 2**18. An event is about 410 bytes
+# (its tuple, its field dict, its stamps): 107 MB if a long-lived
+# process fills the ring, 10-15 MB over a benchmark run; the field dicts
+# hold only numbers and strings, which the collector does not track.
+DEFAULT_CAPACITY = 262144
 # auto-dumps are capped per process: a watchdog storm must not write
 # hundreds of files or spend its dying seconds serializing JSON
 MAX_AUTO_DUMPS = 16
@@ -501,7 +567,9 @@ class FlightRecorder:
                     exist_ok=True)
         json_path = path_prefix + ".json"
         with open(json_path, "w") as f:
-            json.dump(self.dump_dict(reason, last), f)
+            # one call into the C encoder: a dump from the stall watcher
+            # must not trade the interpreter with the scheduler for long
+            f.write(json.dumps(self.dump_dict(reason, last)))
         with open(path_prefix + ".txt", "w") as f:
             rank, restart, pid = identity()
             f.write(f"flight recorder dump — reason: {reason}, "
@@ -582,6 +650,8 @@ def configure(capacity: Optional[int] = None,
         _recorder = FlightRecorder(capacity)
     if on is not None:
         enabled = bool(on)
+        if not enabled:
+            _disarm()
     return _recorder
 
 
@@ -597,6 +667,7 @@ def enable():
 def disable():
     global enabled
     enabled = False
+    _disarm()
 
 
 def is_enabled() -> bool:
@@ -622,8 +693,74 @@ def record_span(name: str, start_ns: int, end_ns: int,
                                  parent=parent, **fields)
 
 
-_tls = threading.local()   # .span: the innermost open span of a thread
+# ------------------------------------------------- threads and boundaries
+
+# A span boundary this long after the last one, with a serve.step open,
+# is a stall. A constant, not a knob: the longest silent stretch of a
+# sound window is a poll's read of four steps (about 100 ms) or a
+# 1024-bucket prefill (24-27 ms); set-up's first iteration (the page
+# pool's arrival, 22-29 s) fires it once, which is right.
+STALL_NS = 250_000_000
+_WATCHED = "serve.step"     # the root span a stall is looked for under
+_WATCH_S = 0.05             # the watcher's sleep
+_MAX_SAMPLES = 40           # of every thread's stack, a stall
+_STACK_FRAMES = 12          # innermost frames kept a thread
+
+
+class _Thread:
+    """What the recorder keeps of one thread: ``span``, its innermost
+    open span; ``stamp``, its last span boundary (open or close);
+    ``silences``, the stalls it has found at its own boundaries and the
+    watcher has not yet reported."""
+    __slots__ = ("span", "stamp", "ident", "name", "silences",
+                 "cpu_clock", "__weakref__")
+
+    def __init__(self):
+        t = threading.current_thread()
+        self.span = None
+        self.stamp = now_ns()
+        self.ident, self.name = t.ident, t.name
+        self.silences = collections.deque()
+        try:    # the thread's own CPU clock, for the watcher to read
+            self.cpu_clock = time.pthread_getcpuclockid(t.ident)
+        except (AttributeError, OSError):
+            self.cpu_clock = None
+
+    def cpu_ns(self) -> int:
+        """CPU time this thread has used (0 where it cannot be read)."""
+        try:
+            return time.clock_gettime_ns(self.cpu_clock)
+        except (TypeError, OSError):
+            return 0
+
+
+_tls = threading.local()   # .st: this thread's _Thread
+_threads = weakref.WeakSet()   # every live thread's: what the watcher walks
 _annotation = None         # jax.profiler.TraceAnnotation, bound lazily
+
+
+def _this_thread() -> _Thread:
+    st = _tls.st = _Thread()
+    _threads.add(st)
+    return st
+
+
+def _silent(st: _Thread, t_ns: int, inner) -> None:
+    """``st`` stamped no boundary for over STALL_NS until ``t_ns``, with
+    ``inner`` its innermost open span all the while: a stall if that was
+    inside a serve.step (a silence between iterations is a wait for
+    work, or the profiler starting). Left for the watcher to report: the
+    scheduler's thread only notes it."""
+    if _watch is not None and _in_iteration(inner):
+        st.silences.append((st.stamp, t_ns, inner.name,
+                            dict(inner.fields)))
+
+
+def _in_iteration(span) -> bool:
+    """Whether ``span``'s outermost ancestor is a serve.step."""
+    while span._outer is not None:
+        span = span._outer
+    return span.name == _WATCHED
 
 
 class _OpenSpan:
@@ -631,7 +768,7 @@ class _OpenSpan:
     the one stamp of each boundary: callers derive their own timings
     from them instead of reading the clock again."""
     __slots__ = ("name", "id", "parent", "start_ns", "end_ns", "fields",
-                 "_rec", "_ann", "_outer")
+                 "_rec", "_ann", "_outer", "_st")
 
     def __init__(self, name, fields):
         self.name = name
@@ -647,22 +784,35 @@ class _OpenSpan:
         if _annotation is None:
             from jax.profiler import TraceAnnotation
             _annotation = TraceAnnotation
+        st = self._st = getattr(_tls, "st", None) or _this_thread()
         self._rec = _recorder
         self.id = next(self._rec._span_ids)
-        self._outer = getattr(_tls, "span", None)
-        self.parent = None if self._outer is None else self._outer.id
-        _tls.span = self
+        outer = self._outer = st.span
+        if outer is not None:
+            self.parent = outer.id
+        else:
+            self.parent = None
+            if _watch is None and self.name == _WATCHED:
+                _arm()
+        st.span = self
         # the fields known at the start ride along as the event's stats
         self._ann = _annotation(self.name, **self.fields)
         self._ann.__enter__()
-        self.start_ns = now_ns()
+        t = self.start_ns = now_ns()
+        if t - st.stamp > STALL_NS and outer is not None:
+            _silent(st, t, outer)
+        st.stamp = t
         return self
 
     def __exit__(self, et, ev, tb):
-        self.end_ns = now_ns()
+        st = self._st
+        t = self.end_ns = now_ns()
+        if t - st.stamp > STALL_NS:
+            _silent(st, t, self)
+        st.stamp = t
         self._ann.__exit__(et, ev, tb)
-        _tls.span = self._outer
-        self._rec.record_span(self.name, self.start_ns, self.end_ns,
+        st.span = self._outer
+        self._rec.record_span(self.name, self.start_ns, t,
                               parent=self.parent, span_id=self.id,
                               **self.fields)
         return False
@@ -698,6 +848,312 @@ def span(name: str, **fields):
     if not enabled:
         return _NO_SPAN
     return _OpenSpan(name, fields)
+
+
+# ------------------------------------------------------ garbage collection
+
+GC_SPAN_NS = 1_000_000     # a collection this long is a host.gc span
+_gc_total_ns = 0           # every finished collection's time, summed
+_gc_t0 = 0                 # the start of the collection now running
+_gc_ann = None             # its TraceAnnotation (generation 2 only)
+# the collections that became spans, newest last: (start, end) on the
+# recorder's clock, for a stall to find its own among
+_gc_spans: "collections.deque[Tuple[int, int]]" = collections.deque(
+    maxlen=256)
+# host.gc spans (start, fields) not yet in the ring: see _flush_gc
+_gc_pending: "collections.deque[Tuple[int, dict]]" = collections.deque(
+    maxlen=256)
+
+
+def gc_ns() -> int:
+    """Nanoseconds the garbage collector has run since the recorder's
+    callback was registered, over every finished collection on every
+    thread (the interpreter runs one at a time, and it stops them all):
+    ``serve.step`` closes with the difference across itself."""
+    return _gc_total_ns
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry: runs on the collecting thread, under
+    the interpreter lock, with collection off while it does."""
+    global _gc_total_ns, _gc_t0, _gc_ann
+    if phase == "start":
+        if info["generation"] == 2 and _annotation is not None:
+            # a full collection also lies on a device trace's host plane
+            _gc_ann = _annotation("host.gc", gen=2)
+            _gc_ann.__enter__()
+        _gc_t0 = now_ns()
+        return
+    t1 = now_ns()
+    t0, _gc_t0 = _gc_t0, 0
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    if not t0:      # registered while this collection ran
+        return
+    _gc_total_ns += t1 - t0
+    gen = info["generation"]
+    if gen == 2 or t1 - t0 > GC_SPAN_NS:
+        _gc_spans.append((t0, t1))
+        f = {"name": "host.gc", "end_ns": t1, "tid": 0, "gen": gen,
+             "collected": info.get("collected", 0),
+             "thread": threading.current_thread().name}
+        st = getattr(_tls, "st", None)
+        if st is not None and st.span is not None:
+            f["parent"] = st.span.id
+        _gc_pending.append((t0, f))
+    if _gc_pending:
+        _flush_gc()
+
+
+def _flush_gc() -> None:
+    """Put the pending host.gc spans in the ring. A collection can start
+    at any allocation, the ring's own append under its lock among them:
+    the callback must not wait for that lock, so it only tries it, and
+    what it could not record goes in at the next collection's end or the
+    watcher's next wake-up."""
+    rec = _recorder
+    if rec._lock.acquire(blocking=False):
+        try:
+            while _gc_pending:
+                t0, f = _gc_pending.popleft()
+                f["id"] = next(rec._span_ids)
+                rec._append((t0, "span", f))
+        finally:
+            rec._lock.release()
+
+
+def _gc_ms_between(t0_ns: int, t1_ns: int) -> float:
+    """The part of ``[t0_ns, t1_ns]`` that host.gc collections cover."""
+    return sum(max(min(e, t1_ns) - max(b, t0_ns), 0)
+               for b, e in list(_gc_spans)) / 1e6
+
+
+# ------------------------------------------------------------- the watcher
+
+def _frames(frame, limit: Optional[int]) -> List[tuple]:
+    """``(file, line, function)`` of ``frame`` and its callers,
+    innermost first, ``limit`` at most."""
+    out = []
+    while frame is not None and (limit is None or len(out) < limit):
+        code = frame.f_code
+        out.append((code.co_filename, frame.f_lineno, code.co_name))
+        frame = frame.f_back
+    return out
+
+
+def _frame_text(fr: tuple) -> str:
+    return f"{fr[0]}:{fr[1]} {fr[2]}"
+
+
+def _other_threads(limit: Optional[int]):
+    """``(ident, name, frames)`` of every thread but the caller's."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    me = threading.get_ident()
+    for tid, frame in sys._current_frames().items():
+        if tid != me:
+            yield tid, names.get(tid, "?"), _frames(frame, limit)
+
+
+def thread_stacks(limit: Optional[int] = None) -> Dict[str, List[str]]:
+    """Every thread's stack but the caller's, as ``file:line function``
+    lines, innermost first, ``limit`` frames at most; keyed
+    ``name (ident)``. What the watchdog dumps and the stall watcher
+    samples."""
+    return {f"{name} ({tid})": [_frame_text(fr) for fr in frames]
+            for tid, name, frames in _other_threads(limit)}
+
+
+# an innermost frame that is a thread parked, not one at work: by the
+# standard library's function, or by what its source line calls
+_WAIT_FUNCS = {("threading.py", "wait"), ("threading.py", "acquire"),
+               ("threading.py", "join"),
+               ("threading.py", "_wait_for_tstate_lock"),
+               ("queue.py", "get"), ("queue.py", "put"),
+               ("selectors.py", "select"), ("socket.py", "accept")}
+_WAIT_CALL = re.compile(
+    r"\b(sleep|wait|wait_for|acquire|select|get|join|accept|recv)\(")
+
+
+def _is_wait(fr: tuple) -> bool:
+    if (os.path.basename(fr[0]), fr[2]) in _WAIT_FUNCS:
+        return True
+    return bool(_WAIT_CALL.search(linecache.getline(fr[0], fr[1])))
+
+
+class _Samples:
+    """Every thread's stack, as the watcher saw it while one silence
+    (the one that began at ``t0_ns``) lasted."""
+
+    def __init__(self, t0_ns: int, late_ns: int, cpu0: Tuple[int, int]):
+        self.t0_ns = t0_ns
+        self.n = 0
+        self.stacks: Dict[int, collections.Counter] = {}
+        self.names: Dict[int, str] = {}
+        self.late_ns = late_ns      # the watcher's own longest oversleep
+        self.cpu0 = cpu0            # (process, thread) CPU before it
+
+    def take(self) -> None:
+        for tid, name, frames in _other_threads(_STACK_FRAMES):
+            self.names[tid] = name
+            self.stacks.setdefault(tid, collections.Counter())[
+                tuple(frames)] += 1
+        self.n += 1
+
+    def fields(self, ident: int) -> dict:
+        """``top`` / ``stack`` of thread ``ident``, ``others`` that were
+        at work in half the samples or more."""
+        out = {"samples": self.n}
+        mine = self.stacks.get(ident)
+        if mine:
+            stack = mine.most_common(1)[0][0]
+            inner = collections.Counter()
+            for frames, n in mine.items():
+                inner[frames[0]] += n
+            out["top"] = _frame_text(inner.most_common(1)[0][0])
+            out["stack"] = " < ".join(_frame_text(f) for f in stack)
+        others = []
+        for tid, seen in self.stacks.items():
+            if tid == ident:
+                continue
+            busy = collections.Counter()
+            for frames, n in seen.items():
+                if not _is_wait(frames[0]):
+                    busy[frames[0]] += n
+            if busy and 2 * sum(busy.values()) >= self.n:
+                others.append(f"{self.names.get(tid, '?')}: "
+                              + _frame_text(busy.most_common(1)[0][0]))
+        out["others"] = "; ".join(sorted(others))
+        return out
+
+
+class _Watch:
+    """The recorder's one watcher thread. Asleep ``_WATCH_S`` at a time;
+    awake it reads each thread's last boundary stamp and innermost span.
+    While a thread with a ``serve.step`` open has stamped nothing for
+    ``STALL_NS`` it samples every thread's stack; when that thread's own
+    next boundary has noted the silence (``_silent``) it records the
+    ``serve.stall`` event, logs one line and dumps the ring."""
+
+    def __init__(self):
+        self._stop = threading.Event()
+        self._sampling: "weakref.WeakKeyDictionary[_Thread, _Samples]" \
+            = weakref.WeakKeyDictionary()
+        # (process, thread) CPU time at the last wake-up that found the
+        # thread stamping, or between iterations: what a silence's CPU
+        # is counted from
+        self._cpu0: "weakref.WeakKeyDictionary[_Thread, Tuple[int, int]]" \
+            = weakref.WeakKeyDictionary()
+        self._thread = threading.Thread(
+            target=self._loop, name="flight-recorder-watch", daemon=True)
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+
+    def _loop(self) -> None:
+        t_last = now_ns()
+        while not self._stop.wait(_WATCH_S):
+            if _gc_pending:
+                _flush_gc()
+            t = now_ns()
+            late = max(t - t_last - int(_WATCH_S * 1e9), 0)
+            t_last = t
+            for st in list(_threads):
+                try:
+                    self._look(st, t, late)
+                except Exception as e:  # noqa: BLE001 — the watcher lives
+                    from . import monitor
+                    monitor.record_swallowed("flight_recorder.watch", e)
+
+    def _look(self, st: _Thread, t_ns: int, late_ns: int) -> None:
+        seen = self._sampling.get(st)
+        if seen is not None:
+            seen.late_ns = max(seen.late_ns, late_ns)
+        while st.silences:
+            t0, t1, name, fields = st.silences.popleft()
+            if seen is None or seen.t0_ns != t0:
+                # never awake inside it: whatever held the scheduler's
+                # thread held the watcher too
+                seen = _Samples(t0, late_ns, self._cpu0.get(st, (0, 0)))
+            self._report(st, t0, t1, name, fields, seen)
+        stamp, inner = st.stamp, st.span
+        if inner is None or t_ns - stamp < STALL_NS:
+            if seen is not None:
+                self._sampling.pop(st, None)
+            if inner is None or t_ns - stamp < int(_WATCH_S * 1e9):
+                self._cpu0[st] = (time.process_time_ns(), st.cpu_ns())
+            return
+        if not _in_iteration(inner):
+            return
+        if seen is None or seen.t0_ns != stamp:
+            seen = self._sampling[st] = _Samples(
+                stamp, late_ns, self._cpu0.get(st, (0, 0)))
+        if seen.n < _MAX_SAMPLES:
+            seen.take()
+
+    def _report(self, st, t0, t1, name, fields, seen) -> None:
+        ev = {"ms": round((t1 - t0) / 1e6, 3), "span": name,
+              "thread": st.name}
+        ev.update((k, fields[k]) for k in
+                  ("site", "program", "steps_queued", "ahead")
+                  if k in fields)
+        ev["gc_ms"] = round(_gc_ms_between(t0, t1), 3)
+        ev["late_ms"] = round(seen.late_ns / 1e6, 3)
+        if seen.cpu0 != (0, 0):
+            ev["cpu_ms"] = round(
+                (time.process_time_ns() - seen.cpu0[0]) / 1e6, 3)
+            ev["thread_cpu_ms"] = round(
+                (st.cpu_ns() - seen.cpu0[1]) / 1e6, 3)
+        ev.update(seen.fields(st.ident))
+        _recorder.record("serve.stall", t_ns=t1, **ev)
+        import logging
+        logging.getLogger("paddle_tpu.flight_recorder").warning(
+            "serve.stall: %s", " ".join(f"{k}={v}" for k, v in ev.items()
+                                        if k != "stack"))
+        _recorder.auto_dump("serve_stall")
+
+
+_watch: Optional[_Watch] = None
+_arm_lock = threading.Lock()
+
+
+def _arm() -> None:
+    """The first ``serve.step`` of an enabled recorder: start the
+    watcher, put the collector on the record."""
+    global _watch
+    with _arm_lock:
+        if _watch is None and enabled:
+            if _on_gc not in gc.callbacks:
+                gc.callbacks.append(_on_gc)
+            _watch = _Watch()
+
+
+def _disarm() -> None:
+    """The recorder is off: no watcher, nothing of ours in
+    gc.callbacks."""
+    global _watch
+    with _arm_lock:
+        if _watch is not None:
+            _watch.stop()
+            _watch = None
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+
+
+def _forked() -> None:
+    """A forked child has no thread but its own, and a lock another
+    thread held stays held: start over (its first serve.step, if it
+    ever runs one, arms it again)."""
+    global _watch, _arm_lock
+    _arm_lock = threading.Lock()
+    _watch = None
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_forked)
 
 
 def events(last: Optional[int] = None) \

@@ -447,7 +447,8 @@ METRIC_DOC = {
     "flightrecorder.dumps": ("counter", ("reason",),
                              "flight-recorder dump files written "
                              "(watchdog | preemption | anomaly_restore "
-                             "| serve_crash | fit_crash | manual)"),
+                             "| serve_crash | serve_stall | fit_crash "
+                             "| manual)"),
     "fleet.publishes": ("counter", (),
                         "metric snapshots this process published to "
                         "the fleet TCPStore (delta-encoded)"),
@@ -1175,7 +1176,7 @@ def record_scrape(endpoint: str):
 
 def record_flight_dump(reason: str):
     """One flight-recorder dump written (watchdog | preemption |
-    anomaly_restore | serve_crash | fit_crash | manual)."""
+    anomaly_restore | serve_crash | serve_stall | fit_crash | manual)."""
     if not enabled:
         return
     metrics.counter("flightrecorder.dumps", reason=reason).inc()

@@ -32,7 +32,6 @@ import signal
 import sys
 import threading
 import time
-import traceback
 from typing import Callable, List, Optional, Tuple
 
 from ..core import flight_recorder, monitor
@@ -70,16 +69,21 @@ def dump_stacks(label: str, timeout: float) -> None:
 
 
 def _dump_all_stacks(label: str, timeout: float) -> None:
+    sys.stderr.write(_all_stacks_text(label, timeout))
+    sys.stderr.flush()
+
+
+def _all_stacks_text(label: str, timeout: float) -> str:
+    """The watchdog's dump as text: every other thread's stack in the
+    recorder's ``file:line function`` form (what its stall watcher
+    samples), outermost frame first."""
     lines = [f"\n=== Watchdog '{label}' expired after {timeout:.1f}s — "
              f"dumping {threading.active_count()} thread stacks ==="]
-    frames = sys._current_frames()
-    names = {t.ident: t.name for t in threading.enumerate()}
-    for tid, frame in frames.items():
-        lines.append(f"--- thread {names.get(tid, '?')} ({tid}) ---")
-        lines.append("".join(traceback.format_stack(frame)))
+    for thread, frames in flight_recorder.thread_stacks().items():
+        lines.append(f"--- thread {thread} ---")
+        lines.extend("  " + f for f in reversed(frames))
     lines.append("=== end watchdog dump ===\n")
-    sys.stderr.write("\n".join(lines))
-    sys.stderr.flush()
+    return "\n".join(lines)
 
 
 _tls = threading.local()

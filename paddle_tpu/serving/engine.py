@@ -90,10 +90,15 @@ as the step before it left them (the ``poll_view`` program's copies:
 the step donates the lanes themselves). ``serve.sync``'s ``ahead`` says
 how many programs were queued behind the one a read waited for.
 
-Every scheduler iteration, admission, blocking read, decode dispatch
-and poll is a flight-recorder span (``serve.step`` > ``serve.admit`` /
-``serve.dispatch`` / ``serve.poll`` > ``serve.sync{site=...}``;
-``core/flight_recorder.DECLARED_SPANS``), and every request records
+Every scheduler iteration, page plan, admission, blocking read, program
+dispatch and poll is a flight-recorder span (``serve.step`` >
+``serve.plan`` / ``serve.admit`` / ``serve.dispatch{program}`` /
+``serve.poll`` > ``serve.sync{site=...}`` / ``serve.telemetry``;
+``core/flight_recorder.DECLARED_SPANS``): no span but ``serve.sync``
+holds a blocking read, so an iteration's host time splits by purpose.
+``serve.step`` closes with the garbage collector's time inside it
+(``gc_ms``), and one that stamps no boundary for 250 ms leaves a
+``serve.stall`` event (the recorder's watcher). Every request records
 ``serve.queue_wait`` + ``serve.prefill``. Each boundary is stamped once,
 on the recorder's clock; the request's ``admitted_at`` /
 ``first_token_at``, the TTFT and per-token latency metrics, the goodput
@@ -872,7 +877,7 @@ class ServingEngine:
         poll of a full engine dispatches one more step before it reads
         (``_poll_lanes``)."""
         with self._pump_lock, flight_recorder.span("serve.step") as sp:
-            steps0 = self.stats["decode_steps"]
+            steps0, gc0 = self.stats["decode_steps"], flight_recorder.gc_ns()
             admitted = self._admit_ready()
             live = sum(s is not None
                        and s.status is RequestStatus.RUNNING
@@ -884,7 +889,8 @@ class ServingEngine:
             if self._steps_since_poll >= self.poll_every:
                 self._poll()
             sp.set(decode=self.stats["decode_steps"] - steps0, live=live,
-                   queued=len(self._queue))
+                   queued=len(self._queue),
+                   gc_ms=(flight_recorder.gc_ns() - gc0) / 1e6)
 
     def _unblock_if(self, req: Request):
         """Clear the page-pressure flag when the request it was
@@ -930,9 +936,16 @@ class ServingEngine:
                     if self._blocked_key == (req.id, ver):
                         self._page_blocked = True
                         return None
-                    plan = self._alloc.plan(
-                        req.prompt, req.budget + self._overhang)
-                    pages = self._alloc.commit(plan)
+                    with flight_recorder.span("serve.plan",
+                                              req=req.id) as sp:
+                        plan = self._alloc.plan(
+                            req.prompt, req.budget + self._overhang)
+                        pages = self._alloc.commit(plan)
+                        if pages is None:
+                            sp.set(blocked=1)
+                        else:
+                            sp.set(pages=len(pages),
+                                   shared=int(plan.shared_len))
                     if pages is None:
                         # a failed commit may still have reclaimed
                         # cached pages — key on the post-attempt version
@@ -1025,8 +1038,12 @@ class ServingEngine:
         self._window_t0_ns = t1
 
     def _run(self, key, *operands):
-        """Dispatch one warm program of the table. Nothing waits."""
-        out = self._compiled(key)(*operands)
+        """Dispatch one warm program of the table under its
+        ``serve.dispatch`` (``program``: the key's name). Nothing
+        waits."""
+        exe = self._compiled(key)
+        with flight_recorder.span("serve.dispatch", program=key[0]):
+            out = exe(*operands)
         self._seq += 1
         return out
 
@@ -1117,8 +1134,10 @@ class ServingEngine:
         ids[0, :req.prompt.size] = req.prompt
         plen = np.array([self._mode.prefill_len(req.prompt)], np.int32)
         exe = self._exe_prefill(bucket)
-        tok, row_cache, self._key, fin = exe(
-            self._state, jnp.asarray(ids), jnp.asarray(plen), self._key)
+        with flight_recorder.span("serve.dispatch", program="prefill"):
+            tok, row_cache, self._key, fin = exe(
+                self._state, jnp.asarray(ids), jnp.asarray(plen),
+                self._key)
         self._seq += 1
         after = self._mark()
         self._install(req, slot, row_cache, tok, fin)
@@ -1329,10 +1348,12 @@ class ServingEngine:
         self._note_cost(req)
 
     def _dispatch_decode(self):
-        with flight_recorder.span("serve.dispatch") as sp:
-            self._cache, self._lanes, self._key = self._run(
-                self._mode.key,
+        exe = self._compiled(self._mode.key)
+        # (every step mode's decode step is program "step")
+        with flight_recorder.span("serve.dispatch", program="step") as sp:
+            self._cache, self._lanes, self._key = exe(
                 self._state, self._cache, self._lanes, self._key)
+        self._seq += 1
         self._steps_since_poll += 1
         if self._chunking is not None:
             # decode steps interleaved into THIS chunked admission —
@@ -1404,37 +1425,8 @@ class ServingEngine:
 
         drained = self._mode.drain(seen.counters, self.stats)
         now = t_ns * 1e-9
-        window_dt = 0.0
-        if t_window is not None and n_window:
-            window_dt = (t_ns - t_window) * 1e-9
-            monitor.record_serve_token_latency(window_dt / n_window)
-            # the dispatch window (host dispatches + the device wait
-            # the lane reads above just paid) is goodput compute
-            self._goodput.charge("compute", window_dt)
-        if window_dt > 0.0:
-            # cost attribution: every live request owns an equal share
-            # of the window the ledger just booked as compute (shares
-            # sum to the window — Request.cost() reconciles against
-            # the compute bucket), plus page*seconds for its resident
-            # KV pages. Charged BEFORE completions below, so a request
-            # finishing this window still pays for it.
-            # PENDING_PREFILL slots are NOT in the decode window: the
-            # chunk walls charge to prefill_s in _advance_chunked —
-            # charging a share here would double-bill the request
-            live = sum(r is not None
-                       and r.status is not RequestStatus.PENDING_PREFILL
-                       for r in self._slots)
-            if live:
-                share = window_dt / live
-                for i, r in enumerate(self._slots):
-                    if r is None or \
-                            r.status is RequestStatus.PENDING_PREFILL:
-                        continue
-                    r._cost_decode_s += share
-                    if self._alloc is not None:
-                        pages = self._row_pages[i]
-                        if pages:
-                            r._cost_page_s += len(pages) * window_dt
+        with flight_recorder.span("serve.telemetry"):
+            self._charge_window(t_ns, t_window, n_window)
         emitted = admitted = completed = evicted = 0
         for i, req in enumerate(self._slots):
             if req is None:
@@ -1479,20 +1471,59 @@ class ServingEngine:
                     self._unblock_if(req)
                     self._cancel(req, "deadline")
             monitor.record_serve_queue_depth(len(self._queue))
-        monitor.record_serve_slot_occupancy(
-            sum(s is not None for s in self._slots) / self.max_batch)
-        if monitor.enabled:
-            # the cache's own occupancy() reads kv_len off the device:
-            # behind the step in flight, were it called here
-            monitor.record_cache_occupancy(
-                float(seen.kv_len.max()) / self._cache.max_len)  # lint: host-sync-ok (host array)
-            self._drain_page_stats()
-            self._drain_quant_stats(seen.clips)
-            self._goodput.flush()
-            # SLO watchtower: sample the time-series ring + evaluate
-            # burn rates at most once per ring period (fast path is a
-            # float compare — gated in test_overhead_gate)
-            slo_mod.tick()
+        with flight_recorder.span("serve.telemetry"):
+            monitor.record_serve_slot_occupancy(
+                sum(s is not None for s in self._slots) / self.max_batch)
+            if monitor.enabled:
+                # the cache's own occupancy() reads kv_len off the
+                # device: behind the step in flight, were it called here
+                monitor.record_cache_occupancy(
+                    float(seen.kv_len.max()) / self._cache.max_len)  # lint: host-sync-ok (host array)
+                self._drain_page_stats()
+                self._drain_quant_stats(seen.clips)
+                self._goodput.flush()
+                # SLO watchtower: sample the time-series ring + evaluate
+                # burn rates at most once per ring period (fast path is
+                # a float compare — gated in test_overhead_gate)
+                slo_mod.tick()
+
+    def _charge_window(self, t_ns: int, t_window: Optional[int],
+                       n_window: int):
+        """Book the decode window a poll's read just closed, BEFORE the
+        poll completes anything (a request finishing this window still
+        pays for it): per-token latency, goodput compute, and each live
+        request's share. Telemetry: the scheduler reads none of it."""
+        if t_window is None or not n_window:
+            return
+        window_dt = (t_ns - t_window) * 1e-9
+        monitor.record_serve_token_latency(window_dt / n_window)
+        # the dispatch window (host dispatches + the device wait the
+        # lane reads just paid) is goodput compute
+        self._goodput.charge("compute", window_dt)
+        if window_dt > 0.0:
+            # cost attribution: every live request owns an equal share
+            # of the window the ledger just booked as compute (shares
+            # sum to the window — Request.cost() reconciles against
+            # the compute bucket), plus page*seconds for its resident
+            # KV pages. Charged BEFORE completions below, so a request
+            # finishing this window still pays for it.
+            # PENDING_PREFILL slots are NOT in the decode window: the
+            # chunk walls charge to prefill_s in _advance_chunked —
+            # charging a share here would double-bill the request
+            live = sum(r is not None
+                       and r.status is not RequestStatus.PENDING_PREFILL
+                       for r in self._slots)
+            if live:
+                share = window_dt / live
+                for i, r in enumerate(self._slots):
+                    if r is None or \
+                            r.status is RequestStatus.PENDING_PREFILL:
+                        continue
+                    r._cost_decode_s += share
+                    if self._alloc is not None:
+                        pages = self._row_pages[i]
+                        if pages:
+                            r._cost_page_s += len(pages) * window_dt
 
     def _read_row(self, slot: int):
         """One lane's result rows (the mode's ``row`` lanes, in position

@@ -15,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import types
 
 import pytest
@@ -48,6 +49,10 @@ def _span_metrics():
 
 
 SPAN_METRICS = _span_metrics()
+# ISSUE 36; the last three read spans and fields that ISSUE adds
+ITERATION_METRICS = ["longest_silence_ms.serve",
+                     "host_gc_ms_per_step.serve",
+                     "telemetry_ms_per_step.serve", "admit_host_ms.serve"]
 
 
 @pytest.fixture(scope="module")
@@ -64,8 +69,10 @@ def spec_path(tmp_path_factory):
 
 
 def test_nine_new_readers_are_declared():
-    # ISSUE 26's nine and ISSUE 31's sync_covered_share.serve
-    assert len(SPAN_METRICS) == 9 + 1
+    # ISSUE 26's nine, ISSUE 31's sync_covered_share.serve and ISSUE
+    # 36's four (the iteration's own account)
+    assert len(SPAN_METRICS) == 9 + 1 + 4
+    assert {m["name"] for m in SPAN_METRICS} >= set(ITERATION_METRICS)
     for m in SPAN_METRICS:
         assert os.path.exists(
             os.path.join(BENCH, "metrics", m["name"] + ".py")), m["name"]
@@ -137,12 +144,19 @@ def _run(t_proc_ns, t_open_ns, t_close_ns):
         program_median_ms=lambda which: None)
 
 
-def _traffic(ahead=(1, 0)):
+def _traffic(ahead=(1, 0), accounted=True):
     """A set-up and a window's worth of spans, by hand. ``ahead``: what
     the poll's and the row's ``serve.sync`` carry of it (None: the field
-    is not there, as on a program before ISSUE 31)."""
+    is not there, as on a program before ISSUE 31). ``accounted``: the
+    iteration splits its host time as since ISSUE 36 (a plan and an
+    admission with its two dispatches, ``program`` on every dispatch,
+    the poll's telemetry, ``gc_ms`` on the step); False: the tree of
+    the program before it."""
     def field(value):
         return {} if value is None else {"ahead": value}
+
+    def program(name):
+        return {"program": name} if accounted else {}
     with fr.span("setup.engine_init"):
         with fr.span("jit.program", label="serving.step") as sp:
             sp.set(source="compile", lower_s=0.0, bytes=0)
@@ -154,7 +168,16 @@ def _traffic(ahead=(1, 0)):
             t = fr.now_ns()
             fr.record_span("serve.queue_wait", t, t + 10, req=i)
             fr.record_span("serve.prefill", t + 10, t + 30, req=i)
-            with fr.span("serve.dispatch"):
+            if accounted:
+                with fr.span("serve.plan", req=i, pages=2, shared=0):
+                    pass
+            with fr.span("serve.admit", req=i, slot=0, bucket=8, prompt=5):
+                if accounted:
+                    with fr.span("serve.dispatch", program="prefill"):
+                        pass
+                    with fr.span("serve.dispatch", program="admit"):
+                        pass
+            with fr.span("serve.dispatch", **program("step")):
                 pass
             with fr.span("serve.poll") as poll:
                 with fr.span("serve.sync", site="poll", steps_queued=1,
@@ -164,8 +187,11 @@ def _traffic(ahead=(1, 0)):
                     with fr.span("serve.sync", site="row", steps_queued=0,
                                  **field(ahead[1])):
                         pass
+                if accounted:
+                    with fr.span("serve.telemetry"):
+                        pass
                 poll.set(steps=1, emitted=2, admitted=1)
-            step.set(decode=1)
+            step.set(decode=1, **({"gc_ms": 0.0} if accounted else {}))
         with fr.span("train.step"):
             pass
     return t_open
@@ -210,6 +236,46 @@ def test_sync_covered_share_reads_ahead(ahead, want, recorder,
     assert got == (want if want is None else pytest.approx(want))
     err = capsys.readouterr().err
     assert ("by site: poll" in err) == (want is not None)
+
+
+@pytest.mark.parametrize("name", ITERATION_METRICS)
+def test_iteration_reader_on_a_tree_without_its_spans(
+        name, recorder, bench_modules):
+    """The benchmark files are laid over the parent's checkout too: the
+    three readers of what ISSUE 36 adds give nothing on a program whose
+    iterations carry no ``gc_ms``, ``serve.telemetry`` or
+    ``serve.dispatch{program=prefill}`` (0 would read as a cost of
+    nothing); the longest silence comes from the spans' own stamps and
+    reads on both."""
+    t_proc = fr.now_ns()
+    t_open = _traffic(accounted=False)
+    value = _reader(name).read(_run(t_proc, t_open, fr.now_ns()))
+    if name == "longest_silence_ms.serve":
+        assert value is not None and 0 <= value < 1e3
+    else:
+        assert value is None
+
+
+def test_longest_silence_names_the_span_and_the_stall(
+        recorder, bench_modules, capsys):
+    t_proc = fr.now_ns()
+    t_open = _traffic()
+    with fr.span("serve.step") as step:
+        with fr.span("serve.poll"):
+            with fr.span("serve.sync", site="poll", steps_queued=4,
+                         ahead=1) as sync:
+                time.sleep(0.03)
+        step.set(decode=1, gc_ms=0.0)
+    fr.record("serve.stall", ms=30.0, span="serve.sync", site="poll",
+              gc_ms=0.0, samples=1, top="engine.py:1 read", others="")
+    # (stamped where the stretch ended, as the watcher stamps it)
+    fr.recorder()._buf[-1] = (sync.end_ns,) + fr.recorder()._buf[-1][1:]
+    value = _reader("longest_silence_ms.serve").read(
+        _run(t_proc, t_open, fr.now_ns()))
+    assert value == pytest.approx((sync.end_ns - sync.start_ns) / 1e6)
+    err = capsys.readouterr().err
+    assert "inside serve.sync {'site': 'poll'" in err
+    assert "serve.stall: ms=30.0" in err and "top='engine.py:1 read'" in err
 
 
 @pytest.mark.parametrize("name", NAMES)

@@ -721,6 +721,10 @@ class TestTraceMerge:
             assert name.startswith(
                 f"flightrecorder_postmortem_r{rank}i0_p{os.getpid()}")
         flight_recorder.set_clock_offset_ns(0)
+        # the process's ring again: a ring of 64 left behind cuts the
+        # next file's serving runs short
+        flight_recorder.configure(
+            capacity=flight_recorder.DEFAULT_CAPACITY, on=True)
         merged = merge_paths([str(tmp_path)])
         assert set(merged["metadata"]["merged_tracks"]) == \
             {"rank0.0", "rank1.0"}

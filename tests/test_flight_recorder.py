@@ -162,8 +162,10 @@ class TestRing:
         assert abs(fr.events()[-1][0] - fr.now_ns()) < 1e9
 
     def test_default_capacity_holds_a_benchmark_window(self):
-        # ISSUE 26: ~60 events/s x (set-up + 51 s + 60 s grace) x 4
-        assert fr.DEFAULT_CAPACITY == 65536 >= 4 * 60 * 180
+        # ISSUE 36: a whole run of the busiest cell on the chip left
+        # 37,382 events (nemotron3n-l13-offline; gpt3l8-chat 27,035,
+        # gpt3l8-offline 30,328): x 4
+        assert fr.DEFAULT_CAPACITY == 262144 >= 4 * 37382
         assert fr.capacity() == fr.DEFAULT_CAPACITY
 
     def test_env_capacity_parse(self, monkeypatch):
@@ -229,7 +231,7 @@ class TestDumps:
     def test_auto_dump_writes_the_newest_events_only(self, tmp_path,
                                                      monkeypatch):
         """The crash path serialises AUTO_DUMP_EVENTS, not the whole
-        65,536-event ring; a dump on demand writes everything."""
+        262,144-event ring; a dump on demand writes everything."""
         monkeypatch.setenv("PADDLE_FLIGHT_RECORDER_DIR", str(tmp_path))
         monkeypatch.setattr(fr, "AUTO_DUMP_EVENTS", 8)
         for i in range(20):

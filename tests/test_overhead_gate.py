@@ -236,9 +236,14 @@ def test_slo_tick_disabled_under_budget():
 # calls with the jitted step swapped the same way. Thread CPU time, the
 # least of many short batches, on less off. Measured here: 6.5 µs an
 # iteration (2.75 records; 3.5 since a full engine's poll dispatches a
-# step ahead of its read), 8.7 µs an admission, 3.8-4.5 µs a TrainStep
-# call and 2.5-2.8 µs a DistributedTrainStep call; under eight busy
-# processes on the eight cores the same to within 0.3 µs.
+# step ahead of its read; 4.0 since ISSUE 36 split the poll's telemetry
+# and the copies' dispatch out), 8.7 µs an admission, 3.8-4.5 µs a
+# TrainStep call and 2.5-2.8 µs a DistributedTrainStep call; under eight
+# busy processes on the eight cores the same to within 0.3 µs. ISSUE 36
+# put a compare and a store of the boundary's stamp in every span's open
+# and close (the stall watcher reads it) and the collector on the record:
+# a span alone (``serve.plan``, ``serve.telemetry``) and a ``gc``
+# callback pair are held below.
 
 STEP_BUDGET_US = 25.0            # added per engine.step(), and per admission
 # ISSUE 26 asks for 5 µs and a call reads 2.5-4.5; the gate holds it
@@ -334,30 +339,87 @@ def _tiny_engine():
 
 def test_engine_step_spans_under_budget():
     """Added host time per ``engine.step()`` in steady decode, recorder
-    on less off: ``serve.step`` and ``serve.dispatch`` every iteration,
-    ``serve.poll`` with its ``serve.sync`` every ``poll_every``-th, the
-    ``set()`` calls and the sampled request's decode segment."""
+    on less off: ``serve.step`` and ``serve.dispatch{program=step}``
+    every iteration, ``serve.poll`` with its ``serve.sync``, its
+    ``serve.dispatch{program=poll_view}`` and its two ``serve.telemetry``
+    (the window's charge before the completions, the gauges after them)
+    every ``poll_every``-th, the ``set()`` calls and the sampled
+    request's decode segment."""
     eng, _ = _tiny_engine()
     try:
         # both lanes live for ever
         eng._exes[("step",)] = _Frozen(lambda state, *carried: carried)
         _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
         # both slots hold a request, so a poll dispatches the next step
-        # ahead of its read: poll_every steps take poll_every - 1
-        # iterations, and an iteration records a third more
+        # ahead of its read: poll_every steps take poll_every - 1 = 3
+        # iterations, which record 3 steps, 4 decode dispatches, and the
+        # poll's 5 (poll, sync, the copies' dispatch, two telemetry):
+        # 12 / 3 = 4.0, and a decode segment a poll for each sampled
+        # lane (two at most: 14 / 3)
         polls0 = eng.stats["polls"]
         iters = 4 * (eng.poll_every - 1)
         for _ in range(iters):
             eng.step()
+        names = [f["name"] for _, kind, f in _fr.events() if kind == "span"]
         per_step = len(_fr.events()) / iters
         assert eng.stats["polls"] - polls0 == 4
-        assert 3.0 <= per_step <= 3.7, per_step
+        assert 4.0 <= per_step <= 4.7, per_step
+        assert names.count("serve.telemetry") == 2 * 4
         added = _on_less_off(eng.step)
     finally:
         eng.shutdown()
     assert added < STEP_BUDGET_US, (
         f"the recorder adds {added:.1f}µs to one engine.step() "
         f"(budget {STEP_BUDGET_US}µs)")
+
+
+def _plan_span():
+    with _fr.span("serve.plan", req=7) as sp:
+        sp.set(pages=3, shared=0)
+
+
+def _telemetry_span():
+    with _fr.span("serve.telemetry"):
+        pass
+
+
+@pytest.mark.parametrize("one_span", [_plan_span, _telemetry_span],
+                         ids=["serve.plan", "serve.telemetry"])
+def test_one_span_under_budget(one_span):
+    """One span of those ISSUE 36 adds to an iteration, on less off: the
+    two stamps, the compare and store of the thread's last boundary at
+    each, the annotation, the locked append."""
+    _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
+    with _fr.span("serve.step") as step:
+        one_span()
+        # the boundary store: the last stamp is the child's close
+        (child,) = [f for _, kind, f in _fr.events() if kind == "span"]
+        assert _fr._tls.st.stamp == child["end_ns"]
+    assert _fr._tls.st.stamp == step.end_ns
+    added = _on_less_off(one_span)
+    assert added < TRAIN_STEP_BUDGET_US, (
+        f"one span costs {added:.1f}µs on less off (budget "
+        f"{TRAIN_STEP_BUDGET_US}µs)")
+
+
+def test_gc_callback_pair_under_budget():
+    """What the recorder's ``gc.callbacks`` entry adds to every
+    collection, young ones included (hundreds a second): two stamps and
+    a sum; only a long or a full one is made a span."""
+    info = {"generation": 0, "collected": 0, "uncollectable": 0}
+
+    def pair():
+        _fr._on_gc("start", info)
+        _fr._on_gc("stop", info)
+
+    _fr.configure(capacity=_fr.DEFAULT_CAPACITY, on=True)
+    total0 = _fr.gc_ns()
+    _cpu_us(pair, 2000, 1)  # warm up
+    best = _cpu_us(pair, 2000, 30)
+    assert _fr.gc_ns() > total0            # every collection is summed
+    assert not [f for _, kind, f in _fr.events() if kind == "span"]
+    assert best < BUDGET_US, (
+        f"a gc callback pair costs {best:.2f}µs (budget {BUDGET_US}µs)")
 
 
 def test_admission_spans_under_budget():

@@ -203,10 +203,14 @@ def test_ahead_says_what_is_queued_behind_each_read(mode):
             n_ahead += was_full
             # never more than one step ahead of a poll's read, and the
             # poll covers the steps before that one
-            inside = _children(spans, poll, "serve.dispatch")
+            inside = _children(spans, poll, "serve.dispatch",
+                               program="step")
             assert len(inside) == int(was_full)
-            assert decode == len(_children(spans, step, "serve.dispatch")) \
-                + len(inside)
+            assert decode == len(_children(spans, step, "serve.dispatch",
+                                           program="step")) + len(inside)
+            # the copies the read is of, made before the step ahead
+            assert len(_children(spans, poll, "serve.dispatch",
+                                 program="poll_view")) == int(was_full)
             assert 1 <= poll.fields["steps"] <= eng.poll_every
             rows = _children(spans, poll, "serve.sync", site="row")
             assert len(rows) == (1 if poll.fields["completed"]
